@@ -13,7 +13,11 @@ Three checks per kernel module (``kernels/gust_spmv.py``,
   Pipelined operand/output tiles are counted twice (Pallas
   double-buffers them); ``memory_space=ANY`` operands are free; tile
   element size is taken as 4 bytes (f32 — an upper bound for the int8 /
-  bf16 / int16 streams).  An over-budget config is an ``error`` finding:
+  bf16 / int16 streams).  Every tile is counted at its padded VMEM
+  footprint: the minor dimension rounds up to 128 lanes and the
+  second-minor to whole sublane tiles (8 rows of 32-bit words; 16 / 32
+  rows for 2- / 1-byte scratch), so a narrow batch axis is charged what
+  the chip actually allocates.  An over-budget config is an ``error`` finding:
   the audit configs are chosen to fit, so exceeding the budget means a
   builder's footprint grew.
 * **GUST-K02 — DB ping/pong pairing.**  In every double-buffered kernel
@@ -94,7 +98,32 @@ DEFAULT_CONFIGS: Tuple[Dict[str, object], ...] = (
          s_blk=8, b=8, c_blk=8, num_blocks=128, total_rows=1024,
          r_rows=256, k_max=8, n_out=256, value_dtype="int8",
          index_dtype="int16", x_dtype="float32"),
+    # yi_6b w_down (4096 x 11008, l=256) decoded at batch 4: the widest
+    # resident x the serving path holds (43 segments)
+    dict(name="yi6b_down", num_windows=16, c_pad=1152, l=256, seg_count=43,
+         s_blk=43, b=4, c_blk=8, num_blocks=2304, total_rows=1024,
+         r_rows=256, k_max=8, n_out=256, value_dtype="float32",
+         index_dtype="int32", x_dtype="float32"),
 )
+
+#: (lanes, 32-bit sublanes) of one VMEM tile.
+_LANES, _SUBLANES = 128, 8
+
+
+def _padded_elems(dims: Tuple[int, ...], itemsize: int) -> int:
+    """Elements a VMEM buffer of ``dims`` occupies once its minor dim is
+    rounded up to whole lanes and its second-minor dim to whole sublane
+    tiles (packed dtypes stack more rows per tile)."""
+    dims = [int(d) for d in dims]
+    if dims:
+        dims[-1] = -(-dims[-1] // _LANES) * _LANES
+    if len(dims) >= 2:
+        rows = _SUBLANES * max(4 // itemsize, 1)
+        dims[-2] = -(-dims[-2] // rows) * rows
+    n = 1
+    for d in dims:
+        n *= d
+    return n
 
 
 @dataclasses.dataclass(frozen=True)
@@ -200,6 +229,10 @@ def _bind_assigns(fn: ast.FunctionDef, env: Dict[str, object]) -> None:
     itemsize).  Anything richer is skipped."""
 
     def value_of(node: ast.AST):
+        if isinstance(node, ast.Call) and isinstance(node.func, ast.Name) \
+                and node.func.id == "_batch_pad" and len(node.args) == 1:
+            b = _eval(node.args[0], env)
+            return -(-b // _SUBLANES) * _SUBLANES
         if isinstance(node, ast.Call) and isinstance(node.func, ast.Attribute) \
                 and node.func.attr == "dtype" and node.args:
             name = node.args[0]
@@ -260,10 +293,7 @@ def _builder_footprint(fn: ast.FunctionDef, config: Dict[str, object]):
                 dims = _eval(shape, env)
             except _Unsupported as e:
                 raise _Unsupported(f"BlockSpec shape: {e}") from None
-            n = 1
-            for d in dims:
-                n *= int(d)
-            total += 2 * n * 4      # pipelined tile, auto double-buffered
+            total += 2 * _padded_elems(dims, 4) * 4  # pipelined, x2
             tiles.append(f"tile{tuple(int(d) for d in dims)}x2")
         elif node.func.attr == "VMEM":
             shape = node.args[0]
@@ -272,10 +302,7 @@ def _builder_footprint(fn: ast.FunctionDef, config: Dict[str, object]):
             except _Unsupported as e:
                 raise _Unsupported(f"VMEM scratch shape: {e}") from None
             isz = _itemsize(node.args[1] if len(node.args) > 1 else None, env)
-            n = 1
-            for d in dims:
-                n *= int(d)
-            total += n * isz
+            total += _padded_elems(dims, isz) * isz
             tiles.append(f"scratch{tuple(int(d) for d in dims)}@{isz}B")
     return total, tuple(tiles)
 

@@ -64,7 +64,6 @@ class SparsityConfig:
             load_balance=self.load_balance,
             layout="padded",
             backend="pallas" if self.use_kernel else "jnp",
-            interpret=True,
         )
 
 
@@ -116,7 +115,7 @@ class GustLinear:
             config = cfg.plan_config
             density = cfg.density
         if config is None:
-            config = PlanConfig(layout="padded", backend="jnp", interpret=True)
+            config = PlanConfig(layout="padded", backend="jnp")
         if density is None:
             density = 0.1
         self.cfg = cfg  # legacy handle (None for plan-config construction)

@@ -591,9 +591,9 @@ class GustPlan:
         return bool(self.artifact.fusable and jax.default_backend() == "tpu")
 
     def _interpret(self) -> bool:
-        if self.config.interpret is not None:
-            return self.config.interpret
-        return jax.default_backend() != "tpu"
+        from repro.kernels.gust_spmv import _resolve_interpret
+
+        return _resolve_interpret(self.config.interpret)
 
     def _pipeline(self) -> str:
         """Resolved streaming mode: the jnp backend has no tile pipeline
@@ -787,7 +787,13 @@ class GustPlan:
             layout = _shard_layout(self.artifact, n_dev)
         m_d, r_d, c_d, lw_d, w_max, idx = layout
         fn = _shard_spmv_fn(self.mesh, self.axis, c.l, c.c_blk, w_max)
-        y_dev = fn(m_d, r_d, c_d, lw_d, v)
+        # replicate the per-device outputs before the host-order gather:
+        # on a mesh with explicit axis types an eager gather from a
+        # sharded operand has no unambiguous output sharding
+        y_dev = jax.device_put(
+            fn(m_d, r_d, c_d, lw_d, v),
+            jax.sharding.NamedSharding(self.mesh, jax.sharding.PartitionSpec()),
+        )
         # Reassemble: device d's first w_cnt[d]*l rows are its window range
         # in order (collectives-free concatenation), then undo the
         # load-balancing row sort.

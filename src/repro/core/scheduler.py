@@ -488,6 +488,23 @@ def _color_chunk_worker(shm_name: str, e: int, s: int, t: int, base: int) -> int
     return s
 
 
+def _worker_context():
+    """Start method for the coloring workers: a forkserver that preloads
+    this module, or None where the platform has none.  The caller has
+    often initialized JAX (a serving process holds the chip, and its
+    runtime is multithreaded), and forking such a process can deadlock
+    the child.  The forkserver is a fresh interpreter that imports this
+    module and never touches a device; workers fork from it, so they
+    start fast and inherit no runtime threads."""
+    import multiprocessing as mp
+
+    if "forkserver" not in mp.get_all_start_methods():
+        return None
+    ctx = mp.get_context("forkserver")
+    ctx.set_forkserver_preload([__name__])
+    return ctx
+
+
 def color_windows_chunked(
     row_key: np.ndarray,
     lane_key: np.ndarray,
@@ -503,7 +520,7 @@ def color_windows_chunked(
     Bit-identical to ``color_edges_fast(row_key, lane_key)`` by window
     independence (see section comment).  Falls back to the serial colorer
     when parallelism can't help (one worker, too few edges or windows) or
-    can't run (no fork start method, shared memory unavailable) — an
+    can't run (no forkserver start method, shared memory unavailable) — an
     explicit ``workers >= 2`` skips the ``min_edges`` threshold so small
     inputs can exercise the parallel path deterministically."""
     e = row_key.shape[0]
@@ -513,11 +530,8 @@ def color_windows_chunked(
     if n_workers < 2 or e == 0 or e < min_edges or num_windows < 2:
         return color_edges_fast(row_key, lane_key)
 
-    import multiprocessing as mp
-
-    if "fork" not in mp.get_all_start_methods():
-        # spawn would re-import the caller's __main__; not worth the risk
-        # for a pure perf path — the serial colorer is always correct.
+    ctx = _worker_context()
+    if ctx is None:  # the serial colorer is always correct
         return color_edges_fast(row_key, lane_key)
 
     chunks = _chunk_bounds(win, num_windows, n_chunks=n_workers)
@@ -535,7 +549,6 @@ def color_windows_chunked(
         buf = np.ndarray((3, e), dtype=np.int64, buffer=shm.buf)
         np.copyto(buf[0], row_key, casting="safe")
         np.copyto(buf[1], lane_key, casting="safe")
-        ctx = mp.get_context("fork")
         with ProcessPoolExecutor(max_workers=len(chunks), mp_context=ctx) as pool:
             futures = [
                 pool.submit(_color_chunk_worker, shm.name, e, s, t, base * l)

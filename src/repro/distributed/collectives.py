@@ -22,10 +22,7 @@ from typing import Any, List, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-try:  # jax >= 0.6 exports shard_map at the top level
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map
+shard_map = jax.shard_map
 
 __all__ = ["shard_map", "ring_all_reduce", "bucketed", "unbucketed",
            "compressed_psum"]
@@ -36,10 +33,7 @@ def ring_all_reduce(x: jnp.ndarray, axis_name: str) -> jnp.ndarray:
     (k-1 hops) then all-gather (k-1 hops).  Semantically == lax.psum, but
     expressed as individually schedulable sends so XLA can overlap each
     hop with compute.  Must run inside shard_map over ``axis_name``."""
-    try:
-        k = jax.lax.axis_size(axis_name)
-    except AttributeError:  # older jax: psum of a literal folds to the size
-        k = jax.lax.psum(1, axis_name)
+    k = jax.lax.axis_size(axis_name)
     if k == 1:
         return x
     idx = jax.lax.axis_index(axis_name)

@@ -1,4 +1,6 @@
-"""Pallas TPU kernels for the GUST hot path (validated via interpret=True).
+"""Pallas TPU kernels for the GUST hot path (compiled by Mosaic on a TPU,
+interpreted elsewhere; ``tests/test_chip_compile.py`` compiles them for a
+described v5e).
 
   gust_spmv.py        -- flagship: fused gather + one-hot MXU routing SpMV
                          over the padded (W, C_pad/c_blk) grid

@@ -12,45 +12,32 @@ building block for the *unfused* execution path (gather kernel -> XLA
 elementwise/segment ops), which is the honest TPU analogue of GUST's
 hardware pipeline stages when fusion is disabled.
 
-Gather mechanism (same as the flagship kernel): the scheduler only ever
-maps a column to its own lane (``off == lane``) or — after load-balance
-step 3 — to the lane-reversed slot (``off == l-1-lane``), so the gather
-decomposes into a one-hot over the ``S = ceil(n/l)`` column segments plus
-a straight/flipped select.  No random access is ever issued.
+Gather mechanism (the flagship kernel's, shared code): the scheduler only
+ever maps a column to its own lane (``off == lane``) or — after
+load-balance step 3 — to the lane-reversed slot (``off == l-1-lane``), so
+the gather decomposes into a select over the ``S = ceil(n/l)`` column
+segments plus a straight/flipped select.  No random access is ever
+issued.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .gust_spmv import _batch_pad, _gather_resident, _resolve_interpret
+
 __all__ = ["make_gather_fill"]
 
 
-def _kernel(col_ref, xs_ref, out_ref, *, l, seg_count, c_blk, b):
-    col_blk = col_ref[...].astype(jnp.int32)  # (C_blk, l) int
-    xs = xs_ref[...].astype(jnp.float32)  # (S, l, B)
-    xf = xs[:, ::-1, :]  # lane-reversed layout, derived in-kernel
-
-    seg = col_blk // l
-    off = col_blk - seg * l
-    lane = jax.lax.broadcasted_iota(jnp.int32, (c_blk, l), 1)
-    flip = (off != lane).astype(jnp.float32)
-
-    seg_t = seg.T  # (l, C_blk)
-    onehot = (
-        seg_t[:, :, None]
-        == jax.lax.broadcasted_iota(jnp.int32, (l, c_blk, seg_count), 2)
-    ).astype(jnp.float32)
-    dnums = (((2,), (0,)), ((0,), (1,)))
-    g_s = jax.lax.dot_general(onehot, xs, dnums, preferred_element_type=jnp.float32)
-    g_f = jax.lax.dot_general(onehot, xf, dnums, preferred_element_type=jnp.float32)
-    fsel = flip.T[:, :, None]
-    out = g_s * (1.0 - fsel) + g_f * fsel  # (l, C_blk, B)
-    out_ref[...] = out.transpose(1, 0, 2)  # (C_blk, l, B)
+def _kernel(col_ref, xs_ref, out_ref, *, l, seg_count):
+    gs = _gather_resident(col_ref[...], xs_ref, l=l, seg_count=seg_count)
+    for c, g in enumerate(gs):
+        out_ref[c] = g
 
 
 @functools.lru_cache(maxsize=256)
@@ -61,23 +48,26 @@ def make_gather_fill(
     b: int,
     *,
     c_blk: int = 8,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
-    """pallas_call producing ``V_sch`` of shape (total_rows, l, B) from
-    ``Col_sch`` (total_rows, l) and the VMEM-resident vector.  Memoized on
-    geometry like :func:`repro.kernels.gust_spmv.make_gust_spmv`."""
+    """pallas_call producing ``V_sch`` from ``Col_sch`` (total_rows, l)
+    and the VMEM-resident vector in the SpMV kernels' layout
+    ``(seg_count, B_pad, l)``: returns (total_rows, B_pad, l), row ``r``
+    holding ``x[Col_sch[r, j], :]`` at lane ``j``.  Memoized on geometry
+    like :func:`repro.kernels.gust_spmv.make_gust_spmv`."""
     if total_rows % c_blk:
         raise ValueError("total_rows must be a multiple of c_blk")
+    bp = _batch_pad(b)
     grid = (total_rows // c_blk,)
-    kernel = functools.partial(_kernel, l=l, seg_count=seg_count, c_blk=c_blk, b=b)
+    kernel = functools.partial(_kernel, l=l, seg_count=seg_count)
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((c_blk, l), lambda i: (i, 0)),
-            pl.BlockSpec((seg_count, l, b), lambda i: (0, 0, 0)),
+            pl.BlockSpec((seg_count, bp, l), lambda i: (0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((c_blk, l, b), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((total_rows, l, b), jnp.float32),
-        interpret=interpret,
+        out_specs=pl.BlockSpec((c_blk, bp, l), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((total_rows, bp, l), jnp.float32),
+        interpret=_resolve_interpret(interpret),
     )

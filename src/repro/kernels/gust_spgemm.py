@@ -15,9 +15,10 @@ of the ``R·n_out·4`` a densified B would — the SpArch condensing win.
 Per grid step (one stream block, scalar-prefetch steering identical to
 ``gust_spmv_ragged``):
 
-  1. **condensed gather** — one-hot over B's ``R`` rows on the MXU fetches
-     the block's ``(c_blk·l, k_max)`` value/column pairs (columns ride the
-     same matmul as exact small integers in f32);
+  1. **condensed gather** — per cycle row, a one-hot over B's ``R`` rows
+     on the MXU fetches the ``(l, k_max)`` value/column pairs of its
+     slots (columns ride the same full-precision matmul as exact small
+     integers in f32);
   2. **multipliers** — VPU multiply by the block's A values;
   3. **merge** — each slot's partial products densify into its output row
      through a weighted one-hot over ``n_out`` columns, then the crossbar
@@ -39,11 +40,14 @@ otherwise (their merge orders differ — segment-sum vs blocked one-hot).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .gust_spmv import _resolve_interpret
 
 __all__ = ["make_gust_spgemm"]
 
@@ -52,46 +56,38 @@ def _kernel(bw_ref, bs_ref, m_ref, col_ref, row_ref, bv_ref, bc_ref, y_ref,
             acc_scr, *, l, r_rows, k_max, n_out, c_blk):
     t = pl.program_id(0)
     w = bw_ref[t]
-    slots = c_blk * l
+    hi = jax.lax.Precision.HIGHEST
 
-    m_blk = m_ref[...].astype(jnp.float32)  # (c_blk, l)
-    col_flat = col_ref[...].astype(jnp.int32).reshape(slots)
-    row_flat = row_ref[...].astype(jnp.int32).reshape(slots)
+    # slots of the block with the lane on sublanes: column c of these
+    # (l, c_blk) arrays is cycle c of the stream block
+    m_t = m_ref[...].astype(jnp.float32).T
+    col_t = col_ref[...].astype(jnp.int32).T
+    row = row_ref[...].astype(jnp.int32)  # (c_blk, l)
+    bv = bv_ref[...].astype(jnp.float32)  # (R, k_max)
+    bc = bc_ref[...].astype(jnp.float32)  # exact small integers
+    b_row = jax.lax.broadcasted_iota(jnp.int32, (l, r_rows), 1)
+    out_col = jax.lax.broadcasted_iota(jnp.int32, (l, n_out), 1)
+    adder = jax.lax.broadcasted_iota(jnp.int32, (l, l), 0)
 
-    # ---- condensed gather: one-hot over B's rows on the MXU -------------
-    onehot_r = (
-        col_flat[:, None]
-        == jax.lax.broadcasted_iota(jnp.int32, (slots, r_rows), 1)
-    ).astype(jnp.float32)  # (slots, R)
-    dnums = (((1,), (0,)), ((), ()))
-    bv = jax.lax.dot_general(
-        onehot_r, bv_ref[...].astype(jnp.float32), dnums,
-        preferred_element_type=jnp.float32,
-    )  # (slots, k_max)
-    # column ids ride the same one-hot matmul as exact f32 integers
-    # (n_out < 2^24), then cast back
-    bc = jax.lax.dot_general(
-        onehot_r, bc_ref[...].astype(jnp.float32), dnums,
-        preferred_element_type=jnp.float32,
-    ).astype(jnp.int32)  # (slots, k_max)
-
-    # ---- multipliers (VPU) ----------------------------------------------
-    partial = m_blk.reshape(slots, 1) * bv  # (slots, k_max)
-
-    # ---- merge: densify each slot's partial row, route onto adders ------
-    onehot_n = (
-        bc[:, :, None]
-        == jax.lax.broadcasted_iota(jnp.int32, (slots, k_max, n_out), 2)
-    ).astype(jnp.float32)
-    slot_rows = jnp.sum(partial[:, :, None] * onehot_n, axis=1)  # (slots, n_out)
-    onehot_row = (
-        row_flat[:, None]
-        == jax.lax.broadcasted_iota(jnp.int32, (slots, l), 1)
-    ).astype(jnp.float32)
-    acc = jax.lax.dot_general(
-        onehot_row, slot_rows, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # (l, n_out)
+    acc = jnp.zeros((l, n_out), jnp.float32)
+    for c in range(c_blk):
+        # ---- condensed gather: one-hot over B's rows on the MXU ---------
+        sel = (b_row == col_t[:, c:c + 1]).astype(jnp.float32)  # (l, R)
+        vals = jnp.dot(sel, bv, precision=hi,
+                       preferred_element_type=jnp.float32)  # (l, k_max)
+        cols = jnp.dot(sel, bc, precision=hi,
+                       preferred_element_type=jnp.float32).astype(jnp.int32)
+        # ---- multipliers (VPU) + merge: densify each slot's partial row
+        partial = vals * m_t[:, c:c + 1]
+        slot_rows = jnp.zeros((l, n_out), jnp.float32)
+        for k in range(k_max):
+            slot_rows = slot_rows + jnp.where(
+                cols[:, k:k + 1] == out_col, partial[:, k:k + 1], 0.0
+            )
+        # ---- crossbar: route slot rows onto adder rows -------------------
+        onehot_row = (adder == row[c:c + 1]).astype(jnp.float32)  # (l, l)
+        acc = acc + jnp.dot(onehot_row, slot_rows, precision=hi,
+                            preferred_element_type=jnp.float32)
 
     # ---- VMEM scratch row accumulator: integrate across the window's
     # blocks, dump on its last one ----------------------------------------
@@ -107,7 +103,7 @@ def _kernel(bw_ref, bs_ref, m_ref, col_ref, row_ref, bv_ref, bc_ref, y_ref,
 
     @pl.when(t == bs_ref[w + 1] - 1)
     def _dump():
-        y_ref[...] = acc_scr[...][None]
+        y_ref[0] = acc_scr[...]
 
 
 @functools.lru_cache(maxsize=256)
@@ -120,7 +116,7 @@ def make_gust_spgemm(
     n_out: int,
     *,
     c_blk: int = 8,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Build the SpGEMM scalar-prefetch pallas_call for one (A stream
     geometry, condensed-B geometry) pair.
@@ -166,5 +162,5 @@ def make_gust_spgemm(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((num_windows, l, n_out), jnp.float32),
-        interpret=interpret,
+        interpret=_resolve_interpret(interpret),
     )

@@ -8,30 +8,38 @@ TPU adaptation of the paper's three hardware levels (DESIGN.md §2):
                   ``v[Col_sch]``, both fused in-kernel (the scheduler
                   only ever assigns a column to its own lane or the
                   lane-reversed position — load-balance step 3 — so the
-                  gather is a segment one-hot / segment-select plus a
-                  straight/flipped select, never random access):
+                  gather is a segment select plus a straight/flipped
+                  select, never random access):
 
                   * **resident** (``make_gust_spmv``): the vector lives
-                    whole in VMEM and each block contracts a one-hot
-                    over all ``seg_count = ceil(n/l)`` column segments —
+                    whole in VMEM and each block walks all
+                    ``seg_count = ceil(n/l)`` column segments —
                     O(seg_count) gather work per slot, O(n) VMEM;
                   * **segment-local** (``make_gust_spmv_local``): the
                     pack-time ``seg_blk`` table (scalar-prefetched)
                     steers the pipeline to stream only the ``S_blk``
-                    x tiles a block actually references — one (1, l, B)
-                    tile per inner grid step — and the contraction
-                    shrinks to the block-local segments: O(S_blk) gather
-                    work per slot, O(l·B) VMEM.  This is the paper's
-                    Buffer-Filler locality story (touch only the vector
-                    entries a window needs) and removes the
+                    x tiles a block actually references — one
+                    (1, B_pad, l) tile per inner grid step — and the
+                    select walks only the block-local segments:
+                    O(S_blk) gather work per slot, O(l·B) VMEM.  This is
+                    the paper's Buffer-Filler locality story (touch only
+                    the vector entries a window needs) and removes the
                     VMEM-residency cap on matrix *width*.
 
-  crossbar +   -> a one-hot routing matmul on the MXU:
-  adders          ``y_win += OneHot(Row_sch_blk)^T @ P_flat``.
+  crossbar +   -> a one-hot routing matmul on the MXU, one per cycle row
+  adders          of the block: ``y_win += P_c @ OneHot(Row_sch[c])``.
                   Collision-freedom of the edge coloring is what makes this
                   exact — within a cycle each adder (output row) receives at
                   most one partial product, so the one-hot rows never
                   overlap within a cycle and the matmul loses nothing.
+
+Layout.  Every kernel takes x as ``(seg_count, B_pad, l)`` f32 — column
+segment, batch padded to a multiple of 8 sublanes, lane — and writes
+per-window accumulators ``(num_windows, B_pad, l)``: the lane axis is the
+hardware length ``l`` in both, so the batch costs sublanes, not lanes.
+:func:`repro.kernels.ops.execute_spmm` converts to and from ``(n, B)``.
+The lane-reversed view of a tile is derived in-kernel
+(``_lane_reverse``), so only one copy of x crosses HBM.
 
 Grid: resident ``(num_windows, num_color_blocks)``; segment-local adds an
 inner ``S_blk`` dimension that walks the block's x tiles.  Dimension 1
@@ -58,10 +66,14 @@ carry performs the same f32 additions in the same order as the revisited
 output tile / gather scratch.
 
 Quantized variants (PR 6).  Every builder takes ``quantized=True`` to
-accept an int8 value stream plus the pack-time per-block scale column
-``scale_blk.reshape(T_blk, 1)``: the dequant ``float32(q) * scale`` is
-fused into the accumulate (one extra VPU multiply per block), bit-exact
-with :func:`repro.kernels.ref.dequant_ref`.
+accept an int8 value stream plus the pack-time per-block scales
+``scale_blk`` (``(T_blk,)`` f32), scalar-prefetched after the steering
+tables: the _dequant ``float32(q) * scale`` is fused into the accumulate
+(one extra VPU multiply per block), bit-exact with
+:func:`repro.kernels.ref.dequant_ref`.
+
+``interpret=None`` on every builder means "interpret only off TPU"
+(``_resolve_interpret``), the same rule as ``PlanConfig.interpret``.
 
 All arithmetic accumulates in f32 regardless of input dtype (MXU-native).
 """
@@ -69,6 +81,7 @@ All arithmetic accumulates in f32 regardless of input dtype (MXU-native).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -80,158 +93,137 @@ __all__ = [
     "make_gust_spmv_local",
     "make_gust_spmv_db",
     "make_gust_spmv_local_db",
-    "block_accumulate",
-    "block_math",
     "route_rows",
-    "decode_local_cols",
-    "local_tile_delta",
+    "gather_local_step",
+    "stream_copy",
 ]
 
-
-def route_rows(partial, row_blk, *, c_blk, l, b):
-    """Crossbar + adders: one-hot routing matmul on the MXU.  ``partial``
-    is the (l, C_blk, B) multiplied block; returns its (1, l, B)
-    contribution to the window accumulator.  Padding slots carry m==0 and
-    row==0, contributing exactly zero."""
-    p_flat = partial.transpose(1, 0, 2).reshape(c_blk * l, b)
-    row_flat = row_blk.reshape(c_blk * l)
-    onehot_row = (
-        row_flat[:, None]
-        == jax.lax.broadcasted_iota(jnp.int32, (c_blk * l, l), 1)
-    ).astype(jnp.float32)
-    # (l, B) = (C_blk*l, l)^T @ (C_blk*l, B)
-    return jax.lax.dot_general(
-        onehot_row,
-        p_flat,
-        (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )[None]  # (1, l, B)
+#: Sublane granularity of the batch axis in the kernel layout.
+_SUBLANES = 8
+#: Lanes in one vreg: the lane reversal gathers within this width.
+_VREG_LANES = 128
 
 
-def block_math(m_blk, col_blk, row_blk, xs, *, l, seg_count, c_blk, b):
-    """Value-level core of the *resident* per-block math: fused
-    Buffer-Filler gather + VPU multiply + one-hot routing matmul, on
-    already-loaded (and already-dequantized) arrays.  ``m_blk`` is the
-    (C_blk, l) f32 value block, ``xs`` the (S, l, B) straight-layout x;
-    the lane-reversed layout is derived here.  Returns the block's
-    (1, l, B) f32 contribution to its window accumulator."""
-    xf = xs[:, ::-1, :]  # (S, l, B) lane-reversed, derived in-kernel
-
-    # ---- Buffer Filler: fused vector gather -----------------------------
-    seg = col_blk // l  # (C_blk, l)
-    off = col_blk - seg * l
-    lane = jax.lax.broadcasted_iota(jnp.int32, (c_blk, l), 1)
-    flip = (off != lane).astype(jnp.float32)  # 1.0 where lane-reversed
-
-    # One-hot over column segments, contracted per lane (lane is a batch
-    # dim): g[j, c, b] = Σ_s [seg[c,j]==s] · x[s, j, b].
-    seg_t = seg.T  # (l, C_blk)
-    onehot = (
-        seg_t[:, :, None]
-        == jax.lax.broadcasted_iota(jnp.int32, (l, c_blk, seg_count), 2)
-    ).astype(jnp.float32)  # (l, C_blk, S)
-    dnums = (((2,), (0,)), ((0,), (1,)))  # contract S; batch over lane j
-    g_straight = jax.lax.dot_general(
-        onehot, xs, dnums, preferred_element_type=jnp.float32
-    )  # (l, C_blk, B)
-    g_flip = jax.lax.dot_general(
-        onehot, xf, dnums, preferred_element_type=jnp.float32
-    )
-    fsel = flip.T[:, :, None]  # (l, C_blk, 1)
-    x_sel = g_straight * (1.0 - fsel) + g_flip * fsel  # (l, C_blk, B)
-
-    # ---- multipliers (VPU) ----------------------------------------------
-    partial = m_blk.T[:, :, None] * x_sel  # (l, C_blk, B)
-
-    # ---- crossbar + adders ----------------------------------------------
-    return route_rows(partial, row_blk, c_blk=c_blk, l=l, b=b)
+def _resolve_interpret(interpret: Optional[bool] = None) -> bool:
+    """The one "interpret only off TPU" rule: an explicit bool wins,
+    ``None`` compiles on a TPU backend and interprets anywhere else."""
+    if interpret is not None:
+        return bool(interpret)
+    return jax.default_backend() != "tpu"
 
 
-def block_accumulate(m_ref, col_ref, row_ref, xs_ref, *, l, seg_count,
-                     c_blk, b, scale=None):
-    """Shared per-block math of the padded and ragged *resident* kernels,
-    reading from refs.  ``scale`` (scalar f32 or None) fuses the int8
-    dequant into the value load."""
-    m_blk = m_ref[...].astype(jnp.float32)  # (C_blk, l)
-    if scale is not None:
-        m_blk = m_blk * scale
-    return block_math(
-        m_blk,
-        col_ref[...].astype(jnp.int32),
-        row_ref[...].astype(jnp.int32),
-        xs_ref[...].astype(jnp.float32),
-        l=l, seg_count=seg_count, c_blk=c_blk, b=b,
+def _batch_pad(b: int) -> int:
+    """Batch width of the kernel layout: ``b`` rounded up to whole
+    sublane groups."""
+    return -(-b // _SUBLANES) * _SUBLANES
+
+
+def _lane_reverse(x):
+    """``x[:, ::-1]`` for a 2-D ``(R, l)`` array, spelled so Mosaic lowers
+    it: a gather with the reversed-lane index inside each 128-lane chunk
+    (the TPU's in-register lane gather), then the chunks in reverse
+    order.  Pure data movement, so the result is bit-exact."""
+    rows, l = x.shape
+    w = _VREG_LANES if l > _VREG_LANES and l % _VREG_LANES == 0 else l
+    rev = (w - 1) - jax.lax.broadcasted_iota(jnp.int32, (rows, w), 1)
+    chunks = [
+        jnp.take_along_axis(x[:, k * w:(k + 1) * w], rev, axis=1)
+        for k in range(l // w)
+    ]
+    return chunks[0] if len(chunks) == 1 else jnp.concatenate(
+        chunks[::-1], axis=1
     )
 
 
-def decode_local_cols(col_loc, *, l, c_blk):
-    """Decode the block-local column block once per block (hoisted out of
-    the tile loop by the double-buffered local kernel): returns
-    ``(local_seg (C_blk, l) int32, fsel (l, C_blk, 1) f32)`` — the local
-    segment of every slot and its straight/flipped lane select."""
-    local_seg = col_loc // l
-    off = col_loc - local_seg * l
-    lane = jax.lax.broadcasted_iota(jnp.int32, (c_blk, l), 1)
-    flip = (off != lane).astype(jnp.float32)
-    return local_seg, flip.T[:, :, None]
+def _decode_cols(col_blk, *, l):
+    """Decode a (C_blk, l) column block once: the column segment of every
+    slot and whether it reads the lane-reversed position."""
+    col = col_blk.astype(jnp.int32)
+    seg = col // l
+    lane = jax.lax.broadcasted_iota(jnp.int32, col.shape, 1)
+    return seg, (col - seg * l) != lane
 
 
-def local_tile_delta(local_seg, fsel, tile, s):
-    """Contribution of one streamed x tile to the (l, C_blk, B) gather
-    accumulator: a slot contributes exactly when its local segment id
-    equals ``s``.  ``tile`` is the (l, B) straight-layout tile; the
-    lane-reversed layout is derived here.  After all ``S_blk`` tiles the
-    accumulator equals the resident kernel's ``x_sel`` bitwise (each
-    slot's value added once, zeros otherwise)."""
-    tile_rev = tile[::-1, :]  # lane-reversed, derived in-kernel
-    sel = tile[:, None, :] * (1.0 - fsel) + tile_rev[:, None, :] * fsel
-    mask = (local_seg == s).astype(jnp.float32)  # (C_blk, l)
-    return mask.T[:, :, None] * sel  # (l, C_blk, B)
-
-
-def gather_local_step(col_ref, xt_ref, s, g_scr, *, l, c_blk):
-    """One segment-local gather step, shared by the padded and ragged
-    local kernels: accumulate into the (l, C_blk, B) scratch the
-    contribution of the single streamed x tile ``xt_ref`` (the block's
-    ``s``-th referenced segment)."""
-    col_loc = col_ref[...].astype(jnp.int32)  # (C_blk, l)
-    local_seg, fsel = decode_local_cols(col_loc, l=l, c_blk=c_blk)
-    tile = xt_ref[...].astype(jnp.float32)[0]  # (l, B) straight
-    g_scr[...] += local_tile_delta(local_seg, fsel, tile, s)
-
-
-def _kernel(m_ref, col_ref, row_ref, xs_ref, y_ref, *, l, seg_count, c_blk,
-            b):
-    cb = pl.program_id(1)
-    acc = block_accumulate(
-        m_ref, col_ref, row_ref, xs_ref,
-        l=l, seg_count=seg_count, c_blk=c_blk, b=b,
+def _gather_tile(gs, seg, flip, s, tile):
+    """Fold the straight x tile ``tile`` (B_pad, l) of segment ``s`` into
+    the per-cycle gather accumulators ``gs`` (one (B_pad, l) array per
+    block row): a slot takes its straight or lane-reversed value exactly
+    when its segment is ``s``.  A select, never an add, so after every
+    segment the accumulators hold ``x[col]`` bit-exactly."""
+    rev = _lane_reverse(tile)
+    return tuple(
+        jnp.where(seg[c:c + 1] == s,
+                  jnp.where(flip[c:c + 1], rev, tile), g)
+        for c, g in enumerate(gs)
     )
 
-    @pl.when(cb == 0)
+
+def _zeros_gs(c_blk, bp, l):
+    return tuple(jnp.zeros((bp, l), jnp.float32) for _ in range(c_blk))
+
+
+def _gather_resident(col_blk, xs_ref, *, l, seg_count):
+    """Resident Buffer Filler: walk every column segment of the
+    VMEM-resident x (seg_count, B_pad, l) for one (C_blk, l) column
+    block.  Returns the per-cycle gathered tuple."""
+    seg, flip = _decode_cols(col_blk, l=l)
+    c_blk, bp = seg.shape[0], xs_ref.shape[1]
+
+    def body(s, gs):
+        return _gather_tile(gs, seg, flip, s, xs_ref[s])
+
+    return jax.lax.fori_loop(0, seg_count, body, _zeros_gs(c_blk, bp, l))
+
+
+def route_rows(m_blk, gs, row_blk, *, l):
+    """Multipliers + crossbar + adders for one block: per cycle row ``c``
+    the VPU multiply ``m[c] * g[c]`` and a one-hot routing matmul on the
+    MXU sending lane ``j`` to adder ``row[c, j]``.  Returns the block's
+    (B_pad, l) f32 contribution to its window accumulator.  Padding
+    slots carry m == 0 and row == 0, contributing exactly zero.  The
+    matmul runs at full f32 precision: each output sums at most one
+    nonzero product per cycle (collision-freedom), so it is exact."""
+    row = row_blk.astype(jnp.int32)
+    adder = jax.lax.broadcasted_iota(jnp.int32, (l, l), 0)
+    acc = None
+    for c, g in enumerate(gs):
+        p = m_blk[c:c + 1] * g  # (B_pad, l)
+        onehot = (adder == row[c:c + 1]).astype(jnp.float32)  # (l_out, l)
+        y = jax.lax.dot_general(
+            p, onehot, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
+        )
+        acc = y if acc is None else acc + y
+    return acc
+
+
+def _dequant(m_blk, scale):
+    """Value block as f32, times its block scale when quantized."""
+    m = m_blk.astype(jnp.float32)
+    return m if scale is None else m * scale
+
+
+def _accumulate_out(y_ref, acc, first):
+    """Integrate-then-dump into the revisited (1, B_pad, l) window tile."""
+
+    @pl.when(first)
     def _init():
-        y_ref[...] = acc
+        y_ref[0] = acc
 
-    @pl.when(cb != 0)
+    @pl.when(jnp.logical_not(first))
     def _accum():
-        y_ref[...] += acc
+        y_ref[0] += acc
 
 
-def _kernel_q(m_ref, col_ref, row_ref, scale_ref, xs_ref, y_ref, *, l,
-              seg_count, c_blk, b):
-    cb = pl.program_id(1)
-    acc = block_accumulate(
-        m_ref, col_ref, row_ref, xs_ref,
-        l=l, seg_count=seg_count, c_blk=c_blk, b=b, scale=scale_ref[0, 0],
-    )
-
-    @pl.when(cb == 0)
-    def _init():
-        y_ref[...] = acc
-
-    @pl.when(cb != 0)
-    def _accum():
-        y_ref[...] += acc
+def _kernel(*refs, l, seg_count, num_cb, quantized):
+    scale_ref = refs[0] if quantized else None
+    m_ref, col_ref, row_ref, xs_ref, y_ref = refs[quantized:]
+    w, cb = pl.program_id(0), pl.program_id(1)
+    scale = None if scale_ref is None else scale_ref[w * num_cb + cb]
+    gs = _gather_resident(col_ref[...], xs_ref, l=l, seg_count=seg_count)
+    acc = route_rows(_dequant(m_ref[...], scale), gs, row_ref[...], l=l)
+    _accumulate_out(y_ref, acc, cb == 0)
 
 
 @functools.lru_cache(maxsize=256)
@@ -243,7 +235,7 @@ def make_gust_spmv(
     b: int,
     *,
     c_blk: int = 8,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     quantized: bool = False,
 ):
     """Build the resident-gather pallas_call for a fixed packed-schedule
@@ -257,97 +249,79 @@ def make_gust_spmv(
     BlockSpecs:
       * schedule stream (m/col/row): HBM -> VMEM tiles of (c_blk, l), one
         per grid step — the Buffer Filler pipeline;
-      * x (straight only; the flip is derived in-kernel): full-array VMEM
-        residency;
-      * y: one (1, l, B) accumulator tile per window, revisited across the
-        color-block (reduction) grid dimension.
+      * x (seg_count, B_pad, l), straight only (the flip is derived
+        in-kernel): full-array VMEM residency;
+      * y: one (1, B_pad, l) accumulator tile per window, revisited
+        across the color-block (reduction) grid dimension.
 
-    With ``quantized=True`` the returned function takes the per-block
-    scale column ``scale_blk.reshape(T_blk, 1)`` between the row block
-    and x: ``fn(m_blk, col_blk, row_blk, scale2d, xs)``.
+    Call signature: ``fn(m_blk, col_blk, row_blk, xs)``; with
+    ``quantized=True`` the per-block scales lead as the scalar-prefetch
+    operand: ``fn(scale_blk, m_blk, col_blk, row_blk, xs)``.
     """
     if c_pad % c_blk:
         raise ValueError("c_pad must be a multiple of c_blk")
     num_cb = c_pad // c_blk
+    bp = _batch_pad(b)
     grid = (num_windows, num_cb)
 
     sched_spec = pl.BlockSpec(
-        (c_blk, l), lambda w, cb: (w * num_cb + cb, 0)
+        (c_blk, l), lambda w, cb, *_: (w * num_cb + cb, 0)
     )
-    x_spec = pl.BlockSpec((seg_count, l, b), lambda w, cb: (0, 0, 0))
-    out_spec = pl.BlockSpec((1, l, b), lambda w, cb: (w, 0, 0))
-
-    in_specs = [sched_spec, sched_spec, sched_spec]
-    if quantized:
-        in_specs.append(
-            pl.BlockSpec((1, 1), lambda w, cb: (w * num_cb + cb, 0))
-        )
-    in_specs.append(x_spec)
+    x_spec = pl.BlockSpec((seg_count, bp, l), lambda w, cb, *_: (0, 0, 0))
+    out_spec = pl.BlockSpec((1, bp, l), lambda w, cb, *_: (w, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=int(quantized),
+        grid=grid,
+        in_specs=[sched_spec, sched_spec, sched_spec, x_spec],
+        out_specs=out_spec,
+    )
     kernel = functools.partial(
-        _kernel_q if quantized else _kernel,
-        l=l, seg_count=seg_count, c_blk=c_blk, b=b,
+        _kernel, l=l, seg_count=seg_count, num_cb=num_cb, quantized=quantized
     )
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_spec,
-        out_shape=jax.ShapeDtypeStruct((num_windows, l, b), jnp.float32),
-        interpret=interpret,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((num_windows, bp, l), jnp.float32),
+        interpret=_resolve_interpret(interpret),
     )
 
 
-def _local_flush(m_ref, row_ref, g, y_ref, first, *, l, c_blk, b, scale):
-    """Shared flush of the single-buffered local kernels: dequant (when
-    quantized) + VPU multiply of the gathered block + routing matmul,
-    then init-or-accumulate the window tile."""
-    m_blk = m_ref[...].astype(jnp.float32)  # (C_blk, l)
-    if scale is not None:
-        m_blk = m_blk * scale
-    partial = m_blk.T[:, :, None] * g  # (l, C_blk, B)
-    acc = route_rows(
-        partial, row_ref[...].astype(jnp.int32), c_blk=c_blk, l=l, b=b
-    )
-
-    @pl.when(first)
-    def _init():
-        y_ref[...] = acc
-
-    @pl.when(jnp.logical_not(first))
-    def _accum():
-        y_ref[...] += acc
+def gather_local_step(col_ref, xt_ref, s, g_scr, *, l):
+    """One segment-local gather step, shared by the padded and ragged
+    local kernels: fold the single streamed x tile ``xt_ref`` (the
+    block's ``s``-th referenced segment) into the (C_blk, B_pad, l)
+    gather scratch."""
+    seg, flip = _decode_cols(col_ref[...], l=l)
+    gs = tuple(g_scr[c] for c in range(g_scr.shape[0]))
+    for c, g in enumerate(_gather_tile(gs, seg, flip, s, xt_ref[0])):
+        g_scr[c] = g
 
 
-def _local_kernel(seg_ref, m_ref, col_ref, row_ref, xt_ref, y_ref, g_scr,
-                  *, l, s_blk, c_blk, b):
-    cb, s = pl.program_id(1), pl.program_id(2)
+def _local_flush(m_ref, row_ref, gs, y_ref, first, *, l, scale):
+    """Shared flush of the local kernels: _dequant (when quantized) + VPU
+    multiply of the gathered block + routing matmul, then
+    init-or-accumulate the window tile."""
+    acc = route_rows(_dequant(m_ref[...], scale), gs, row_ref[...], l=l)
+    _accumulate_out(y_ref, acc, first)
+
+
+def _local_kernel(*refs, l, s_blk, num_cb, quantized):
+    seg_ref = refs[0]
+    scale_ref = refs[1] if quantized else None
+    m_ref, col_ref, row_ref, xt_ref, y_ref, g_scr = refs[1 + quantized:]
+    w, cb, s = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(s == 0)
     def _zero():
         g_scr[...] = jnp.zeros_like(g_scr)
 
-    gather_local_step(col_ref, xt_ref, s, g_scr, l=l, c_blk=c_blk)
+    gather_local_step(col_ref, xt_ref, s, g_scr, l=l)
 
     @pl.when(s == s_blk - 1)
     def _flush():
-        _local_flush(m_ref, row_ref, g_scr[...], y_ref, cb == 0,
-                     l=l, c_blk=c_blk, b=b, scale=None)
-
-
-def _local_kernel_q(seg_ref, m_ref, col_ref, row_ref, scale_ref, xt_ref,
-                    y_ref, g_scr, *, l, s_blk, c_blk, b):
-    cb, s = pl.program_id(1), pl.program_id(2)
-
-    @pl.when(s == 0)
-    def _zero():
-        g_scr[...] = jnp.zeros_like(g_scr)
-
-    gather_local_step(col_ref, xt_ref, s, g_scr, l=l, c_blk=c_blk)
-
-    @pl.when(s == s_blk - 1)
-    def _flush():
-        _local_flush(m_ref, row_ref, g_scr[...], y_ref, cb == 0,
-                     l=l, c_blk=c_blk, b=b, scale=scale_ref[0, 0])
+        scale = None if scale_ref is None else scale_ref[w * num_cb + cb]
+        gs = tuple(g_scr[c] for c in range(g_scr.shape[0]))
+        _local_flush(m_ref, row_ref, gs, y_ref, cb == 0, l=l, scale=scale)
 
 
 @functools.lru_cache(maxsize=256)
@@ -359,20 +333,19 @@ def make_gust_spmv_local(
     b: int,
     *,
     c_blk: int = 8,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     quantized: bool = False,
 ):
     """Build the segment-local pallas_call for a padded-schedule geometry.
 
     Call signature of the returned function:
-    ``fn(seg_flat, m_blk, col_loc, row_blk, xs)`` where ``seg_flat`` is
-    the pack-time segment table flattened to ``(T_blk * S_blk,)`` int32
-    (scalar-prefetched: it steers the x-tile pipeline before each body
-    runs), ``col_loc`` holds the block-local columns, and ``xs`` is the
-    straight-layout x ``(seg_count, l, B)`` — which stays in HBM-sized
-    memory; only one (1, l, B) tile is in VMEM per grid step.  With
-    ``quantized=True`` the scale column ``scale_blk.reshape(T_blk, 1)``
-    is inserted after the row block.
+    ``fn(seg_flat, [scale_blk,] m_blk, col_loc, row_blk, xs)`` where
+    ``seg_flat`` is the pack-time segment table flattened to
+    ``(T_blk * S_blk,)`` int32 (scalar-prefetched: it steers the x-tile
+    pipeline before each body runs), ``col_loc`` holds the block-local
+    columns, and ``xs`` is the straight-layout x ``(seg_count, B_pad,
+    l)`` — which stays in HBM-sized memory; only one (1, B_pad, l) tile
+    is in VMEM per grid step.
 
     Grid ``(num_windows, c_pad/c_blk, S_blk)``: the inner dimension walks
     the ``S_blk`` x tiles the block references (``seg_flat[t*S_blk+s]``),
@@ -385,39 +358,32 @@ def make_gust_spmv_local(
     if c_pad % c_blk:
         raise ValueError("c_pad must be a multiple of c_blk")
     num_cb = c_pad // c_blk
+    bp = _batch_pad(b)
     grid = (num_windows, num_cb, s_blk)
 
     sched_spec = pl.BlockSpec(
-        (c_blk, l), lambda w, cb, s, seg: (w * num_cb + cb, 0)
+        (c_blk, l), lambda w, cb, s, seg, *_: (w * num_cb + cb, 0)
     )
     x_spec = pl.BlockSpec(
-        (1, l, b),
-        lambda w, cb, s, seg: (seg[(w * num_cb + cb) * s_blk + s], 0, 0),
+        (1, bp, l),
+        lambda w, cb, s, seg, *_: (seg[(w * num_cb + cb) * s_blk + s], 0, 0),
     )
-    out_spec = pl.BlockSpec((1, l, b), lambda w, cb, s, seg: (w, 0, 0))
-
-    in_specs = [sched_spec, sched_spec, sched_spec]
-    if quantized:
-        in_specs.append(
-            pl.BlockSpec((1, 1), lambda w, cb, s, seg: (w * num_cb + cb, 0))
-        )
-    in_specs.append(x_spec)
+    out_spec = pl.BlockSpec((1, bp, l), lambda w, cb, s, seg, *_: (w, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=1 + int(quantized),
         grid=grid,
-        in_specs=in_specs,
+        in_specs=[sched_spec, sched_spec, sched_spec, x_spec],
         out_specs=out_spec,
-        scratch_shapes=[pltpu.VMEM((l, c_blk, b), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((c_blk, bp, l), jnp.float32)],
     )
     kernel = functools.partial(
-        _local_kernel_q if quantized else _local_kernel,
-        l=l, s_blk=s_blk, c_blk=c_blk, b=b,
+        _local_kernel, l=l, s_blk=s_blk, num_cb=num_cb, quantized=quantized
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((num_windows, l, b), jnp.float32),
-        interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct((num_windows, bp, l), jnp.float32),
+        interpret=_resolve_interpret(interpret),
     )
 
 
@@ -440,15 +406,16 @@ def stream_copy(src_ref, scr_ref, sem, slot, start_row, rows):
     )
 
 
-def _db_kernel(m_ref, col_ref, row_ref, xs_ref, y_ref,
-               m_scr, col_scr, row_scr, sems,
-               *, l, seg_count, c_blk, num_cb, b, scale_ref=None):
+def _db_kernel(*refs, l, seg_count, c_blk, num_cb, quantized):
     """Double-buffered resident kernel body: grid (W,), the color-block
     reduction runs as an in-kernel fori_loop whose ping/pong scratch
     holds two schedule block triples — the DMA of triple ``i+1`` overlaps
     the gather/multiply/route of triple ``i``.  The f32 additions happen
     in the same order as the single-buffered kernel's revisited output
     tile, so the result is bitwise identical."""
+    scale_ref = refs[0] if quantized else None
+    (m_ref, col_ref, row_ref, xs_ref, y_ref,
+     m_scr, col_scr, row_scr, sems) = refs[quantized:]
     w = pl.program_id(0)
 
     def copies(slot, blk):
@@ -474,29 +441,14 @@ def _db_kernel(m_ref, col_ref, row_ref, xs_ref, y_ref,
 
         for c in copies(slot, i):
             c.wait()
-        m_blk = m_scr[slot].astype(jnp.float32)
-        if scale_ref is not None:
-            m_blk = m_blk * scale_ref[w * num_cb + i, 0]
-        return acc + block_math(
-            m_blk,
-            col_scr[slot].astype(jnp.int32),
-            row_scr[slot].astype(jnp.int32),
-            xs_ref[...].astype(jnp.float32),
-            l=l, seg_count=seg_count, c_blk=c_blk, b=b,
+        scale = None if scale_ref is None else scale_ref[w * num_cb + i]
+        gs = _gather_resident(col_scr[slot], xs_ref, l=l, seg_count=seg_count)
+        return acc + route_rows(
+            _dequant(m_scr[slot], scale), gs, row_scr[slot], l=l
         )
 
-    y_ref[...] = jax.lax.fori_loop(
-        0, num_cb, body, jnp.zeros((1, l, b), jnp.float32)
-    )
-
-
-def _db_kernel_q(m_ref, col_ref, row_ref, scale_ref, xs_ref, y_ref,
-                 m_scr, col_scr, row_scr, sems, *, l, seg_count, c_blk,
-                 num_cb, b):
-    _db_kernel(
-        m_ref, col_ref, row_ref, xs_ref, y_ref, m_scr, col_scr, row_scr,
-        sems, l=l, seg_count=seg_count, c_blk=c_blk, num_cb=num_cb, b=b,
-        scale_ref=scale_ref,
+    y_ref[0] = jax.lax.fori_loop(
+        0, num_cb, body, jnp.zeros(y_ref.shape[1:], jnp.float32)
     )
 
 
@@ -509,7 +461,7 @@ def make_gust_spmv_db(
     b: int,
     *,
     c_blk: int = 8,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     quantized: bool = False,
     value_dtype: str = "float32",
     index_dtype: str = "int32",
@@ -522,27 +474,23 @@ def make_gust_spmv_db(
     grid step (grid ``(W,)`` instead of ``(W, num_cb)``).
 
     The scratch dtypes must match the operands, so the builder takes the
-    stream's ``value_dtype``/``index_dtype`` names (the geometry memo now
-    includes them).  When ``quantized``, the (T_blk, 1) scale column is
-    small enough to sit whole in VMEM and is indexed per block inside the
-    loop."""
+    stream's ``value_dtype``/``index_dtype`` names (the geometry memo
+    includes them)."""
     if c_pad % c_blk:
         raise ValueError("c_pad must be a multiple of c_blk")
     num_cb = c_pad // c_blk
-    t_blk = num_windows * num_cb
+    bp = _batch_pad(b)
     vdt, idt = jnp.dtype(value_dtype), jnp.dtype(index_dtype)
 
-    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
-    in_specs = [any_spec, any_spec, any_spec]
-    if quantized:
-        in_specs.append(pl.BlockSpec((t_blk, 1), lambda w: (0, 0)))
-    in_specs.append(pl.BlockSpec((seg_count, l, b), lambda w: (0, 0, 0)))
-
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=0,
+        num_scalar_prefetch=int(quantized),
         grid=(num_windows,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, l, b), lambda w: (w, 0, 0)),
+        in_specs=[
+            any_spec, any_spec, any_spec,
+            pl.BlockSpec((seg_count, bp, l), lambda w, *_: (0, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, bp, l), lambda w, *_: (w, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, c_blk, l), vdt),
             pltpu.VMEM((2, c_blk, l), idt),
@@ -551,25 +499,25 @@ def make_gust_spmv_db(
         ],
     )
     kernel = functools.partial(
-        _db_kernel_q if quantized else _db_kernel,
-        l=l, seg_count=seg_count, c_blk=c_blk, num_cb=num_cb, b=b,
+        _db_kernel, l=l, seg_count=seg_count, c_blk=c_blk, num_cb=num_cb,
+        quantized=quantized,
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((num_windows, l, b), jnp.float32),
-        interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct((num_windows, bp, l), jnp.float32),
+        interpret=_resolve_interpret(interpret),
     )
 
 
-def _local_db_body(seg_ref, m_ref, col_ref, row_ref, xs_ref, y_ref,
-                   xt_scr, sems, t, first, *, l, s_blk, c_blk, b, scale):
-    """Shared double-buffered segment-local block: ping/pong the S_blk x
-    tiles of stream block ``t`` (``seg_ref[t*s_blk + s]`` steers each
-    copy), accumulating the gathered block in a fori_loop carry — the
-    same f32 additions, in the same order, as the single-buffered
-    kernel's gather scratch.  The column decode is hoisted out of the
-    tile loop (one decode per block instead of one per tile)."""
+def _local_db_block(seg_ref, col_ref, xs_ref, xt_scr, sems, t, *, l, s_blk):
+    """Shared double-buffered segment-local gather of stream block ``t``:
+    ping/pong its S_blk x tiles (``seg_ref[t*s_blk + s]`` steers each
+    copy), folding each into the per-cycle gather carry — the same
+    selects, in the same order, as the single-buffered kernel's gather
+    scratch.  The column decode is hoisted out of the tile loop (one
+    decode per block instead of one per tile).  Returns the gathered
+    tuple."""
 
     def copy(slot, s):
         return stream_copy(
@@ -577,11 +525,10 @@ def _local_db_body(seg_ref, m_ref, col_ref, row_ref, xs_ref, y_ref,
         )
 
     copy(0, 0).start()
-    local_seg, fsel = decode_local_cols(
-        col_ref[...].astype(jnp.int32), l=l, c_blk=c_blk
-    )
+    seg, flip = _decode_cols(col_ref[...], l=l)
+    c_blk, bp = seg.shape[0], xt_scr.shape[2]
 
-    def body(s, g):
+    def body(s, gs):
         slot = jax.lax.rem(s, 2)
 
         @pl.when(s + 1 < s_blk)
@@ -589,31 +536,21 @@ def _local_db_body(seg_ref, m_ref, col_ref, row_ref, xs_ref, y_ref,
             copy(1 - slot, s + 1).start()
 
         copy(slot, s).wait()
-        tile = xt_scr[slot].astype(jnp.float32)[0]  # (l, B)
-        return g + local_tile_delta(local_seg, fsel, tile, s)
+        return _gather_tile(gs, seg, flip, s, xt_scr[slot][0])
 
-    g = jax.lax.fori_loop(
-        0, s_blk, body, jnp.zeros((l, c_blk, b), jnp.float32)
-    )
-    _local_flush(m_ref, row_ref, g, y_ref, first,
-                 l=l, c_blk=c_blk, b=b, scale=scale)
+    return jax.lax.fori_loop(0, s_blk, body, _zeros_gs(c_blk, bp, l))
 
 
-def _local_db_kernel(seg_ref, m_ref, col_ref, row_ref, xs_ref, y_ref,
-                     xt_scr, sems, *, l, s_blk, c_blk, num_cb, b):
+def _local_db_kernel(*refs, l, s_blk, num_cb, quantized):
+    seg_ref = refs[0]
+    scale_ref = refs[1] if quantized else None
+    m_ref, col_ref, row_ref, xs_ref, y_ref, xt_scr, sems = refs[1 + quantized:]
     w, cb = pl.program_id(0), pl.program_id(1)
-    _local_db_body(seg_ref, m_ref, col_ref, row_ref, xs_ref, y_ref,
-                   xt_scr, sems, w * num_cb + cb, cb == 0,
-                   l=l, s_blk=s_blk, c_blk=c_blk, b=b, scale=None)
-
-
-def _local_db_kernel_q(seg_ref, m_ref, col_ref, row_ref, scale_ref, xs_ref,
-                       y_ref, xt_scr, sems, *, l, s_blk, c_blk, num_cb, b):
-    w, cb = pl.program_id(0), pl.program_id(1)
-    _local_db_body(seg_ref, m_ref, col_ref, row_ref, xs_ref, y_ref,
-                   xt_scr, sems, w * num_cb + cb, cb == 0,
-                   l=l, s_blk=s_blk, c_blk=c_blk, b=b,
-                   scale=scale_ref[0, 0])
+    t = w * num_cb + cb
+    gs = _local_db_block(seg_ref, col_ref, xs_ref, xt_scr, sems, t,
+                        l=l, s_blk=s_blk)
+    scale = None if scale_ref is None else scale_ref[t]
+    _local_flush(m_ref, row_ref, gs, y_ref, cb == 0, l=l, scale=scale)
 
 
 @functools.lru_cache(maxsize=256)
@@ -625,9 +562,8 @@ def make_gust_spmv_local_db(
     b: int,
     *,
     c_blk: int = 8,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     quantized: bool = False,
-    x_dtype: str = "float32",
 ):
     """Double-buffered twin of :func:`make_gust_spmv_local`: same call
     signature and bitwise-identical output.  The schedule blocks stay
@@ -642,36 +578,29 @@ def make_gust_spmv_local_db(
     if c_pad % c_blk:
         raise ValueError("c_pad must be a multiple of c_blk")
     num_cb = c_pad // c_blk
+    bp = _batch_pad(b)
     grid = (num_windows, num_cb)
-    xdt = jnp.dtype(x_dtype)
 
     sched_spec = pl.BlockSpec(
-        (c_blk, l), lambda w, cb, seg: (w * num_cb + cb, 0)
+        (c_blk, l), lambda w, cb, seg, *_: (w * num_cb + cb, 0)
     )
-    in_specs = [sched_spec, sched_spec, sched_spec]
-    if quantized:
-        in_specs.append(
-            pl.BlockSpec((1, 1), lambda w, cb, seg: (w * num_cb + cb, 0))
-        )
-    in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))
-
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=1 + int(quantized),
         grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, l, b), lambda w, cb, seg: (w, 0, 0)),
+        in_specs=[sched_spec, sched_spec, sched_spec,
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, bp, l), lambda w, cb, seg, *_: (w, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, 1, l, b), xdt),
+            pltpu.VMEM((2, 1, bp, l), jnp.float32),
             pltpu.SemaphoreType.DMA((2,)),
         ],
     )
     kernel = functools.partial(
-        _local_db_kernel_q if quantized else _local_db_kernel,
-        l=l, s_blk=s_blk, c_blk=c_blk, num_cb=num_cb, b=b,
+        _local_db_kernel, l=l, s_blk=s_blk, num_cb=num_cb, quantized=quantized
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((num_windows, l, b), jnp.float32),
-        interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct((num_windows, bp, l), jnp.float32),
+        interpret=_resolve_interpret(interpret),
     )

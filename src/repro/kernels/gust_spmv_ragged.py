@@ -14,7 +14,7 @@ pipeline before each kernel body runs:
 
   block_window (T_blk,)  — window id of block ``t``; indexes the output
                            BlockSpec so block ``t`` lands on its window's
-                           (1, l, B) accumulator tile;
+                           (1, B_pad, l) accumulator tile;
   block_starts (W + 1,)  — per-window block prefix; ``t ==
                            block_starts[block_window[t]]`` marks a
                            window's first block.
@@ -26,15 +26,16 @@ moves to the next window's tile — the paper's integrate-then-dump, minus
 the dead padding cycles.
 
 Like the padded flagship, the Buffer-Filler gather runs in one of two
-modes (shared math in :mod:`repro.kernels.gust_spmv`):
+modes, and every kernel shares the padded kernels' x / output layouts
+and per-block math (:mod:`repro.kernels.gust_spmv`):
 
   * **resident** (:func:`make_gust_spmv_ragged`): x fully VMEM-resident,
-    one-hot contraction over all ``seg_count`` segments;
+    the select walks all ``seg_count`` segments;
   * **segment-local** (:func:`make_gust_spmv_ragged_local`): a third
     scalar-prefetch operand — the pack-time ``seg_blk`` table — steers an
     inner ``S_blk`` grid dimension that streams only the x tiles block
     ``t`` references, shrinking per-block gather work from O(seg_count)
-    to O(S_blk) and x VMEM residency to a single (1, l, B) tile.
+    to O(S_blk) and x VMEM residency to a single (1, B_pad, l) tile.
 
 Double-buffered variants (PR 6), bitwise-identical to their
 single-buffered twins (same f32 additions in the same order):
@@ -50,13 +51,15 @@ single-buffered twins (same f32 additions in the same order):
     the column decode hoisted out of the tile loop.
 
 Every builder takes ``quantized=True`` to accept an int8 value stream
-plus the per-block scale column ``scale_blk.reshape(T_blk, 1)`` (dequant
-fused into the accumulate — see ``gust_spmv.py``).
+plus the per-block scales ``scale_blk`` (``(T_blk,)`` f32), the last
+scalar-prefetch operand (_dequant fused into the accumulate — see
+``gust_spmv.py``).
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -64,12 +67,15 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .gust_spmv import (
+    _accumulate_out,
+    _batch_pad,
+    _dequant,
+    _gather_resident,
+    _local_db_block,
     _local_flush,
-    block_accumulate,
-    block_math,
-    decode_local_cols,
+    _resolve_interpret,
     gather_local_step,
-    local_tile_delta,
+    route_rows,
     stream_copy,
 )
 
@@ -81,32 +87,16 @@ __all__ = [
 ]
 
 
-def _accumulate_out(y_ref, acc, first):
-    @pl.when(first)
-    def _init():
-        y_ref[...] = acc
-
-    @pl.when(jnp.logical_not(first))
-    def _accum():
-        y_ref[...] += acc
-
-
-def _kernel(bw_ref, bs_ref, m_ref, col_ref, row_ref, xs_ref, y_ref,
-            *, l, seg_count, c_blk, b, scale_ref=None):
+def _kernel(*refs, l, seg_count, quantized):
+    bw_ref, bs_ref = refs[:2]
+    scale_ref = refs[2] if quantized else None
+    m_ref, col_ref, row_ref, xs_ref, y_ref = refs[2 + quantized:]
     t = pl.program_id(0)
     w = bw_ref[t]
-    acc = block_accumulate(
-        m_ref, col_ref, row_ref, xs_ref,
-        l=l, seg_count=seg_count, c_blk=c_blk, b=b,
-        scale=None if scale_ref is None else scale_ref[0, 0],
-    )
+    scale = None if scale_ref is None else scale_ref[t]
+    gs = _gather_resident(col_ref[...], xs_ref, l=l, seg_count=seg_count)
+    acc = route_rows(_dequant(m_ref[...], scale), gs, row_ref[...], l=l)
     _accumulate_out(y_ref, acc, t == bs_ref[w])
-
-
-def _kernel_q(bw_ref, bs_ref, m_ref, col_ref, row_ref, scale_ref, xs_ref,
-              y_ref, *, l, seg_count, c_blk, b):
-    _kernel(bw_ref, bs_ref, m_ref, col_ref, row_ref, xs_ref, y_ref,
-            l=l, seg_count=seg_count, c_blk=c_blk, b=b, scale_ref=scale_ref)
 
 
 @functools.lru_cache(maxsize=256)
@@ -118,58 +108,56 @@ def make_gust_spmv_ragged(
     b: int,
     *,
     c_blk: int = 8,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     quantized: bool = False,
 ):
     """Build the resident-gather scalar-prefetch pallas_call for a
     ragged-stream geometry.
 
     Call signature of the returned function:
-    ``fn(block_window, block_starts, m_blk, col_blk, row_blk, xs)``
-    with the stream blocks ``(num_blocks * c_blk, l)`` and the straight
-    x layout ``(seg_count, l, b)`` (the lane-reversed layout is derived
-    in-kernel); returns ``(num_windows, l, b)`` f32 per-window
-    accumulators.  With ``quantized=True`` the scale column
-    ``scale_blk.reshape(T_blk, 1)`` is inserted after the row block.
+    ``fn(block_window, block_starts, [scale_blk,] m_blk, col_blk,
+    row_blk, xs)`` with the stream blocks ``(num_blocks * c_blk, l)`` and
+    the straight x layout ``(seg_count, B_pad, l)`` (the lane-reversed
+    layout is derived in-kernel); returns ``(num_windows, B_pad, l)`` f32
+    per-window accumulators.
 
     BlockSpecs:
       * schedule stream (m/col/row): HBM -> VMEM tiles of (c_blk, l), one
         real block per grid step — no padding blocks are ever streamed;
       * x (straight): full-array VMEM residency;
-      * y: the (1, l, b) accumulator tile of ``block_window[t]``,
+      * y: the (1, B_pad, l) accumulator tile of ``block_window[t]``,
         revisited across that window's contiguous blocks.
 
     Memoized on geometry, like the padded builder.
     """
+    bp = _batch_pad(b)
     grid = (num_blocks,)
-    sched_spec = pl.BlockSpec((c_blk, l), lambda t, bw, bs: (t, 0))
-    x_spec = pl.BlockSpec((seg_count, l, b), lambda t, bw, bs: (0, 0, 0))
-    out_spec = pl.BlockSpec((1, l, b), lambda t, bw, bs: (bw[t], 0, 0))
-
-    in_specs = [sched_spec, sched_spec, sched_spec]
-    if quantized:
-        in_specs.append(pl.BlockSpec((1, 1), lambda t, bw, bs: (t, 0)))
-    in_specs.append(x_spec)
+    sched_spec = pl.BlockSpec((c_blk, l), lambda t, bw, bs, *_: (t, 0))
+    x_spec = pl.BlockSpec(
+        (seg_count, bp, l), lambda t, bw, bs, *_: (0, 0, 0)
+    )
+    out_spec = pl.BlockSpec((1, bp, l), lambda t, bw, bs, *_: (bw[t], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=2 + int(quantized),
         grid=grid,
-        in_specs=in_specs,
+        in_specs=[sched_spec, sched_spec, sched_spec, x_spec],
         out_specs=out_spec,
     )
     kernel = functools.partial(
-        _kernel_q if quantized else _kernel,
-        l=l, seg_count=seg_count, c_blk=c_blk, b=b,
+        _kernel, l=l, seg_count=seg_count, quantized=quantized
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((num_windows, l, b), jnp.float32),
-        interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct((num_windows, bp, l), jnp.float32),
+        interpret=_resolve_interpret(interpret),
     )
 
 
-def _local_kernel(bw_ref, bs_ref, seg_ref, m_ref, col_ref, row_ref, xt_ref,
-                  y_ref, g_scr, *, l, s_blk, c_blk, b, scale_ref=None):
+def _local_kernel(*refs, l, s_blk, quantized):
+    bw_ref, bs_ref = refs[:2]
+    scale_ref = refs[3] if quantized else None
+    m_ref, col_ref, row_ref, xt_ref, y_ref, g_scr = refs[3 + quantized:]
     t, s = pl.program_id(0), pl.program_id(1)
     w = bw_ref[t]
 
@@ -177,22 +165,14 @@ def _local_kernel(bw_ref, bs_ref, seg_ref, m_ref, col_ref, row_ref, xt_ref,
     def _zero():
         g_scr[...] = jnp.zeros_like(g_scr)
 
-    gather_local_step(col_ref, xt_ref, s, g_scr, l=l, c_blk=c_blk)
+    gather_local_step(col_ref, xt_ref, s, g_scr, l=l)
 
     @pl.when(s == s_blk - 1)
     def _flush():
-        _local_flush(
-            m_ref, row_ref, g_scr[...], y_ref, t == bs_ref[w],
-            l=l, c_blk=c_blk, b=b,
-            scale=None if scale_ref is None else scale_ref[0, 0],
-        )
-
-
-def _local_kernel_q(bw_ref, bs_ref, seg_ref, m_ref, col_ref, row_ref,
-                    scale_ref, xt_ref, y_ref, g_scr, *, l, s_blk, c_blk, b):
-    _local_kernel(bw_ref, bs_ref, seg_ref, m_ref, col_ref, row_ref, xt_ref,
-                  y_ref, g_scr, l=l, s_blk=s_blk, c_blk=c_blk, b=b,
-                  scale_ref=scale_ref)
+        scale = None if scale_ref is None else scale_ref[t]
+        gs = tuple(g_scr[c] for c in range(g_scr.shape[0]))
+        _local_flush(m_ref, row_ref, gs, y_ref, t == bs_ref[w],
+                    l=l, scale=scale)
 
 
 @functools.lru_cache(maxsize=256)
@@ -204,55 +184,50 @@ def make_gust_spmv_ragged_local(
     b: int,
     *,
     c_blk: int = 8,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     quantized: bool = False,
 ):
     """Build the segment-local scalar-prefetch pallas_call for a
     ragged-stream geometry.
 
     Call signature of the returned function:
-    ``fn(block_window, block_starts, seg_flat, m_blk, col_loc, row_blk,
-    xs)`` — ``seg_flat`` is the pack-time segment table flattened to
-    ``(T_blk * S_blk,)`` int32 and ``col_loc`` the block-local columns.
-    With ``quantized=True`` the scale column is inserted after the row
-    block.  Grid ``(num_blocks, S_blk)``: the inner dimension streams the
-    x tile of segment ``seg_flat[t*S_blk + s]`` (one (1, l, B) tile in
-    VMEM per step), the gathered block accumulates in VMEM scratch, and
-    the multiply + routing matmul fire on the last tile.  Combines the
-    ragged stream's "no dead padding cycles" with the segment-local
-    gather's O(S_blk) per-block cost — the full GUST utilization story.
+    ``fn(block_window, block_starts, seg_flat, [scale_blk,] m_blk,
+    col_loc, row_blk, xs)`` — ``seg_flat`` is the pack-time segment table
+    flattened to ``(T_blk * S_blk,)`` int32 and ``col_loc`` the
+    block-local columns.  Grid ``(num_blocks, S_blk)``: the inner
+    dimension streams the x tile of segment ``seg_flat[t*S_blk + s]``
+    (one (1, B_pad, l) tile in VMEM per step), the gathered block
+    accumulates in VMEM scratch, and the multiply + routing matmul fire
+    on the last tile.  Combines the ragged stream's "no dead padding
+    cycles" with the segment-local gather's O(S_blk) per-block cost — the
+    full GUST utilization story.
     """
+    bp = _batch_pad(b)
     grid = (num_blocks, s_blk)
-    sched_spec = pl.BlockSpec((c_blk, l), lambda t, s, bw, bs, seg: (t, 0))
+    sched_spec = pl.BlockSpec(
+        (c_blk, l), lambda t, s, bw, bs, seg, *_: (t, 0)
+    )
     x_spec = pl.BlockSpec(
-        (1, l, b), lambda t, s, bw, bs, seg: (seg[t * s_blk + s], 0, 0)
+        (1, bp, l), lambda t, s, bw, bs, seg, *_: (seg[t * s_blk + s], 0, 0)
     )
     out_spec = pl.BlockSpec(
-        (1, l, b), lambda t, s, bw, bs, seg: (bw[t], 0, 0)
+        (1, bp, l), lambda t, s, bw, bs, seg, *_: (bw[t], 0, 0)
     )
-
-    in_specs = [sched_spec, sched_spec, sched_spec]
-    if quantized:
-        in_specs.append(
-            pl.BlockSpec((1, 1), lambda t, s, bw, bs, seg: (t, 0))
-        )
-    in_specs.append(x_spec)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=3 + int(quantized),
         grid=grid,
-        in_specs=in_specs,
+        in_specs=[sched_spec, sched_spec, sched_spec, x_spec],
         out_specs=out_spec,
-        scratch_shapes=[pltpu.VMEM((l, c_blk, b), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((c_blk, bp, l), jnp.float32)],
     )
     kernel = functools.partial(
-        _local_kernel_q if quantized else _local_kernel,
-        l=l, s_blk=s_blk, c_blk=c_blk, b=b,
+        _local_kernel, l=l, s_blk=s_blk, quantized=quantized
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((num_windows, l, b), jnp.float32),
-        interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct((num_windows, bp, l), jnp.float32),
+        interpret=_resolve_interpret(interpret),
     )
 
 
@@ -261,14 +236,16 @@ def make_gust_spmv_ragged_local(
 # ---------------------------------------------------------------------------
 
 
-def _db_kernel(bs_ref, m_ref, col_ref, row_ref, xs_ref, y_ref,
-               m_scr, col_scr, row_scr, sems,
-               *, l, seg_count, c_blk, b, scale_ref=None):
+def _db_kernel(*refs, l, seg_count, c_blk, quantized):
     """Grid (W,): window ``w`` walks its own ragged block range in a
     fori_loop, the schedule block triple double-buffered through manual
     async copies.  Same f32 additions in the same order as the
     single-buffered ragged kernel's revisited accumulator tile —
     bitwise identical."""
+    bs_ref = refs[0]
+    scale_ref = refs[1] if quantized else None
+    (m_ref, col_ref, row_ref, xs_ref, y_ref,
+     m_scr, col_scr, row_scr, sems) = refs[1 + quantized:]
     w = pl.program_id(0)
     t0 = bs_ref[w]
     count = bs_ref[w + 1] - t0
@@ -296,28 +273,15 @@ def _db_kernel(bs_ref, m_ref, col_ref, row_ref, xs_ref, y_ref,
 
         for c in copies(slot, t0 + i):
             c.wait()
-        m_blk = m_scr[slot].astype(jnp.float32)
-        if scale_ref is not None:
-            m_blk = m_blk * scale_ref[t0 + i, 0]
-        return acc + block_math(
-            m_blk,
-            col_scr[slot].astype(jnp.int32),
-            row_scr[slot].astype(jnp.int32),
-            xs_ref[...].astype(jnp.float32),
-            l=l, seg_count=seg_count, c_blk=c_blk, b=b,
+        scale = None if scale_ref is None else scale_ref[t0 + i]
+        gs = _gather_resident(col_scr[slot], xs_ref, l=l, seg_count=seg_count)
+        return acc + route_rows(
+            _dequant(m_scr[slot], scale), gs, row_scr[slot], l=l
         )
 
-    y_ref[...] = jax.lax.fori_loop(
-        0, count, body, jnp.zeros((1, l, b), jnp.float32)
+    y_ref[0] = jax.lax.fori_loop(
+        0, count, body, jnp.zeros(y_ref.shape[1:], jnp.float32)
     )
-
-
-def _db_kernel_q(bs_ref, m_ref, col_ref, row_ref, scale_ref, xs_ref, y_ref,
-                 m_scr, col_scr, row_scr, sems, *, l, seg_count, c_blk, b):
-    _db_kernel(bs_ref, m_ref, col_ref, row_ref, xs_ref, y_ref,
-               m_scr, col_scr, row_scr, sems,
-               l=l, seg_count=seg_count, c_blk=c_blk, b=b,
-               scale_ref=scale_ref)
 
 
 @functools.lru_cache(maxsize=256)
@@ -329,34 +293,30 @@ def make_gust_spmv_ragged_db(
     b: int,
     *,
     c_blk: int = 8,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     quantized: bool = False,
     value_dtype: str = "float32",
     index_dtype: str = "int32",
 ):
     """Double-buffered twin of :func:`make_gust_spmv_ragged`, grid
     ``(W,)``.  Call signature:
-    ``fn(block_starts, m_blk, col_blk, row_blk, [scale2d,] xs)`` —
+    ``fn(block_starts, [scale_blk,] m_blk, col_blk, row_blk, xs)`` —
     ``block_window`` is not needed (the window is the grid step; its
     block range comes from ``block_starts`` alone).  The schedule stream
     lives in ANY-space memory and ping/pongs through VMEM scratch sized
-    at the stream's actual dtypes; when quantized the (T_blk, 1) scale
-    column sits whole in VMEM."""
+    at the stream's actual dtypes."""
+    bp = _batch_pad(b)
     vdt, idt = jnp.dtype(value_dtype), jnp.dtype(index_dtype)
 
-    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
-    in_specs = [any_spec, any_spec, any_spec]
-    if quantized:
-        in_specs.append(pl.BlockSpec((num_blocks, 1), lambda w, bs: (0, 0)))
-    in_specs.append(
-        pl.BlockSpec((seg_count, l, b), lambda w, bs: (0, 0, 0))
-    )
-
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=1 + int(quantized),
         grid=(num_windows,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, l, b), lambda w, bs: (w, 0, 0)),
+        in_specs=[
+            any_spec, any_spec, any_spec,
+            pl.BlockSpec((seg_count, bp, l), lambda w, bs, *_: (0, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, bp, l), lambda w, bs, *_: (w, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, c_blk, l), vdt),
             pltpu.VMEM((2, c_blk, l), idt),
@@ -365,65 +325,30 @@ def make_gust_spmv_ragged_db(
         ],
     )
     kernel = functools.partial(
-        _db_kernel_q if quantized else _db_kernel,
-        l=l, seg_count=seg_count, c_blk=c_blk, b=b,
+        _db_kernel, l=l, seg_count=seg_count, c_blk=c_blk, quantized=quantized
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((num_windows, l, b), jnp.float32),
-        interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct((num_windows, bp, l), jnp.float32),
+        interpret=_resolve_interpret(interpret),
     )
 
 
-def _local_db_kernel(bw_ref, bs_ref, seg_ref, m_ref, col_ref, row_ref,
-                     xs_ref, y_ref, xt_scr, sems,
-                     *, l, s_blk, c_blk, b, scale_ref=None):
+def _local_db_kernel(*refs, l, s_blk, quantized):
     """Grid (num_blocks,): schedule blocks pipeline-managed, the block's
     S_blk x tiles double-buffered through manual async copies with the
     column decode hoisted out of the tile loop (the ragged twin of the
     padded ``_local_db_kernel``)."""
+    bw_ref, bs_ref, seg_ref = refs[:3]
+    scale_ref = refs[3] if quantized else None
+    m_ref, col_ref, row_ref, xs_ref, y_ref, xt_scr, sems = refs[3 + quantized:]
     t = pl.program_id(0)
     w = bw_ref[t]
-
-    def copy(slot, s):
-        return stream_copy(
-            xs_ref, xt_scr, sems.at[slot], slot, seg_ref[t * s_blk + s], 1
-        )
-
-    copy(0, 0).start()
-    local_seg, fsel = decode_local_cols(
-        col_ref[...].astype(jnp.int32), l=l, c_blk=c_blk
-    )
-
-    def body(s, g):
-        slot = jax.lax.rem(s, 2)
-
-        @pl.when(s + 1 < s_blk)
-        def _prefetch():
-            copy(1 - slot, s + 1).start()
-
-        copy(slot, s).wait()
-        tile = xt_scr[slot].astype(jnp.float32)[0]  # (l, B)
-        return g + local_tile_delta(local_seg, fsel, tile, s)
-
-    g = jax.lax.fori_loop(
-        0, s_blk, body, jnp.zeros((l, c_blk, b), jnp.float32)
-    )
-    _local_flush(
-        m_ref, row_ref, g, y_ref, t == bs_ref[w],
-        l=l, c_blk=c_blk, b=b,
-        scale=None if scale_ref is None else scale_ref[0, 0],
-    )
-
-
-def _local_db_kernel_q(bw_ref, bs_ref, seg_ref, m_ref, col_ref, row_ref,
-                       scale_ref, xs_ref, y_ref, xt_scr, sems,
-                       *, l, s_blk, c_blk, b):
-    _local_db_kernel(bw_ref, bs_ref, seg_ref, m_ref, col_ref, row_ref,
-                     xs_ref, y_ref, xt_scr, sems,
-                     l=l, s_blk=s_blk, c_blk=c_blk, b=b,
-                     scale_ref=scale_ref)
+    gs = _local_db_block(seg_ref, col_ref, xs_ref, xt_scr, sems, t,
+                        l=l, s_blk=s_blk)
+    scale = None if scale_ref is None else scale_ref[t]
+    _local_flush(m_ref, row_ref, gs, y_ref, t == bs_ref[w], l=l, scale=scale)
 
 
 @functools.lru_cache(maxsize=256)
@@ -435,9 +360,8 @@ def make_gust_spmv_ragged_local_db(
     b: int,
     *,
     c_blk: int = 8,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     quantized: bool = False,
-    x_dtype: str = "float32",
 ):
     """Double-buffered twin of :func:`make_gust_spmv_ragged_local`: same
     call signature and bitwise-identical output, grid ``(num_blocks,)``
@@ -445,30 +369,29 @@ def make_gust_spmv_ragged_local_db(
     in ANY-space memory; the block's referenced tiles ping/pong through
     a two-slot VMEM scratch so the fetch of tile ``s+1`` overlaps the
     gather of tile ``s``."""
-    xdt = jnp.dtype(x_dtype)
-    sched_spec = pl.BlockSpec((c_blk, l), lambda t, bw, bs, seg: (t, 0))
-    in_specs = [sched_spec, sched_spec, sched_spec]
-    if quantized:
-        in_specs.append(pl.BlockSpec((1, 1), lambda t, bw, bs, seg: (t, 0)))
-    in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))
-
+    bp = _batch_pad(b)
+    sched_spec = pl.BlockSpec(
+        (c_blk, l), lambda t, bw, bs, seg, *_: (t, 0)
+    )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=3 + int(quantized),
         grid=(num_blocks,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, l, b), lambda t, bw, bs, seg: (bw[t], 0, 0)),
+        in_specs=[sched_spec, sched_spec, sched_spec,
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(
+            (1, bp, l), lambda t, bw, bs, seg, *_: (bw[t], 0, 0)
+        ),
         scratch_shapes=[
-            pltpu.VMEM((2, 1, l, b), xdt),
+            pltpu.VMEM((2, 1, bp, l), jnp.float32),
             pltpu.SemaphoreType.DMA((2,)),
         ],
     )
     kernel = functools.partial(
-        _local_db_kernel_q if quantized else _local_db_kernel,
-        l=l, s_blk=s_blk, c_blk=c_blk, b=b,
+        _local_db_kernel, l=l, s_blk=s_blk, quantized=quantized
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((num_windows, l, b), jnp.float32),
-        interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct((num_windows, bp, l), jnp.float32),
+        interpret=_resolve_interpret(interpret),
     )

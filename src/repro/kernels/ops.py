@@ -19,7 +19,7 @@ from __future__ import annotations
 
 import functools
 import warnings
-from typing import Tuple, Union
+from typing import Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -36,6 +36,7 @@ from repro.core.packing import (
 from repro.resilience import faults
 
 from .gust_spmv import (
+    _batch_pad,
     make_gust_spmv,
     make_gust_spmv_db,
     make_gust_spmv_local,
@@ -96,14 +97,18 @@ def normalize_choice(name: str, value: str, allowed: Tuple[str, ...] = None):
 
 
 def _prep_x(x: jnp.ndarray, n: int, l: int) -> jnp.ndarray:
-    """Zero-pad x to (S*l, B) and reshape to the straight segment-major
-    VMEM layout (S, l, B).  The lane-reversed layout the fused gather
-    selects against is derived in-kernel (``xs[:, ::-1, :]``), so only
-    one copy of x crosses HBM->VMEM."""
+    """Zero-pad x (n, B) to (S*l, B_pad) f32 and lay it out as the
+    kernels' segment-major ``(S, B_pad, l)``: the hardware length on the
+    lanes, the batch on whole sublane groups.  The lane-reversed layout
+    the fused gather selects against is derived in-kernel, so only one
+    copy of x crosses HBM->VMEM."""
     seg_count = -(-n // l)
-    pad = seg_count * l - n
-    xp = jnp.pad(x, ((0, pad), (0, 0)))
-    return xp.reshape(seg_count, l, -1)
+    b = x.shape[1]
+    xp = jnp.pad(
+        x.astype(jnp.float32),
+        ((0, seg_count * l - n), (0, _batch_pad(b) - b)),
+    )
+    return xp.reshape(seg_count, l, -1).transpose(0, 2, 1)
 
 
 def _seg_flat(packed) -> jnp.ndarray:
@@ -113,18 +118,12 @@ def _seg_flat(packed) -> jnp.ndarray:
     return jnp.asarray(packed.seg_blk, jnp.int32).reshape(-1)
 
 
-def _scale2d(packed) -> jnp.ndarray:
-    """The per-block scale leaf as the (T_blk, 1) f32 column the
-    quantized kernels take."""
-    return jnp.asarray(packed.scale_blk, jnp.float32).reshape(-1, 1)
-
-
 def execute_spmm(
     packed: Union[PackedSchedule, RaggedSchedule],
     x: jnp.ndarray,
     *,
     use_kernel: bool = True,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     c_blk: int = 8,
     transpose_io: bool = False,
     gather: str = "auto",
@@ -175,7 +174,7 @@ def _execute_spmm_impl(
     x: jnp.ndarray,
     *,
     use_kernel: bool = True,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     c_blk: int = 8,
     transpose_io: bool = False,
     gather: str = "auto",
@@ -269,82 +268,47 @@ def _execute_spmm_impl(
         double = pipeline != "single"
         x2d = _prep_x(x, n, l)
         vdt, idt = str(packed.m_blk.dtype), str(packed.col_blk.dtype)
-        scale_args = (_scale2d(packed),) if quant else ()
+        # the per-block scales ride as the last scalar-prefetch operand
+        scale = (jnp.asarray(packed.scale_blk, jnp.float32),) if quant else ()
+        kw = dict(c_blk=packed.c_blk, interpret=interpret, quantized=quant)
+        stream = (packed.m_blk, packed.col_blk, packed.row_blk, x2d)
+        stream_loc = (packed.m_blk, packed.col_loc, packed.row_blk, x2d)
         if ragged:
+            steer = (packed.block_window, packed.block_starts)
             if gather == "local":
-                if double:
-                    fn = make_gust_spmv_ragged_local_db(
-                        packed.num_blocks, W, l, packed.s_blk, b,
-                        c_blk=packed.c_blk, interpret=interpret,
-                        quantized=quant, x_dtype=str(x2d.dtype),
-                    )
-                else:
-                    fn = make_gust_spmv_ragged_local(
-                        packed.num_blocks, W, l, packed.s_blk, b,
-                        c_blk=packed.c_blk, interpret=interpret,
-                        quantized=quant,
-                    )
-                y_win = fn(
-                    packed.block_window, packed.block_starts,
-                    _seg_flat(packed),
-                    packed.m_blk, packed.col_loc, packed.row_blk,
-                    *scale_args, x2d,
-                )
+                build = (make_gust_spmv_ragged_local_db if double
+                         else make_gust_spmv_ragged_local)
+                fn = build(packed.num_blocks, W, l, packed.s_blk, b, **kw)
+                y_win = fn(*steer, _seg_flat(packed), *scale, *stream_loc)
             elif double:
                 fn = make_gust_spmv_ragged_db(
                     packed.num_blocks, W, l, packed.seg_count, b,
-                    c_blk=packed.c_blk, interpret=interpret,
-                    quantized=quant, value_dtype=vdt, index_dtype=idt,
+                    value_dtype=vdt, index_dtype=idt, **kw,
                 )
-                y_win = fn(
-                    packed.block_starts,
-                    packed.m_blk, packed.col_blk, packed.row_blk,
-                    *scale_args, x2d,
-                )
+                y_win = fn(packed.block_starts, *scale, *stream)
             else:
                 fn = make_gust_spmv_ragged(
-                    packed.num_blocks, W, l, packed.seg_count, b,
-                    c_blk=packed.c_blk, interpret=interpret, quantized=quant,
+                    packed.num_blocks, W, l, packed.seg_count, b, **kw
                 )
-                y_win = fn(
-                    packed.block_window, packed.block_starts,
-                    packed.m_blk, packed.col_blk, packed.row_blk,
-                    *scale_args, x2d,
-                )
+                y_win = fn(*steer, *scale, *stream)
         elif gather == "local":
-            if double:
-                fn = make_gust_spmv_local_db(
-                    W, packed.c_pad, l, packed.s_blk, b,
-                    c_blk=packed.c_blk, interpret=interpret,
-                    quantized=quant, x_dtype=str(x2d.dtype),
-                )
-            else:
-                fn = make_gust_spmv_local(
-                    W, packed.c_pad, l, packed.s_blk, b,
-                    c_blk=packed.c_blk, interpret=interpret, quantized=quant,
-                )
-            y_win = fn(
-                _seg_flat(packed),
-                packed.m_blk, packed.col_loc, packed.row_blk,
-                *scale_args, x2d,
-            )
+            build = make_gust_spmv_local_db if double else make_gust_spmv_local
+            fn = build(W, packed.c_pad, l, packed.s_blk, b, **kw)
+            y_win = fn(_seg_flat(packed), *scale, *stream_loc)
         else:
-            eff_c_blk = packed.c_blk if quant else c_blk
+            if not quant:
+                kw["c_blk"] = c_blk
             if double:
                 fn = make_gust_spmv_db(
                     W, packed.c_pad, l, packed.seg_count, b,
-                    c_blk=eff_c_blk, interpret=interpret,
-                    quantized=quant, value_dtype=vdt, index_dtype=idt,
+                    value_dtype=vdt, index_dtype=idt, **kw,
                 )
             else:
-                fn = make_gust_spmv(
-                    W, packed.c_pad, l, packed.seg_count, b, c_blk=eff_c_blk,
-                    interpret=interpret, quantized=quant,
-                )
-            y_win = fn(
-                packed.m_blk, packed.col_blk, packed.row_blk,
-                *scale_args, x2d,
-            )
+                fn = make_gust_spmv(W, packed.c_pad, l, packed.seg_count, b,
+                                    **kw)
+            y_win = fn(*scale, *stream)
+        # (W, B_pad, l) kernel accumulators -> (W, l, B)
+        y_win = y_win[:, :b, :].transpose(0, 2, 1)
     else:
         seg_count = -(-n // l)
         xp = jnp.pad(x, ((0, seg_count * l - n), (0, 0)))
@@ -416,7 +380,7 @@ def gust_spmm(
     x: jnp.ndarray,
     *,
     use_kernel: bool = True,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     c_blk: int = 8,
 ) -> jnp.ndarray:
     """Legacy packed-entry shim: ``y = M @ x``, x (n, B) -> y (m, B).
@@ -438,7 +402,7 @@ def gust_spmm_auto(
     x: jnp.ndarray,
     *,
     use_kernel: bool = True,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     c_blk: int = 8,
     waste_threshold: float = None,
     cache=default_cache,

@@ -301,8 +301,6 @@ def run_cell(arch_id: str, shape_name: str, multi_pod: bool,
             ),
         }
         ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):  # one dict per device on jax<=0.4.x
-            ca = ca[0] if ca else {}
         rec["xla_cost"] = {
             "flops": float(ca.get("flops", -1.0)),
             "bytes": float(ca.get("bytes accessed", -1.0)),

@@ -44,7 +44,4 @@ def make_production_mesh(*, multi_pod: bool = False):
     shape, axes = mesh_shape(multi_pod)
     n = int(np.prod(shape))
     devs = require_devices(n)
-    try:
-        return jax.make_mesh(shape, axes, devices=devs)
-    except TypeError:  # older jax.make_mesh without devices kwarg
-        return jax.sharding.Mesh(np.array(devs).reshape(shape), axes)
+    return jax.make_mesh(shape, axes, devices=devs)

@@ -15,31 +15,77 @@ leaves; ``--ragged``/``--compact``/``--use-kernel`` map onto the plan's
 layout/dtype/backend knobs.  GUST decode shares the continuous-batching
 machinery with the dense path.
 
+``--no-reduced`` serves the published widths; ``--layers N`` cuts depth
+to the first N layers (the only cut at full width).  Without an
+installed fault plan, a request that ends FAILED makes the command exit
+nonzero: a lowering or execution error on the device is an error, not a
+statistic.
+
 Usage:
-    PYTHONPATH=src python -m repro.launch.serve --arch yi_6b --reduced \
+    PYTHONPATH=src python -m repro.launch.serve --arch yi_6b \
         --requests 6 --max-new 16 [--gust --density 0.2 --ragged --compact]
+    PYTHONPATH=src python -m repro.launch.serve --arch yi_6b --no-reduced \
+        --layers 4 --gust --density 0.1 --gust-length 256 --use-kernel
 """
 
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
+import os
+import sys
 import time
 
 import jax
 import numpy as np
 
-from repro.configs.base import get_arch
+from repro.configs.base import ArchConfig, get_arch
 from repro.models.model_zoo import build_model
+from repro.resilience import faults
 from repro.serving import GustServeConfig, ServeConfig, ServeLoop
 
 __all__ = ["run_serving"]
+
+#: Checkout-local compile cache used when JAX_COMPILATION_CACHE_DIR is
+#: unset (a fixed path: the cache is keyed by it, so it must not move).
+_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))),
+    ".jax_cache",
+)
+
+
+def _use_compile_cache() -> str:
+    """Turn on JAX's persistent compile cache.  ``JAX_COMPILATION_CACHE_DIR``
+    wins when set (JAX reads it itself, so no other directory is set
+    here); otherwise the checkout's ``.jax_cache``.  Returns the
+    directory in use."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", _CACHE_DIR)
+    return _CACHE_DIR
+
+
+def _arch(arch: str, *, reduced: bool, layers: int = None) -> ArchConfig:
+    """The served configuration: the reduced smoke widths or the published
+    ones, with depth optionally cut to ``layers``."""
+    cfg = get_arch(arch)
+    if reduced:
+        cfg = cfg.reduced()
+    if layers is not None:
+        if not 0 < layers <= cfg.n_layers:
+            raise ValueError(f"--layers must be in [1, {cfg.n_layers}]")
+        cfg = dataclasses.replace(cfg, n_layers=layers)
+    return cfg
 
 
 def run_serving(
     arch: str,
     *,
     reduced: bool = True,
+    layers: int = None,
     batch: int = 4,
     seq_len: int = 128,
     requests: int = 4,
@@ -59,11 +105,9 @@ def run_serving(
     deadline_steps: int = None,
     deadline_s: float = None,
 ):
-    cfg = get_arch(arch)
-    if reduced:
-        cfg = cfg.reduced()
+    cfg = _arch(arch, reduced=reduced, layers=layers)
     lm = build_model(cfg)
-    params = lm.init(jax.random.PRNGKey(seed))
+    params = jax.jit(lm.init)(jax.random.PRNGKey(seed))
     gcfg = None
     if gust:
         gcfg = GustServeConfig(
@@ -134,10 +178,15 @@ def run_serving(
     return done, stats
 
 
-def main():
+def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve the family-preserving smoke widths "
+                    "(default); --no-reduced serves the published widths")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut depth to the first N layers")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--seq-len", type=int, default=128)
     ap.add_argument("--requests", type=int, default=4)
@@ -167,9 +216,11 @@ def main():
                     "the request with status=TIMEOUT (tokens kept)")
     ap.add_argument("--deadline-s", type=float, default=None,
                     help="per-request wall-clock budget in seconds")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    _use_compile_cache()
     _, stats = run_serving(
-        args.arch, batch=args.batch, seq_len=args.seq_len,
+        args.arch, reduced=args.reduced, layers=args.layers,
+        batch=args.batch, seq_len=args.seq_len,
         requests=args.requests, prompt_len=args.prompt_len,
         max_new=args.max_new, gust=args.gust, density=args.density,
         gust_length=args.gust_length, use_kernel=args.use_kernel,
@@ -179,7 +230,13 @@ def main():
         deadline_steps=args.deadline_steps, deadline_s=args.deadline_s,
     )
     print(json.dumps(stats))
+    failed = stats["resilience"]["failed"]
+    if failed and not faults.enabled():
+        print(f"{failed} request(s) FAILED with no fault plan installed",
+              file=sys.stderr)
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

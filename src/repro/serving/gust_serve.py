@@ -98,7 +98,6 @@ class GustServeConfig:
             layout="ragged" if self.ragged else "padded",
             backend="pallas" if self.use_kernel else "jnp",
             gather=self.gather,
-            interpret=True,
             value_dtype=jnp.dtype(self.value_dtype).name,
             index_dtype=jnp.dtype(self.index_dtype).name,
         )

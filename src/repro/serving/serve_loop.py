@@ -101,6 +101,12 @@ def make_serve_fns(lm: LM, cfg: ServeConfig, gust_tree=None):
     prefills new requests at batch=1); ``decode_fn`` takes ``pos`` as a
     (B,) int32 vector of per-slot positions (a scalar still works for
     homogeneous callers such as the dry-run).
+
+    With GUST, ``decode_fn(params, caches, tokens, pos, leaves)`` takes
+    the stacked plan leaves (``{name: tree["mats"][name]["leaves"]}``) as
+    an argument: closed over, they would be embedded in the compiled
+    program as constants — hundreds of MB at published widths — and every
+    compile would copy them.  ``gust_tree`` supplies only the plan meta.
     """
     dtype = cfg.jnp_dtype
 
@@ -114,9 +120,13 @@ def make_serve_fns(lm: LM, cfg: ServeConfig, gust_tree=None):
         if gust_tree is None:
             raise ValueError("gust serving requires a gustify()/dryrun tree")
 
-        def decode_fn(params, caches, tokens, pos):
+        metas = {k: v["meta"] for k, v in gust_tree["mats"].items()}
+
+        def decode_fn(params, caches, tokens, pos, leaves):
+            tree = {"mats": {k: {"leaves": leaves[k], "meta": metas[k]}
+                             for k in metas}}
             return decode_step_gust(
-                lm, params, gust_tree, caches, tokens, pos,
+                lm, params, tree, caches, tokens, pos,
                 cfg=cfg.gust, dtype=dtype,
             )
     else:
@@ -187,6 +197,10 @@ class ServeLoop:
         if cfg.gust is not None and cfg.gust.enable:
             gust_tree = gustify(lm, params, cfg.gust)
         self.gust_tree = gust_tree
+        # the plan leaves ride as a decode argument, never as constants
+        self._decode_extra = () if gust_tree is None else (
+            {k: v["leaves"] for k, v in gust_tree["mats"].items()},
+        )
         pre, dec, init = make_serve_fns(lm, cfg, gust_tree)
         self._prefill = jax.jit(pre)
         self._decode = jax.jit(dec)
@@ -442,7 +456,8 @@ class ServeLoop:
         try:
             faults.trip("serve.decode")
             logits, new_caches = self._decode(
-                self.params, self.caches, jnp.asarray(toks), jnp.asarray(pos)
+                self.params, self.caches, jnp.asarray(toks), jnp.asarray(pos),
+                *self._decode_extra,
             )
             sampled = self._sample_rows(
                 logits[:, 0],

@@ -94,9 +94,11 @@ def test_kernel_vs_ref_on_packed_blocks():
     )
     from repro.kernels.gust_spmv import make_gust_spmv
 
-    x2d = xp.reshape(seg, 8, 3)
+    # kernel layout: (segment, batch padded to 8 sublanes, lane)
+    xs = jnp.pad(xp, ((0, 0), (0, 5))).reshape(seg, 8, 8).transpose(0, 2, 1)
     fn = make_gust_spmv(packed.num_windows, packed.c_pad, 8, seg, 3)
-    y_k = np.asarray(fn(packed.m_blk, packed.col_blk, packed.row_blk, x2d))
+    y_k = np.asarray(fn(packed.m_blk, packed.col_blk, packed.row_blk, xs))
+    y_k = y_k[:, :3, :].transpose(0, 2, 1)
     np.testing.assert_allclose(y_k, y_ref, rtol=1e-5, atol=1e-5)
 
 
@@ -114,7 +116,9 @@ def test_gather_fill_kernel(l, seg, b):
     offs = np.where(flip, l - 1 - lanes, lanes)
     cols = (segs * l + offs).astype(np.int32)
     fn = make_gather_fill(total, l, seg, b)
-    x2d = jnp.asarray(x).reshape(seg, l, b)
-    out = np.asarray(fn(jnp.asarray(cols), x2d))
+    # kernel layout: (segment, batch padded to 8 sublanes, lane)
+    xs = jnp.pad(jnp.asarray(x), ((0, 0), (0, 8 - b)))
+    xs = xs.reshape(seg, l, 8).transpose(0, 2, 1)
+    out = np.asarray(fn(jnp.asarray(cols), xs))[:, :b, :].transpose(0, 2, 1)
     ref = np.asarray(gather_fill_ref(jnp.asarray(cols), jnp.asarray(x)))
     np.testing.assert_allclose(out, ref, rtol=1e-6, atol=1e-6)

@@ -106,6 +106,7 @@ def test_train_driver_with_resume(tmp_path):
 def test_serve_driver_gust(tmp_path):
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "jax_cache")
     p = subprocess.run(
         [sys.executable, "-m", "repro.launch.serve", "--arch", "yi_6b",
          "--requests", "2", "--max-new", "3", "--gust", "--density", "0.5",
@@ -116,3 +117,52 @@ def test_serve_driver_gust(tmp_path):
     stats = json.loads(p.stdout.strip().splitlines()[-1])
     assert stats["requests"] == 2 and stats["gust"]
     assert all(0 < u <= 1 for u in stats["gust_stream_utilization"].values())
+
+
+def test_serve_published_widths_and_depth_cut(monkeypatch, tmp_path):
+    """``--no-reduced`` reaches the published widths and ``--layers`` cuts
+    only depth; the default stays the reduced smoke model."""
+    from repro.launch import serve
+
+    full = serve._arch("yi_6b", reduced=False, layers=4)
+    assert (full.d_model, full.d_ff, full.n_heads, full.n_kv, full.head_dim,
+            full.vocab, full.n_layers) == (4096, 11008, 32, 4, 128, 64000, 4)
+    assert serve._arch("yi_6b", reduced=True).d_model == 64
+    with pytest.raises(ValueError):
+        serve._arch("yi_6b", reduced=False, layers=33)
+
+    seen = []
+
+    def fake_run(arch, **kw):
+        seen.append(kw)
+        return {}, {"resilience": {"failed": 0}}
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    monkeypatch.setattr(serve, "run_serving", fake_run)
+    assert serve.main(["--arch", "yi_6b", "--no-reduced", "--layers", "4"]) == 0
+    assert serve.main(["--arch", "yi_6b"]) == 0
+    assert (seen[0]["reduced"], seen[0]["layers"]) == (False, 4)
+    assert (seen[1]["reduced"], seen[1]["layers"]) == (True, None)
+
+
+def test_serve_exits_nonzero_on_failed_request(monkeypatch, tmp_path, capsys):
+    """A FAILED request is an error unless a fault plan injected it."""
+    from repro.launch import serve
+    from repro.resilience.faults import FaultPlan, FaultSpec, injected
+    from repro.serving.serve_loop import ServeLoop
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    argv = ["--arch", "yi_6b", "--requests", "2", "--max-new", "2"]
+    assert serve.main(argv) == 0
+
+    with injected(FaultPlan([FaultSpec("serve.admit", times=-1)], seed=0)):
+        assert serve.main(argv) == 0  # failures were injected on purpose
+    stats = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert stats["resilience"]["failed"] == 2
+
+    def broken_admit(self, *a, **kw):
+        raise RuntimeError("kernel refused to lower")
+
+    monkeypatch.setattr(ServeLoop, "_admit", broken_admit)
+    assert serve.main(argv) == 1
+    assert "FAILED" in capsys.readouterr().err

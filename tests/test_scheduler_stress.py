@@ -248,6 +248,42 @@ def test_parallel_falls_back_serial_below_threshold():
     assert sched_counters["parallel_chunks"] == 0
 
 
+def test_parallel_coloring_after_jax_initialized():
+    """A process whose JAX backend is up (a server holding the chip, its
+    runtime multithreaded) still colors in parallel above the auto
+    threshold: the workers start from a forkserver instead of forking this
+    process, return within the time limit, and match serial bitwise."""
+    import threading
+
+    import jax.numpy as jnp
+
+    from repro.core.scheduler import DEFAULT_PARALLEL_MIN_EDGES
+
+    jnp.zeros(8).block_until_ready()  # initialize the backend
+    rng = np.random.default_rng(11)
+    m, n, l = 16384, 4096, 256
+    flat = np.unique(rng.integers(0, m * n, 2_200_000))
+    coo = COOMatrix((m, n), flat // n, flat % n,
+                    np.ones(flat.shape[0], np.float32))
+    win, row_local, lane, _, _, _ = _build_edges(coo, l, False)
+    row_key, lane_key, num_windows = win * l + row_local, win * l + lane, m // l
+    assert row_key.shape[0] > DEFAULT_PARALLEL_MIN_EDGES
+    want = color_edges_fast(row_key, lane_key)
+
+    reset_sched_counters()
+    out = {}
+    worker = threading.Thread(
+        target=lambda: out.update(got=color_windows_chunked(
+            row_key, lane_key, win, num_windows, l, workers=2)),
+        daemon=True,
+    )
+    worker.start()
+    worker.join(timeout=240)
+    assert not worker.is_alive(), "parallel coloring hung"
+    assert sched_counters["parallel_chunks"] >= 2  # the pool really ran
+    assert np.array_equal(out["got"], want)
+
+
 # ---------------------------------------------------------------------------
 # Bit-identity: paper colorer done-mask fix vs the old sorted-dict loop
 # ---------------------------------------------------------------------------
