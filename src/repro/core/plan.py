@@ -691,7 +691,8 @@ class GustPlan:
     def spmv(self, v: jnp.ndarray) -> jnp.ndarray:
         """Single-vector execution: ``v (n,) -> y (m,)``.  On a sharded
         plan (:meth:`shard`) this runs k parallel length-l GUSTs over
-        contiguous window ranges and concatenates collectives-free."""
+        contiguous window ranges, with no collective, and concatenates
+        their rows on the mesh's first device."""
         v = jnp.asarray(v)
         m, n = self.shape
         if v.shape != (n,):
@@ -787,16 +788,17 @@ class GustPlan:
             layout = _shard_layout(self.artifact, n_dev)
         m_d, r_d, c_d, lw_d, w_max, idx = layout
         fn = _shard_spmv_fn(self.mesh, self.axis, c.l, c.c_blk, w_max)
-        # replicate the per-device outputs before the host-order gather:
-        # on a mesh with explicit axis types an eager gather from a
-        # sharded operand has no unambiguous output sharding
+        # Reassemble on the mesh's first device: each other device's rows
+        # are copied there once (the shard_map program runs no
+        # collective, and an eager gather from a sharded operand has no
+        # unambiguous output sharding on an explicit-axis mesh).  Device
+        # d's first w_cnt[d]*l rows are its window range in order; the
+        # gather below concatenates them, then undoes the load-balancing
+        # row sort.
         y_dev = jax.device_put(
             fn(m_d, r_d, c_d, lw_d, v),
-            jax.sharding.NamedSharding(self.mesh, jax.sharding.PartitionSpec()),
+            jax.sharding.SingleDeviceSharding(self.mesh.devices.flat[0]),
         )
-        # Reassemble: device d's first w_cnt[d]*l rows are its window range
-        # in order (collectives-free concatenation), then undo the
-        # load-balancing row sort.
         m = self.shape[0]
         if self.sched is not None:
             y_sorted = y_dev.reshape(-1)[idx][:m]

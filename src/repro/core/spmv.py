@@ -178,8 +178,8 @@ def distributed_spmv(
     row-windows across ``axis`` (contiguous window ranges balanced by
     ragged-stream block count; the schedule is untouched — paper: "the
     Edge-Coloring schedule would not need to change").  The vector is
-    replicated; outputs concatenate without collectives because windows
-    own disjoint output rows.
+    replicated; the outputs need no collective because windows own
+    disjoint output rows, and are concatenated on the mesh's first device.
 
     Routes through ``repro.plan(sched, ...).shard(mesh, axis).spmv(v)`` —
     the plan owns the device-major layout memoization (``cache="default"``

@@ -17,6 +17,7 @@ import warnings
 
 import numpy as np
 import pytest
+from conftest import run_spmd_subprocess
 
 import jax
 import jax.numpy as jnp
@@ -358,6 +359,28 @@ def test_plan_shard_single_device_matches_dense():
     np.testing.assert_allclose(y, dense @ v, rtol=1e-4, atol=1e-4)
     with pytest.raises(NotImplementedError):
         p.shard(mesh, "data").spmm(jnp.zeros((32, 2), jnp.float32))
+
+
+def test_plan_shard_four_devices_explicit_mesh_assembles_on_one_device():
+    # jax.make_mesh gives explicit axis types; the sharded SpMV must equal
+    # the one-device SpMV bitwise and leave y on one device, not replicated
+    out = run_spmd_subprocess(
+        """
+import jax, jax.numpy as jnp, numpy as np
+from repro.core.plan import PlanConfig, plan
+rng = np.random.default_rng(3)
+dense = (rng.random((200, 120)) < 0.1) * rng.standard_normal((200, 120))
+v = jnp.asarray(rng.standard_normal(120).astype(np.float32))
+p = plan(dense.astype(np.float32), PlanConfig(l=8, layout="ragged", backend="jnp"))
+mesh = jax.make_mesh((4,), ("x",))
+y = p.shard(mesh, "x").spmv(v)
+assert len(y.sharding.device_set) == 1, y.sharding
+assert np.array_equal(np.asarray(y), np.asarray(p.spmv(v)))
+print("OK")
+""",
+        devices=4,
+    )
+    assert out.strip().endswith("OK")
 
 
 # ---------------------------------------------------------------------------
