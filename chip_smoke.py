@@ -33,8 +33,9 @@ the same plan and a float64 reference.
 
 Earlier stdout lines are one JSON object per phase.  On success the last
 line is ``{"ok": true, "device": {...}}``; a failed check, a missing TPU
-or a missing ``repro`` package exits nonzero without printing it.
-Matmuls run at the default precision, as in ``launch/serve.py``.
+or a missing ``repro`` package exits nonzero without printing it, and so
+does a child process (the coloring workers' forkserver or resource
+tracker) still running once they are stopped.  Matmuls run at the default precision, as in ``launch/serve.py``.
 """
 
 from __future__ import annotations
@@ -374,6 +375,7 @@ def main(argv=None) -> int:
               file=sys.stderr)
         return 2
     sys.path.insert(0, os.path.join(HERE, "src"))
+    from repro.core.scheduler import stop_workers
     from repro.launch.serve import _arch, _use_compile_cache
 
     cache = _use_compile_cache()
@@ -413,11 +415,30 @@ def main(argv=None) -> int:
             gcfgs = variants()
             phase_exact(gcfgs, n=cfg.d_model, batch=BATCH, seed=args.seed)
             phase_serve(cfg, gcfgs, seed=args.seed)
+        stop_workers()
+        left = children()
+        check(not left, f"processes still running: {left}")
     except SmokeFailure as err:
         print(f"chip_smoke: FAILED: {err}", file=sys.stderr)
         return 1
     print(json.dumps({"ok": True, "device": device}))
     return 0
+
+
+def children() -> list:
+    """Command lines of this process's live child processes (Linux)."""
+    pids = []
+    for task in os.listdir("/proc/self/task"):
+        with open(f"/proc/self/task/{task}/children") as f:
+            pids += f.read().split()
+    out = []
+    for pid in pids:
+        try:
+            with open(f"/proc/{pid}/cmdline", "rb") as f:
+                out.append(f.read().replace(b"\0", b" ").decode().strip())
+        except OSError:  # exited meanwhile
+            pass
+    return out
 
 
 if __name__ == "__main__":

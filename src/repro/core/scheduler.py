@@ -28,6 +28,7 @@ All three satisfy: validity, completeness, C_w >= Δ_w (Eq. 1 bound).
 
 from __future__ import annotations
 
+import atexit
 import hashlib
 import os
 from typing import Dict, Optional, Sequence, Tuple
@@ -46,6 +47,7 @@ __all__ = [
     "incremental_schedule",
     "window_fingerprints",
     "resolve_workers",
+    "stop_workers",
     "sched_counters",
     "reset_sched_counters",
     "DEFAULT_PARALLEL_MIN_EDGES",
@@ -502,7 +504,27 @@ def _worker_context():
         return None
     ctx = mp.get_context("forkserver")
     ctx.set_forkserver_preload([__name__])
+    global _stop_registered
+    if not _stop_registered:
+        atexit.register(stop_workers)
+        _stop_registered = True
     return ctx
+
+
+_stop_registered = False
+
+
+def stop_workers() -> None:
+    """Stop the helper processes the parallel colorer leaves running: the
+    forkserver, then the shared-memory resource tracker it holds open.
+    Both would otherwise outlive this process by a moment, until they see
+    its pipes close.  Each stop waits for its process to exit; a later
+    parallel coloring starts them again.  Registered at exit once the
+    parallel colorer has run; calling it more than once is harmless."""
+    from multiprocessing import forkserver, resource_tracker
+
+    forkserver._forkserver._stop()
+    resource_tracker._resource_tracker._stop()
 
 
 def color_windows_chunked(

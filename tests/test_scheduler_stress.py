@@ -15,6 +15,8 @@ seeded deterministic slice runs the same bodies (same policy as
 ``test_quant_property.py`` — CI images may lack hypothesis).
 """
 
+import os
+
 import numpy as np
 import pytest
 
@@ -282,6 +284,34 @@ def test_parallel_coloring_after_jax_initialized():
     assert not worker.is_alive(), "parallel coloring hung"
     assert sched_counters["parallel_chunks"] >= 2  # the pool really ran
     assert np.array_equal(out["got"], want)
+
+
+def test_stop_workers_ends_helper_processes():
+    """After a parallel coloring the forkserver and the resource tracker
+    are running; ``stop_workers`` ends both (and reaps them), is harmless
+    when repeated, and a later parallel coloring starts them again."""
+    from multiprocessing import forkserver, resource_tracker
+
+    from repro.core.scheduler import stop_workers
+
+    rng = np.random.default_rng(3)
+    row_key, lane_key, win, num_windows = _edges_for(
+        empty_window_dense(rng, 96, 64, 8, density=0.15), 8)
+    want = color_edges_fast(row_key, lane_key)
+    for _ in range(2):
+        reset_sched_counters()
+        got = color_windows_chunked(row_key, lane_key, win, num_windows, 8,
+                                    workers=2)
+        assert sched_counters["parallel_chunks"] >= 2
+        assert np.array_equal(got, want)
+        pids = [forkserver._forkserver._forkserver_pid,
+                resource_tracker._resource_tracker._pid]
+        assert all(pids)
+        stop_workers()
+        stop_workers()
+        for pid in pids:
+            with pytest.raises(ProcessLookupError):
+                os.kill(pid, 0)
 
 
 # ---------------------------------------------------------------------------
