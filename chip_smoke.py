@@ -175,9 +175,9 @@ def phase_serve(cfg, gcfgs, *, seed: int) -> None:
               "so the kernel backend would run the jnp path")
         emit("gustify", variant=name, seconds=time.perf_counter() - t0,
              interpret=_interpret(g) if g.use_kernel else None,
-             stream_utilization={k: v["stream_utilization"]
-                                 for k, v in tree["stats"].items()
-                                 if "stream_utilization" in v},
+             stream_utilization={k: tree["stats"][k]["stream_utilization"]
+                                 for k in g.mats},
+             build_s=tree["stats"]["build_s"],
              sched_counters=dict(sched_counters), **memory())
         if g.use_kernel:
             check(not _interpret(g), f"{name}: kernels would run interpreted")

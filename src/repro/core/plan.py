@@ -47,6 +47,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from .formats import COOMatrix, GustSchedule, coo_from_dense
+from .scheduler import sched_counters
+from .spans import Span
 from .packing import (
     PackedSchedule,
     RaggedSchedule,
@@ -204,8 +206,8 @@ class PlanCost:
     are the Eq. 9-11 statistical bounds at the matrix's measured density.
 
     The gather-locality block (PR 5) quantifies both Buffer-Filler modes
-    without executing — this is what ``dryrun``/``roofline_report`` read
-    to show the segment-local win:
+    without executing — this is what ``dryrun`` reads to show the
+    segment-local win:
 
     * ``s_blk`` / ``locality_ratio`` — measured per-block segment working
       set and its ratio to ``seg_count`` (the ``gather="auto"`` signal);
@@ -419,18 +421,19 @@ def plan(
             p.summary = record.get("summary")
             return p
 
-    if cache is None:
-        from .scheduler import schedule as _schedule
+    with Span("build.colour", sched_counters, "colour_s"):
+        if cache is None:
+            from .scheduler import schedule as _schedule
 
-        sched = _schedule(
-            matrix, config.l, load_balance=config.load_balance,
-            method=config.colorer, workers=workers,
-        )
-    else:
-        sched = cache.schedule(
-            matrix, config.l, load_balance=config.load_balance,
-            method=config.colorer, workers=workers,
-        )
+            sched = _schedule(
+                matrix, config.l, load_balance=config.load_balance,
+                method=config.colorer, workers=workers,
+            )
+        else:
+            sched = cache.schedule(
+                matrix, config.l, load_balance=config.load_balance,
+                method=config.colorer, workers=workers,
+            )
     p = GustPlan(config, sched=sched, cache=cache, source=_source)
     p._store = store
     p._store_key = store_key
@@ -521,7 +524,8 @@ class GustPlan:
         write either."""
         if self._artifact is None:
             faults.trip("pack.materialize")
-            self._artifact = self._pack()
+            with Span("build.pack", sched_counters, "pack_s"):
+                self._artifact = self._pack()
             self._store_put()
         return self._artifact
 

@@ -59,17 +59,22 @@ __all__ = [
 #: untouched (the zero-coloring-work gate in ``benchmarks/sched_bench.py``).
 #: ``parallel_chunks`` counts chunks actually colored by worker processes
 #: (0 when the serial fallback ran), ``windows_recolored`` /
-#: ``windows_reused`` track incremental rescheduling.
-sched_counters: Dict[str, int] = {
+#: ``windows_reused`` track incremental rescheduling.  ``colour_s`` /
+#: ``pack_s`` are host seconds in ``repro.plan``'s schedule call and in
+#: packing a plan's artifact (the ``build.colour`` / ``build.pack`` spans
+#: of ``core/plan.py``).
+sched_counters: Dict[str, float] = {
     "color_calls": 0,
     "colored_edges": 0,
     "parallel_chunks": 0,
     "windows_recolored": 0,
     "windows_reused": 0,
+    "colour_s": 0.0,
+    "pack_s": 0.0,
 }
 
 
-def reset_sched_counters() -> Dict[str, int]:
+def reset_sched_counters() -> Dict[str, float]:
     """Zero all scheduler counters; returns the (mutable) counter dict."""
     for k in sched_counters:
         sched_counters[k] = 0

@@ -70,4 +70,5 @@ def make_gather_fill(
         out_specs=pl.BlockSpec((c_blk, bp, l), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((total_rows, bp, l), jnp.float32),
         interpret=_resolve_interpret(interpret),
+        name="gust_gather_fill",
     )

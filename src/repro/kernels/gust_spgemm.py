@@ -163,4 +163,5 @@ def make_gust_spgemm(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((num_windows, l, n_out), jnp.float32),
         interpret=_resolve_interpret(interpret),
+        name="gust_spgemm",
     )

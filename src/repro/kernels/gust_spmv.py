@@ -283,6 +283,7 @@ def make_gust_spmv(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((num_windows, bp, l), jnp.float32),
         interpret=_resolve_interpret(interpret),
+        name="gust_spmv_padded_resident",
     )
 
 
@@ -384,6 +385,7 @@ def make_gust_spmv_local(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((num_windows, bp, l), jnp.float32),
         interpret=_resolve_interpret(interpret),
+        name="gust_spmv_padded_local",
     )
 
 
@@ -507,6 +509,7 @@ def make_gust_spmv_db(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((num_windows, bp, l), jnp.float32),
         interpret=_resolve_interpret(interpret),
+        name="gust_spmv_padded_resident_db",
     )
 
 
@@ -603,4 +606,5 @@ def make_gust_spmv_local_db(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((num_windows, bp, l), jnp.float32),
         interpret=_resolve_interpret(interpret),
+        name="gust_spmv_padded_local_db",
     )

@@ -151,6 +151,7 @@ def make_gust_spmv_ragged(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((num_windows, bp, l), jnp.float32),
         interpret=_resolve_interpret(interpret),
+        name="gust_spmv_ragged_resident",
     )
 
 
@@ -228,6 +229,7 @@ def make_gust_spmv_ragged_local(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((num_windows, bp, l), jnp.float32),
         interpret=_resolve_interpret(interpret),
+        name="gust_spmv_ragged_local",
     )
 
 
@@ -332,6 +334,7 @@ def make_gust_spmv_ragged_db(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((num_windows, bp, l), jnp.float32),
         interpret=_resolve_interpret(interpret),
+        name="gust_spmv_ragged_resident_db",
     )
 
 
@@ -394,4 +397,5 @@ def make_gust_spmv_ragged_local_db(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((num_windows, bp, l), jnp.float32),
         interpret=_resolve_interpret(interpret),
+        name="gust_spmv_ragged_local_db",
     )

@@ -157,21 +157,31 @@ def run_serving(
         "slot_occupancy": round(loop.occupancy, 4),
         "mode": "serial" if serial else "continuous",
         "gust": bool(gust),
+        # the serve loop's spans (serve.step, serve.admit, serve.wait, ...):
+        # host milliseconds per decode step
+        "serve_ms_per_step": {
+            k[len("serve."):-len("_s")]: round(
+                v / max(loop.stats["decode_steps"], 1) * 1e3, 3)
+            for k, v in loop.stats.items() if k.startswith("serve.")
+        },
         # lifecycle + degradation counters (PR 10): terminal statuses
         # and the process-wide fallback counters
         "resilience": loop.resilience_stats(),
     }
     if gust and loop.gust_tree is not None:
-        # per-matrix entries only — "plan_store" is the store's counter dict
-        mat_stats = {
-            k: v for k, v in loop.gust_tree["stats"].items()
-            if k != "plan_store"
-        }
+        mat_stats = {k: loop.gust_tree["stats"][k] for k in gcfg.mats}
         stats["gust_stream_utilization"] = {
             k: round(v["stream_utilization"], 4) for k, v in mat_stats.items()
         }
         stats["gust_streamed_slots"] = {
             k: v["streamed_slots"] for k, v in mat_stats.items()
+        }
+        # gustify's wall time and its phases (prune, colour, pack, stack,
+        # upload), host seconds
+        stats["gustify_s"] = round(loop.gust_tree["stats"]["gustify_s"], 3)
+        stats["gust_build_s"] = {
+            k: round(v, 3)
+            for k, v in loop.gust_tree["stats"]["build_s"].items()
         }
         if "plan_store" in loop.gust_tree["stats"]:
             stats["gust_plan_store"] = loop.gust_tree["stats"]["plan_store"]

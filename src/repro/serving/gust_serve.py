@@ -29,6 +29,7 @@ decode path without running the scheduler.
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -42,6 +43,8 @@ from repro.core.gust_linear import prune_by_magnitude
 from repro.core.packing import default_cache, stacked_leaf_specs
 from repro.core.plan import GustPlan, PlanConfig, plan
 from repro.core.plan_store import PlanStore
+from repro.core.scheduler import sched_counters
+from repro.core.spans import Span
 from repro.models import transformer as T
 from repro.models.layers import apply_norm
 from repro.models.model_zoo import LM
@@ -128,7 +131,14 @@ def gustify(lm: LM, params, cfg: GustServeConfig, *,
 
     Returns ``{"mats": {name: {"leaves": {...(R, ...)}, "meta": static
     layout tuple}}, "stats": {...}}`` — per matrix, the
-    :meth:`GustPlan.stack` of one plan per layer.
+    :meth:`GustPlan.stack` of one plan per layer.  ``stats`` holds one
+    entry per matrix, ``gustify_s`` (this call's host seconds) and
+    ``build_s``: the seconds of its phases, each a span
+    (:class:`~repro.core.spans.Span`) — ``prune`` (weights to the host,
+    magnitude pruning), ``colour`` and ``pack`` (``repro.plan``'s spans,
+    read off ``sched_counters``), ``stack`` (equalize and stack the
+    layers) and ``upload`` (wait until the stacked leaves are on the
+    device).
 
     With ``cfg.plan_store`` (or an explicit ``store``), plans read
     through the persistent :class:`PlanStore`: a warm start rebuilds
@@ -141,22 +151,32 @@ def gustify(lm: LM, params, cfg: GustServeConfig, *,
         )
     if store is None and cfg.plan_store is not None:
         store = PlanStore(cfg.plan_store, verify=cfg.store_verify)
+    t0 = time.perf_counter()
     mlp_params = params["stack"]["reps"][0]["mlp"]
     reps = lm.stack.reps
     pc = cfg.plan_config
+    phases = dict.fromkeys(("prune", "colour", "pack", "stack", "upload"), 0.0)
     out: Dict = {"mats": {}, "stats": {}}
     fb0 = dict(fallback_counters)  # attribute downgrades to this build
+    sc0 = {k: sched_counters[k] for k in ("colour_s", "pack_s")}
+    plans_of = {}
     for name in cfg.mats:
-        w_stack = np.asarray(mlp_params[name])  # (R, d_in, d_out)
+        with Span("build.prune", phases, "prune"):
+            w_stack = np.asarray(mlp_params[name])  # (R, d_in, d_out)
         # one plan per layer, through the content-keyed cache: re-gustifying
         # the same weights (e.g. a compact re-export) reuses the schedule
-        plans = [
-            plan(_prune_to_coo(w_stack[r], cfg), pc, cache=default_cache,
-                 store=store)
-            for r in range(reps)
-        ]
-        stacked = GustPlan.stack(plans)
-        out["mats"][name] = stacked
+        plans = []
+        for r in range(reps):
+            with Span("build.prune", phases, "prune"):
+                coo = _prune_to_coo(w_stack[r], cfg)
+            plans.append(plan(coo, pc, cache=default_cache, store=store))
+        arts = [p.artifact for p in plans]  # packs each layer (build.pack)
+        with Span("build.stack", phases, "stack"):
+            out["mats"][name] = GustPlan.stack(arts)
+        plans_of[name] = plans
+    with Span("build.upload", phases, "upload"):
+        jax.block_until_ready([m["leaves"] for m in out["mats"].values()])
+    for name, plans in plans_of.items():
         # uniform stream size after stacking = max over layers (stack()
         # equalizes to it); read off the artifacts, not meta positions
         if cfg.ragged:
@@ -165,7 +185,7 @@ def gustify(lm: LM, params, cfg: GustServeConfig, *,
             }
         else:
             size_stat = {"c_pad": max(p.artifact.c_pad for p in plans)}
-        leaves = stacked["leaves"]
+        leaves = out["mats"][name]["leaves"]
         nnz = int(np.count_nonzero(np.asarray(leaves["m_blk"])))
         slots = leaves["m_blk"].size
         out["stats"][name] = {
@@ -174,6 +194,9 @@ def gustify(lm: LM, params, cfg: GustServeConfig, *,
             "streamed_slots": int(slots),
             **size_stat,
         }
+    phases["colour"] = sched_counters["colour_s"] - sc0["colour_s"]
+    phases["pack"] = sched_counters["pack_s"] - sc0["pack_s"]
+    out["stats"]["build_s"] = phases
     if store is not None:
         out["stats"]["plan_store"] = store.stats()
     fb = {k: v - fb0[k] for k, v in fallback_counters.items() if v - fb0[k]}
@@ -181,6 +204,7 @@ def gustify(lm: LM, params, cfg: GustServeConfig, *,
         # degradations applied while building (e.g. stored -> fresh on a
         # failing store read): counted, surfaced, never an exception
         out["stats"]["fallbacks"] = fb
+    out["stats"]["gustify_s"] = time.perf_counter() - t0
     return out
 
 
@@ -227,7 +251,8 @@ def decode_step_gust(lm: LM, params, gust, caches, tokens, pos, *,
         h = apply_norm(p_sl["ln_attn"], x, kind=bc.norm_kind)
         from repro.models import attention as A
 
-        y, cache = A.decode_step(p_sl["attn"], h, bc.attn, c_sl, pos)
+        with jax.named_scope("attn"):  # attention and its KV update
+            y, cache = A.decode_step(p_sl["attn"], h, bc.attn, c_sl, pos)
         x = x + y
         h = apply_norm(p_sl["ln_mlp"], x, kind=bc.norm_kind)
         x = x + _gust_mlp(g_sl, metas, h, bc.mlp_kind, cfg)

@@ -41,6 +41,15 @@ streams are bitwise equal to a fault-free run.
 Per-request outputs are bit-identical to a solo run of the same request
 (locked by tests/test_serving.py): decode compute is row-independent and
 admission writes are slot-local.
+
+Every ``step`` is a ``serve.step`` span (:class:`~repro.core.spans.Span`)
+holding ``serve.admit`` per admission (``serve.prefill``, ``serve.insert``,
+``serve.first_token``), ``serve.decode`` (inputs, decode and sampler
+dispatch), ``serve.wait`` (the host blocked on the sampled tokens) and
+``serve.retire`` (per-slot bookkeeping); each span's seconds add up in
+``stats`` under its name plus ``_s`` (``serve.step_s``, ...).  The prefill
+program runs under the ``prefill`` named scope, so its device operations
+carry it in their op names.
 """
 
 from __future__ import annotations
@@ -54,6 +63,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.spans import Span
 from repro.models.model_zoo import LM
 from repro.resilience import faults
 from repro.resilience.fallback import fallback_counters
@@ -114,7 +124,8 @@ def make_serve_fns(lm: LM, cfg: ServeConfig, gust_tree=None):
         return lm.init_caches(batch or cfg.batch, cfg.seq_len, dtype)
 
     def prefill_fn(params, batch, caches):
-        return lm.prefill(params, batch, caches, dtype=dtype)
+        with jax.named_scope("prefill"):
+            return lm.prefill(params, batch, caches, dtype=dtype)
 
     if cfg.gust is not None and cfg.gust.enable:
         if gust_tree is None:
@@ -220,6 +231,7 @@ class ServeLoop:
         self.completed: Dict[int, List[int]] = {}
         self.results: Dict[int, RequestResult] = {}
         self._decode_failures = 0  # consecutive contained step failures
+        self._step_num = 0  # the serve.step span's step number
         self.stats = {
             "decode_steps": 0, "active_slot_steps": 0, "prefills": 0,
             "done": 0, "failed": 0, "timeouts": 0, "shed": 0,
@@ -350,15 +362,21 @@ class ServeLoop:
         length in the stream compiles once (exact-length prefill is what
         keeps admission bit-identical to a solo run; length bucketing
         needs masked prefill — see ROADMAP open items)."""
-        faults.trip("serve.admit", tag=str(rid))
-        logits, one = self._prefill(
-            self.params,
-            {"tokens": jnp.asarray(prompt)[None]},
-            self._cache_template_b1,
-        )
-        self.caches = self._insert(self.caches, one, i)
-        first = int(self._sample_rows(logits[:, -1], [(rid, 0)])[0])
-        self.stats["prefills"] += 1
+        st = self.stats
+        with Span("serve.admit", st, rid=rid, prompt_len=int(prompt.shape[0])):
+            faults.trip("serve.admit", tag=str(rid))
+            with Span("serve.prefill", st):
+                logits, one = self._prefill(
+                    self.params,
+                    {"tokens": jnp.asarray(prompt)[None]},
+                    self._cache_template_b1,
+                )
+            with Span("serve.insert", st):
+                self.caches = self._insert(self.caches, one, i)
+            with Span("serve.first_token", st):
+                first = int(np.asarray(
+                    self._sample_rows(logits[:, -1], [(rid, 0)]))[0])
+        st["prefills"] += 1
         slot = _Slot(
             True, rid, int(prompt.shape[0]), [first], max_new,
             deadline_steps=(
@@ -397,12 +415,13 @@ class ServeLoop:
 
     # -- sampling ----------------------------------------------------------
     def _sample_rows(self, logits_rows, rid_step: List[Tuple[int, int]]):
-        """Sample one token per row.  ``rid_step[r] = (request_id, token
-        index)`` seeds row r's key, making each request's sampled
-        continuation independent of which other requests share the batch."""
-        return np.asarray(self._sampler(
+        """Sample one token per row (dispatched; the result stays on the
+        device).  ``rid_step[r] = (request_id, token index)`` seeds row
+        r's key, making each request's sampled continuation independent
+        of which other requests share the batch."""
+        return self._sampler(
             logits_rows, self._base_key, jnp.asarray(rid_step, jnp.int32)
-        ))
+        )
 
     def _finished(self, slot: _Slot, token: int) -> bool:
         if self.cfg.eos_id is not None and token == self.cfg.eos_id:
@@ -443,33 +462,43 @@ class ServeLoop:
         ``cfg.max_step_failures`` consecutive contained failures the
         active set retires FAILED (definite status) instead of spinning.
         """
+        self._step_num += 1
+        with Span("serve.step", self.stats, step=self._step_num):
+            return self._step()
+
+    def _step(self) -> int:
+        st = self.stats
         self._admit_from_queue()
         self._expire_deadlines()
         active = [i for i, s in enumerate(self.slots) if s.active]
         if not active:
             return 0
-        toks = np.zeros((self.cfg.batch, 1), np.int32)
-        pos = np.zeros((self.cfg.batch,), np.int32)
-        for i in active:
-            toks[i, 0] = self.slots[i].generated[-1]
-            pos[i] = self.slots[i].pos
         try:
-            faults.trip("serve.decode")
-            logits, new_caches = self._decode(
-                self.params, self.caches, jnp.asarray(toks), jnp.asarray(pos),
-                *self._decode_extra,
-            )
-            sampled = self._sample_rows(
-                logits[:, 0],
-                [
-                    # inactive rows sample garbage that is discarded; any
-                    # non-negative key seed works (fold_in is uint32)
-                    (s.request_id, len(s.generated)) if s.active else (0, 0)
-                    for s in self.slots
-                ],
-            )
+            with Span("serve.decode", st):
+                toks = np.zeros((self.cfg.batch, 1), np.int32)
+                pos = np.zeros((self.cfg.batch,), np.int32)
+                for i in active:
+                    toks[i, 0] = self.slots[i].generated[-1]
+                    pos[i] = self.slots[i].pos
+                faults.trip("serve.decode")
+                logits, new_caches = self._decode(
+                    self.params, self.caches, jnp.asarray(toks),
+                    jnp.asarray(pos), *self._decode_extra,
+                )
+                sampled = self._sample_rows(
+                    logits[:, 0],
+                    [
+                        # inactive rows sample garbage that is discarded;
+                        # any non-negative key seed works (fold_in is uint32)
+                        (s.request_id, len(s.generated)) if s.active
+                        else (0, 0)
+                        for s in self.slots
+                    ],
+                )
+            with Span("serve.wait", st):
+                sampled = np.asarray(sampled)
         except Exception as err:  # sanctioned containment (GUST-L07 site)
-            self.stats["decode_retries"] = self.stats.get("decode_retries", 0) + 1
+            st["decode_retries"] = st.get("decode_retries", 0) + 1
             self._decode_failures += 1
             if self._decode_failures >= self.cfg.max_step_failures:
                 for i in active:
@@ -487,28 +516,29 @@ class ServeLoop:
             return len([s for s in self.slots if s.active])
         self._decode_failures = 0
         self.caches = new_caches
-        self.stats["decode_steps"] += 1
-        self.stats["active_slot_steps"] += len(active)
-        for i in active:
-            s = self.slots[i]
-            try:
-                faults.trip("serve.slot", tag=str(s.request_id))
-                tok = int(sampled[i])
-                s.generated.append(tok)
-                s.pos += 1
-                s.steps += 1
-                if self._finished(s, tok):
+        st["decode_steps"] += 1
+        st["active_slot_steps"] += len(active)
+        with Span("serve.retire", st):
+            for i in active:
+                s = self.slots[i]
+                try:
+                    faults.trip("serve.slot", tag=str(s.request_id))
+                    tok = int(sampled[i])
+                    s.generated.append(tok)
+                    s.pos += 1
+                    s.steps += 1
+                    if self._finished(s, tok):
+                        self._retire(
+                            s.request_id, RequestStatus.DONE, s.generated,
+                            steps=s.steps,
+                        )
+                        self.slots[i] = _Slot()
+                except Exception as err:  # contained: one slot, one request
                     self._retire(
-                        s.request_id, RequestStatus.DONE, s.generated,
-                        steps=s.steps,
+                        s.request_id, RequestStatus.FAILED, s.generated,
+                        reason=f"slot fault: {err!r}", steps=s.steps,
                     )
                     self.slots[i] = _Slot()
-            except Exception as err:  # contained: one slot, one request
-                self._retire(
-                    s.request_id, RequestStatus.FAILED, s.generated,
-                    reason=f"slot fault: {err!r}", steps=s.steps,
-                )
-                self.slots[i] = _Slot()
         return len([s for s in self.slots if s.active])
 
     @property

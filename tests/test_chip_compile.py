@@ -2,7 +2,8 @@
 
 Each test compiles one kernel builder for a v5e chip that is described,
 not attached (the TPU compiler is installed with jax), and asserts that
-the compiled program holds the Mosaic kernel (``tpu_custom_call``).  This
+the compiled program holds the Mosaic kernel (``tpu_custom_call``) under
+the kernel's family name, the name a device trace shows it by.  This
 catches what interpret mode cannot: unlowerable primitives, block shapes
 off the (8, 128) tiling, and VMEM overruns.
 
@@ -12,6 +13,7 @@ decoded at batch 4; stream lengths are the planner's at that density.
 """
 
 import os
+import re
 
 import pytest
 
@@ -62,6 +64,15 @@ def _compile_text(fn, args):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _assert_kernel(text, family):
+    """The compiled text holds a Mosaic custom call named ``family``."""
+    calls = [ln.strip().removeprefix("ROOT ") for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert calls, "no tpu_custom_call in the compiled program"
+    names = [c.split(" = ", 1)[0] for c in calls]
+    assert any(re.fullmatch(rf"%{family}(\.\d+)?", n) for n in names), names
+
+
 @pytest.mark.parametrize("dtypes", sorted(DTYPES))
 @pytest.mark.parametrize("mat", sorted(SHAPES))
 @pytest.mark.parametrize("layout,gather,pipeline", KERNELS)
@@ -85,6 +96,7 @@ def test_spmv_kernel_compiles_for_v5e(one_chip, layout, gather, pipeline,
     kw = dict(c_blk=C_BLK, interpret=False, quantized=quant)
     dkw = dict(value_dtype=vdt, index_dtype=idt)
     double = pipeline == "double"
+    family = f"gust_spmv_{layout}_{gather}" + ("_db" if double else "")
     if layout == "padded":
         if gather == "local":
             build = K.make_gust_spmv_local_db if double else K.make_gust_spmv_local
@@ -105,7 +117,7 @@ def test_spmv_kernel_compiles_for_v5e(one_chip, layout, gather, pipeline,
             pre = steer[1:]
         else:
             fn, pre = R.make_gust_spmv_ragged(t_blk, w, L, seg, B, **kw), steer
-    assert "tpu_custom_call" in _compile_text(fn, pre + scale + stream)
+    _assert_kernel(_compile_text(fn, pre + scale + stream), family)
 
 
 @pytest.mark.parametrize("mat", sorted(SHAPES))
@@ -117,7 +129,7 @@ def test_gather_fill_compiles_for_v5e(one_chip, mat):
     fn = make_gather_fill(rows, L, seg, B, c_blk=C_BLK, interpret=False)
     args = (jax.ShapeDtypeStruct((rows, L), jnp.int32, sharding=one_chip),
             jax.ShapeDtypeStruct((seg, 8, L), jnp.float32, sharding=one_chip))
-    assert "tpu_custom_call" in _compile_text(fn, args)
+    _assert_kernel(_compile_text(fn, args), "gust_gather_fill")
 
 
 def test_spgemm_compiles_for_v5e(one_chip):
@@ -138,4 +150,4 @@ def test_spgemm_compiles_for_v5e(one_chip):
             spec((t_blk * C_BLK, L), jnp.int32),
             spec((r_rows, k_max), jnp.float32),
             spec((r_rows, k_max), jnp.int32))
-    assert "tpu_custom_call" in _compile_text(fn, args)
+    _assert_kernel(_compile_text(fn, args), "gust_spgemm")

@@ -117,6 +117,13 @@ def test_serve_driver_gust(tmp_path):
     stats = json.loads(p.stdout.strip().splitlines()[-1])
     assert stats["requests"] == 2 and stats["gust"]
     assert all(0 < u <= 1 for u in stats["gust_stream_utilization"].values())
+    # the serve loop's spans per decode step and gustify's build phases
+    per_step = stats["serve_ms_per_step"]
+    assert {"step", "admit", "prefill", "decode", "wait", "retire"} <= set(per_step)
+    assert per_step["step"] >= per_step["wait"] + per_step["admit"] - 1e-2
+    assert set(stats["gust_build_s"]) == {"prune", "colour", "pack", "stack",
+                                          "upload"}
+    assert sum(stats["gust_build_s"].values()) <= stats["gustify_s"] + 1e-2
 
 
 def test_serve_published_widths_and_depth_cut(monkeypatch, tmp_path):
