@@ -225,14 +225,17 @@ def _itemsize(node: Optional[ast.AST], env: Dict[str, object]) -> int:
 
 def _bind_assigns(fn: ast.FunctionDef, env: Dict[str, object]) -> None:
     """Interpret the builder's simple local assignments into ``env``:
-    integer arithmetic plus ``jnp.dtype(<name>)`` (bound to its
-    itemsize).  Anything richer is skipped."""
+    integer arithmetic, ``_batch_pad`` / ``_resident_x_rows`` and
+    ``jnp.dtype(<name>)`` (bound to its itemsize).  Anything richer is
+    skipped."""
 
     def value_of(node: ast.AST):
         if isinstance(node, ast.Call) and isinstance(node.func, ast.Name) \
-                and node.func.id == "_batch_pad" and len(node.args) == 1:
-            b = _eval(node.args[0], env)
-            return -(-b // _SUBLANES) * _SUBLANES
+                and node.func.id in ("_batch_pad", "_resident_x_rows") \
+                and len(node.args) == 1:
+            # both round their argument up to whole groups of 8
+            v = _eval(node.args[0], env)
+            return -(-v // _SUBLANES) * _SUBLANES
         if isinstance(node, ast.Call) and isinstance(node.func, ast.Attribute) \
                 and node.func.attr == "dtype" and node.args:
             name = node.args[0]

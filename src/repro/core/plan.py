@@ -106,7 +106,7 @@ class PlanConfig:
       backend:         ``jnp`` | ``pallas`` | ``auto`` (Pallas on TPU when
                        the schedule is fusable).
       gather:          Buffer-Filler mode — ``resident`` (x whole in
-                       VMEM, one-hot over every column segment),
+                       VMEM, a walk over every column segment),
                        ``local`` (stream only the ``S_blk`` x tiles each
                        block references via the pack-time segment table),
                        or ``auto`` (segment-local when the measured
@@ -214,6 +214,9 @@ class PlanCost:
     * ``gather_flops_resident`` / ``gather_flops_local`` — fused-gather
       FLOPs per vector column: ``4 · slots · seg_count`` vs
       ``4 · slots · S_blk`` (two one-hot contractions, 2 flops/MAC);
+    * ``gather_walk_steps`` — steps of the resident walk per color block,
+      ``ceil(seg_count / 8)``: each step gathers one group of eight
+      column segments with sublane gathers;
     * ``x_vmem_bytes_resident`` / ``x_vmem_bytes_local`` — f32 x-tile
       VMEM residency per vector column: the whole padded vector
       (``seg_count · l · 4``) vs one block's tile working set
@@ -263,6 +266,7 @@ class PlanCost:
     locality_ratio: float
     gather_flops_resident: int
     gather_flops_local: int
+    gather_walk_steps: int
     x_vmem_bytes_resident: int
     x_vmem_bytes_local: int
     backend: str = "jnp"
@@ -1114,6 +1118,7 @@ class GustPlan:
             expected_execution_cycles,
             expected_utilization,
         )
+        from repro.kernels.gust_spmv import _resident_walk_steps
 
         if self.sched is None:
             raise ValueError(
@@ -1144,6 +1149,7 @@ class GustPlan:
             locality_ratio=a.s_blk / max(a.seg_count, 1),
             gather_flops_resident=4 * streamed * a.seg_count,
             gather_flops_local=4 * streamed * a.s_blk,
+            gather_walk_steps=_resident_walk_steps(a.seg_count),
             x_vmem_bytes_resident=a.seg_count * self.l * 4,
             x_vmem_bytes_local=a.s_blk * self.l * 4,
             backend="pallas" if self._use_kernel() else "jnp",

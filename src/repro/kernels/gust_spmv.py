@@ -12,9 +12,13 @@ TPU adaptation of the paper's three hardware levels (DESIGN.md §2):
                   select, never random access):
 
                   * **resident** (``make_gust_spmv``): the vector lives
-                    whole in VMEM and each block walks all
-                    ``seg_count = ceil(n/l)`` column segments —
-                    O(seg_count) gather work per slot, O(n) VMEM;
+                    whole in VMEM with its lane-reversed twin, and each
+                    block walks the ``seg_count = ceil(n/l)`` column
+                    segments eight at a time: a slot's segment ``seg``
+                    is sublane ``seg % 8`` of group ``seg // 8``, so one
+                    sublane gather per group, batch row and orientation
+                    fetches it — ``ceil(seg_count / 8)`` walk steps per
+                    block, O(n) VMEM;
                   * **segment-local** (``make_gust_spmv_local``): the
                     pack-time ``seg_blk`` table (scalar-prefetched)
                     steers the pipeline to stream only the ``S_blk``
@@ -33,13 +37,18 @@ TPU adaptation of the paper's three hardware levels (DESIGN.md §2):
                   most one partial product, so the one-hot rows never
                   overlap within a cycle and the matmul loses nothing.
 
-Layout.  Every kernel takes x as ``(seg_count, B_pad, l)`` f32 — column
-segment, batch padded to a multiple of 8 sublanes, lane — and writes
-per-window accumulators ``(num_windows, B_pad, l)``: the lane axis is the
-hardware length ``l`` in both, so the batch costs sublanes, not lanes.
-:func:`repro.kernels.ops.execute_spmm` converts to and from ``(n, B)``.
-The lane-reversed view of a tile is derived in-kernel
-(``_lane_reverse``), so only one copy of x crosses HBM.
+Layout.  The resident kernels take x as ``(B, S8, l)`` f32 — batch row,
+column segment padded with zero rows to ``S8``, a multiple of 8, lane —
+so eight segments share one sublane tile; the segment-local kernels take
+``(seg_count, B_pad, l)`` — column segment, batch padded to a multiple of
+8 sublanes, lane — one streamed tile per segment.  Every kernel writes
+per-window accumulators ``(num_windows, B_pad, l)``: the lane axis is
+the hardware length ``l`` throughout, so the batch costs sublanes, not
+lanes.  :func:`repro.kernels.ops.execute_spmm` converts to and from
+``(n, B)``.  The lane-reversed view is derived in-kernel
+(``_lane_reverse``: once per window into VMEM scratch on the resident
+path, once per tile on the local path), so only one copy of x crosses
+HBM.
 
 Grid: resident ``(num_windows, num_color_blocks)``; segment-local adds an
 inner ``S_blk`` dimension that walks the block's x tiles.  Dimension 1
@@ -162,17 +171,70 @@ def _zeros_gs(c_blk, bp, l):
     return tuple(jnp.zeros((bp, l), jnp.float32) for _ in range(c_blk))
 
 
-def _gather_resident(col_blk, xs_ref, *, l, seg_count):
-    """Resident Buffer Filler: walk every column segment of the
-    VMEM-resident x (seg_count, B_pad, l) for one (C_blk, l) column
-    block.  Returns the per-cycle gathered tuple."""
+def _resident_walk_steps(seg_count: int) -> int:
+    """Steps of the resident walk per color block: one per group of
+    eight column segments (one sublane gather picks among eight)."""
+    return -(-seg_count // _SUBLANES)
+
+
+def _resident_x_rows(seg_count: int) -> int:
+    """Segment rows of the resident x layout ``(b, S8, l)``:
+    ``seg_count`` rounded up to whole groups of eight."""
+    return _resident_walk_steps(seg_count) * _SUBLANES
+
+
+def _reverse_x(xs_ref, xr_ref):
+    """Fill ``xr_ref`` with the lane-reversed twin of the resident x
+    ``(b, S8, l)``, one batch row at a time."""
+    for r in range(xs_ref.shape[0]):
+        xr_ref[r] = _lane_reverse(xs_ref[r])
+
+
+def _sublane_take(tile, idx):
+    """``tile[idx[i, j], j]`` for an (8, l) tile: the sublane gather, one
+    (8, l) index chunk at a time (block heights that are no multiple of
+    8 run only in interpret mode, in one piece)."""
+    c = idx.shape[0]
+    step = _SUBLANES if c % _SUBLANES == 0 else c
+    chunks = [
+        jnp.take_along_axis(tile, idx[k:k + step], axis=0,
+                            mode="promise_in_bounds")
+        for k in range(0, c, step)
+    ]
+    return chunks[0] if len(chunks) == 1 else jnp.concatenate(chunks)
+
+
+def _gather_resident(col_blk, xs_ref, xr_ref, *, l, seg_count):
+    """Resident Buffer Filler for one (C_blk, l) column block against the
+    VMEM-resident x ``xs_ref`` (b, S8, l) and its lane-reversed twin
+    ``xr_ref``.  Segment ``seg`` sits in group ``seg // 8`` at sublane
+    ``seg % 8``, so each walk step gathers one group per batch row with
+    two sublane gathers (straight, reversed), picks by the flip and keeps
+    the slots whose group it is: ``ceil(seg_count / 8)`` steps.  Pure
+    data movement, so every slot holds ``x[col]`` bit-exactly.  Returns
+    the per-cycle tuple of (B_pad, l) arrays :func:`route_rows` takes."""
     seg, flip = _decode_cols(col_blk, l=l)
-    c_blk, bp = seg.shape[0], xs_ref.shape[1]
+    lo, hi = seg % _SUBLANES, seg // _SUBLANES
+    b, (c_blk, _) = xs_ref.shape[0], seg.shape
 
-    def body(s, gs):
-        return _gather_tile(gs, seg, flip, s, xs_ref[s])
+    def body(g, rows):
+        at = pl.ds(pl.multiple_of(g * _SUBLANES, _SUBLANES), _SUBLANES)
+        return tuple(
+            jnp.where(hi == g,
+                      jnp.where(flip, _sublane_take(xr_ref[r, at], lo),
+                                _sublane_take(xs_ref[r, at], lo)),
+                      acc)
+            for r, acc in enumerate(rows)
+        )
 
-    return jax.lax.fori_loop(0, seg_count, body, _zeros_gs(c_blk, bp, l))
+    rows = jax.lax.fori_loop(
+        0, _resident_walk_steps(seg_count), body,
+        tuple(jnp.zeros((c_blk, l), jnp.float32) for _ in range(b)),
+    )
+    bp = _batch_pad(b)
+    pad = (jnp.zeros((c_blk, l), jnp.float32),) * (bp - b)
+    per_cycle = jnp.swapaxes(jnp.stack(rows + pad), 0, 1)  # (c_blk, bp, l)
+    return tuple(per_cycle[c] for c in range(c_blk))
 
 
 def route_rows(m_blk, gs, row_blk, *, l):
@@ -218,10 +280,16 @@ def _accumulate_out(y_ref, acc, first):
 
 def _kernel(*refs, l, seg_count, num_cb, quantized):
     scale_ref = refs[0] if quantized else None
-    m_ref, col_ref, row_ref, xs_ref, y_ref = refs[quantized:]
+    m_ref, col_ref, row_ref, xs_ref, y_ref, xr_scr = refs[quantized:]
     w, cb = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(cb == 0)
+    def _reverse():
+        _reverse_x(xs_ref, xr_scr)
+
     scale = None if scale_ref is None else scale_ref[w * num_cb + cb]
-    gs = _gather_resident(col_ref[...], xs_ref, l=l, seg_count=seg_count)
+    gs = _gather_resident(col_ref[...], xs_ref, xr_scr, l=l,
+                          seg_count=seg_count)
     acc = route_rows(_dequant(m_ref[...], scale), gs, row_ref[...], l=l)
     _accumulate_out(y_ref, acc, cb == 0)
 
@@ -249,8 +317,9 @@ def make_gust_spmv(
     BlockSpecs:
       * schedule stream (m/col/row): HBM -> VMEM tiles of (c_blk, l), one
         per grid step — the Buffer Filler pipeline;
-      * x (seg_count, B_pad, l), straight only (the flip is derived
-        in-kernel): full-array VMEM residency;
+      * x (b, S8, l), straight only: full-array VMEM residency; its
+        lane-reversed twin is derived into VMEM scratch at each window's
+        first color block;
       * y: one (1, B_pad, l) accumulator tile per window, revisited
         across the color-block (reduction) grid dimension.
 
@@ -264,16 +333,19 @@ def make_gust_spmv(
     bp = _batch_pad(b)
     grid = (num_windows, num_cb)
 
+    rows = _resident_x_rows(seg_count)
+
     sched_spec = pl.BlockSpec(
         (c_blk, l), lambda w, cb, *_: (w * num_cb + cb, 0)
     )
-    x_spec = pl.BlockSpec((seg_count, bp, l), lambda w, cb, *_: (0, 0, 0))
+    x_spec = pl.BlockSpec((b, rows, l), lambda w, cb, *_: (0, 0, 0))
     out_spec = pl.BlockSpec((1, bp, l), lambda w, cb, *_: (w, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=int(quantized),
         grid=grid,
         in_specs=[sched_spec, sched_spec, sched_spec, x_spec],
         out_specs=out_spec,
+        scratch_shapes=[pltpu.VMEM((b, rows, l), jnp.float32)],
     )
     kernel = functools.partial(
         _kernel, l=l, seg_count=seg_count, num_cb=num_cb, quantized=quantized
@@ -352,9 +424,10 @@ def make_gust_spmv_local(
     the ``S_blk`` x tiles the block references (``seg_flat[t*S_blk+s]``),
     accumulating the gathered block in VMEM scratch; the multiply +
     routing matmul fire on the last tile.  Gather work per block is
-    O(S_blk · C_blk · l) instead of the resident kernel's
-    O(seg_count · C_blk · l), and x VMEM residency is one tile instead of
-    the whole vector — the wide-matrix fast path.
+    O(S_blk · C_blk · l) instead of the resident walk's
+    ``ceil(seg_count / 8)`` steps of every batch row, and x VMEM
+    residency is one tile instead of the whole vector — the wide-matrix
+    fast path.
     """
     if c_pad % c_blk:
         raise ValueError("c_pad must be a multiple of c_blk")
@@ -417,7 +490,7 @@ def _db_kernel(*refs, l, seg_count, c_blk, num_cb, quantized):
     tile, so the result is bitwise identical."""
     scale_ref = refs[0] if quantized else None
     (m_ref, col_ref, row_ref, xs_ref, y_ref,
-     m_scr, col_scr, row_scr, sems) = refs[quantized:]
+     m_scr, col_scr, row_scr, sems, xr_scr) = refs[quantized:]
     w = pl.program_id(0)
 
     def copies(slot, blk):
@@ -432,6 +505,7 @@ def _db_kernel(*refs, l, seg_count, c_blk, num_cb, quantized):
 
     for c in copies(0, 0):
         c.start()
+    _reverse_x(xs_ref, xr_scr)
 
     def body(i, acc):
         slot = jax.lax.rem(i, 2)
@@ -444,7 +518,8 @@ def _db_kernel(*refs, l, seg_count, c_blk, num_cb, quantized):
         for c in copies(slot, i):
             c.wait()
         scale = None if scale_ref is None else scale_ref[w * num_cb + i]
-        gs = _gather_resident(col_scr[slot], xs_ref, l=l, seg_count=seg_count)
+        gs = _gather_resident(col_scr[slot], xs_ref, xr_scr, l=l,
+                              seg_count=seg_count)
         return acc + route_rows(
             _dequant(m_scr[slot], scale), gs, row_scr[slot], l=l
         )
@@ -483,6 +558,7 @@ def make_gust_spmv_db(
     num_cb = c_pad // c_blk
     bp = _batch_pad(b)
     vdt, idt = jnp.dtype(value_dtype), jnp.dtype(index_dtype)
+    rows = _resident_x_rows(seg_count)
 
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -490,7 +566,7 @@ def make_gust_spmv_db(
         grid=(num_windows,),
         in_specs=[
             any_spec, any_spec, any_spec,
-            pl.BlockSpec((seg_count, bp, l), lambda w, *_: (0, 0, 0)),
+            pl.BlockSpec((b, rows, l), lambda w, *_: (0, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, bp, l), lambda w, *_: (w, 0, 0)),
         scratch_shapes=[
@@ -498,6 +574,7 @@ def make_gust_spmv_db(
             pltpu.VMEM((2, c_blk, l), idt),
             pltpu.VMEM((2, c_blk, l), idt),
             pltpu.SemaphoreType.DMA((2, 3)),
+            pltpu.VMEM((b, rows, l), jnp.float32),
         ],
     )
     kernel = functools.partial(

@@ -27,15 +27,17 @@ the dead padding cycles.
 
 Like the padded flagship, the Buffer-Filler gather runs in one of two
 modes, and every kernel shares the padded kernels' x / output layouts
-and per-block math (:mod:`repro.kernels.gust_spmv`):
+(resident or local) and per-block math (:mod:`repro.kernels.gust_spmv`):
 
-  * **resident** (:func:`make_gust_spmv_ragged`): x fully VMEM-resident,
-    the select walks all ``seg_count`` segments;
+  * **resident** (:func:`make_gust_spmv_ragged`): x fully VMEM-resident
+    in the ``(b, S8, l)`` layout, the walk gathers eight segments a step
+    (``ceil(seg_count / 8)`` steps per block);
   * **segment-local** (:func:`make_gust_spmv_ragged_local`): a third
     scalar-prefetch operand — the pack-time ``seg_blk`` table — steers an
     inner ``S_blk`` grid dimension that streams only the x tiles block
-    ``t`` references, shrinking per-block gather work from O(seg_count)
-    to O(S_blk) and x VMEM residency to a single (1, B_pad, l) tile.
+    ``t`` references, shrinking per-block gather work from a walk over
+    all ``seg_count`` segments to O(S_blk) tiles and x VMEM residency to
+    a single (1, B_pad, l) tile.
 
 Double-buffered variants (PR 6), bitwise-identical to their
 single-buffered twins (same f32 additions in the same order):
@@ -73,7 +75,9 @@ from .gust_spmv import (
     _gather_resident,
     _local_db_block,
     _local_flush,
+    _resident_x_rows,
     _resolve_interpret,
+    _reverse_x,
     gather_local_step,
     route_rows,
     stream_copy,
@@ -90,13 +94,20 @@ __all__ = [
 def _kernel(*refs, l, seg_count, quantized):
     bw_ref, bs_ref = refs[:2]
     scale_ref = refs[2] if quantized else None
-    m_ref, col_ref, row_ref, xs_ref, y_ref = refs[2 + quantized:]
+    m_ref, col_ref, row_ref, xs_ref, y_ref, xr_scr = refs[2 + quantized:]
     t = pl.program_id(0)
     w = bw_ref[t]
+    first = t == bs_ref[w]
+
+    @pl.when(first)
+    def _reverse():
+        _reverse_x(xs_ref, xr_scr)
+
     scale = None if scale_ref is None else scale_ref[t]
-    gs = _gather_resident(col_ref[...], xs_ref, l=l, seg_count=seg_count)
+    gs = _gather_resident(col_ref[...], xs_ref, xr_scr, l=l,
+                          seg_count=seg_count)
     acc = route_rows(_dequant(m_ref[...], scale), gs, row_ref[...], l=l)
-    _accumulate_out(y_ref, acc, t == bs_ref[w])
+    _accumulate_out(y_ref, acc, first)
 
 
 @functools.lru_cache(maxsize=256)
@@ -117,9 +128,9 @@ def make_gust_spmv_ragged(
     Call signature of the returned function:
     ``fn(block_window, block_starts, [scale_blk,] m_blk, col_blk,
     row_blk, xs)`` with the stream blocks ``(num_blocks * c_blk, l)`` and
-    the straight x layout ``(seg_count, B_pad, l)`` (the lane-reversed
-    layout is derived in-kernel); returns ``(num_windows, B_pad, l)`` f32
-    per-window accumulators.
+    the straight resident x layout ``(b, S8, l)`` (the lane-reversed twin
+    is derived in-kernel, once per window); returns ``(num_windows,
+    B_pad, l)`` f32 per-window accumulators.
 
     BlockSpecs:
       * schedule stream (m/col/row): HBM -> VMEM tiles of (c_blk, l), one
@@ -132,16 +143,16 @@ def make_gust_spmv_ragged(
     """
     bp = _batch_pad(b)
     grid = (num_blocks,)
+    rows = _resident_x_rows(seg_count)
     sched_spec = pl.BlockSpec((c_blk, l), lambda t, bw, bs, *_: (t, 0))
-    x_spec = pl.BlockSpec(
-        (seg_count, bp, l), lambda t, bw, bs, *_: (0, 0, 0)
-    )
+    x_spec = pl.BlockSpec((b, rows, l), lambda t, bw, bs, *_: (0, 0, 0))
     out_spec = pl.BlockSpec((1, bp, l), lambda t, bw, bs, *_: (bw[t], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2 + int(quantized),
         grid=grid,
         in_specs=[sched_spec, sched_spec, sched_spec, x_spec],
         out_specs=out_spec,
+        scratch_shapes=[pltpu.VMEM((b, rows, l), jnp.float32)],
     )
     kernel = functools.partial(
         _kernel, l=l, seg_count=seg_count, quantized=quantized
@@ -247,7 +258,7 @@ def _db_kernel(*refs, l, seg_count, c_blk, quantized):
     bs_ref = refs[0]
     scale_ref = refs[1] if quantized else None
     (m_ref, col_ref, row_ref, xs_ref, y_ref,
-     m_scr, col_scr, row_scr, sems) = refs[1 + quantized:]
+     m_scr, col_scr, row_scr, sems, xr_scr) = refs[1 + quantized:]
     w = pl.program_id(0)
     t0 = bs_ref[w]
     count = bs_ref[w + 1] - t0
@@ -264,6 +275,7 @@ def _db_kernel(*refs, l, seg_count, c_blk, quantized):
 
     for c in copies(0, t0):
         c.start()
+    _reverse_x(xs_ref, xr_scr)
 
     def body(i, acc):
         slot = jax.lax.rem(i, 2)
@@ -276,7 +288,8 @@ def _db_kernel(*refs, l, seg_count, c_blk, quantized):
         for c in copies(slot, t0 + i):
             c.wait()
         scale = None if scale_ref is None else scale_ref[t0 + i]
-        gs = _gather_resident(col_scr[slot], xs_ref, l=l, seg_count=seg_count)
+        gs = _gather_resident(col_scr[slot], xs_ref, xr_scr, l=l,
+                              seg_count=seg_count)
         return acc + route_rows(
             _dequant(m_scr[slot], scale), gs, row_scr[slot], l=l
         )
@@ -309,6 +322,7 @@ def make_gust_spmv_ragged_db(
     at the stream's actual dtypes."""
     bp = _batch_pad(b)
     vdt, idt = jnp.dtype(value_dtype), jnp.dtype(index_dtype)
+    rows = _resident_x_rows(seg_count)
 
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -316,7 +330,7 @@ def make_gust_spmv_ragged_db(
         grid=(num_windows,),
         in_specs=[
             any_spec, any_spec, any_spec,
-            pl.BlockSpec((seg_count, bp, l), lambda w, bs, *_: (0, 0, 0)),
+            pl.BlockSpec((b, rows, l), lambda w, bs, *_: (0, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, bp, l), lambda w, bs, *_: (w, 0, 0)),
         scratch_shapes=[
@@ -324,6 +338,7 @@ def make_gust_spmv_ragged_db(
             pltpu.VMEM((2, c_blk, l), idt),
             pltpu.VMEM((2, c_blk, l), idt),
             pltpu.SemaphoreType.DMA((2, 3)),
+            pltpu.VMEM((b, rows, l), jnp.float32),
         ],
     )
     kernel = functools.partial(

@@ -37,6 +37,7 @@ from repro.resilience import faults
 
 from .gust_spmv import (
     _batch_pad,
+    _resident_x_rows,
     make_gust_spmv,
     make_gust_spmv_db,
     make_gust_spmv_local,
@@ -96,18 +97,20 @@ def normalize_choice(name: str, value: str, allowed: Tuple[str, ...] = None):
     return value
 
 
-def _prep_x(x: jnp.ndarray, n: int, l: int) -> jnp.ndarray:
-    """Zero-pad x (n, B) to (S*l, B_pad) f32 and lay it out as the
-    kernels' segment-major ``(S, B_pad, l)``: the hardware length on the
-    lanes, the batch on whole sublane groups.  The lane-reversed layout
-    the fused gather selects against is derived in-kernel, so only one
-    copy of x crosses HBM->VMEM."""
+def _prep_x(x: jnp.ndarray, n: int, l: int, *, resident: bool) -> jnp.ndarray:
+    """Zero-pad x (n, B) f32 into a kernel layout, the hardware length on
+    the lanes.  Local kernels stream segment tiles ``(S, B_pad, l)``, the
+    batch on whole sublane groups; resident kernels hold ``(B, S8, l)``,
+    the ``S`` segments padded to groups of eight for the sublane gather.
+    The lane-reversed copy the gather selects against is derived
+    in-kernel, so only one copy of x crosses HBM->VMEM."""
     seg_count = -(-n // l)
     b = x.shape[1]
-    xp = jnp.pad(
-        x.astype(jnp.float32),
-        ((0, seg_count * l - n), (0, _batch_pad(b) - b)),
-    )
+    x = x.astype(jnp.float32)
+    if resident:
+        rows = _resident_x_rows(seg_count)
+        return jnp.pad(x, ((0, rows * l - n), (0, 0))).T.reshape(b, rows, l)
+    xp = jnp.pad(x, ((0, seg_count * l - n), (0, _batch_pad(b) - b)))
     return xp.reshape(seg_count, l, -1).transpose(0, 2, 1)
 
 
@@ -197,10 +200,10 @@ def _execute_spmm_impl(
     materialize a transposed copy.
 
     ``gather`` selects the Buffer-Filler mode: ``"resident"`` (x whole in
-    VMEM, one-hot over every column segment), ``"local"`` (stream only
-    the ``S_blk`` x tiles each block references via the pack-time segment
-    table — O(S_blk) gather work per slot instead of O(seg_count), no
-    whole-x VMEM residency), or ``"auto"`` (the
+    VMEM, a walk over every group of eight column segments), ``"local"``
+    (stream only the ``S_blk`` x tiles each block references via the
+    pack-time segment table — O(S_blk) gather work per slot, no whole-x
+    VMEM residency), or ``"auto"`` (the
     :func:`~repro.core.packing.resolve_gather` locality-ratio decision).
     Both modes are bit-identical.
 
@@ -266,7 +269,7 @@ def _execute_spmm_impl(
 
     if use_kernel and packed.fusable:
         double = pipeline != "single"
-        x2d = _prep_x(x, n, l)
+        x2d = _prep_x(x, n, l, resident=gather != "local")
         vdt, idt = str(packed.m_blk.dtype), str(packed.col_blk.dtype)
         # the per-block scales ride as the last scalar-prefetch operand
         scale = (jnp.asarray(packed.scale_blk, jnp.float32),) if quant else ()

@@ -10,6 +10,9 @@ off the (8, 128) tiling, and VMEM overruns.
 Shapes are those of yi_6b's MLP matrices pruned to density 0.1 and
 planned with l=256 (w_up/w_gate: 11008 x 4096, w_down: 4096 x 11008),
 decoded at batch 4; stream lengths are the planner's at that density.
+The padded resident kernels also compile at the Table-3 ``mouse_gene``
+plan (45,101 square, l=256) at batch 1, the widest resident walk the
+benchmark runs.
 """
 
 import os
@@ -37,6 +40,8 @@ KERNELS = [
     for pipeline in ("single", "double")
 ]
 L, B, C_BLK = 256, 4, 8
+#: mouse_gene at l=256: (num_windows, seg_count, c_pad), batch 1.
+MOUSEGENE = (177, 177, 888)
 
 
 @pytest.fixture(scope="module")
@@ -89,8 +94,10 @@ def test_spmv_kernel_compiles_for_v5e(one_chip, layout, gather, pipeline,
     vdt, idt = DTYPES[dtypes]
     quant = vdt == "int8"
     t_blk = t_ragged if layout == "ragged" else w * c_pad // C_BLK
+    x_shape = ((seg, 8, L) if gather == "local"
+               else (B, K._resident_x_rows(seg), L))
     stream = (spec((t_blk * C_BLK, L), vdt), spec((t_blk * C_BLK, L), idt),
-              spec((t_blk * C_BLK, L), idt), spec((seg, 8, L), "float32"))
+              spec((t_blk * C_BLK, L), idt), spec(x_shape, "float32"))
     scale = (spec((t_blk,), "float32"),) if quant else ()
     seg_flat = (spec((t_blk * s_blk,), "int32"),)
     kw = dict(c_blk=C_BLK, interpret=False, quantized=quant)
@@ -120,15 +127,43 @@ def test_spmv_kernel_compiles_for_v5e(one_chip, layout, gather, pipeline,
     _assert_kernel(_compile_text(fn, pre + scale + stream), family)
 
 
+@pytest.mark.parametrize("pipeline", ["single", "double"])
+def test_resident_spmv_compiles_at_mousegene(one_chip, pipeline):
+    """The widest resident walk the benchmark runs: 177 segments, 23
+    groups of eight, batch 1, f32 stream."""
+    from repro.kernels import gust_spmv as K
+
+    w, seg, c_pad = MOUSEGENE
+    rows = w * c_pad
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    args = (spec((rows, L), "float32"), spec((rows, L), "int32"),
+            spec((rows, L), "int32"),
+            spec((1, K._resident_x_rows(seg), L), "float32"))
+    kw = dict(c_blk=C_BLK, interpret=False)
+    if pipeline == "double":
+        fn, family = K.make_gust_spmv_db(w, c_pad, L, seg, 1, **kw), \
+            "gust_spmv_padded_resident_db"
+    else:
+        fn, family = K.make_gust_spmv(w, c_pad, L, seg, 1, **kw), \
+            "gust_spmv_padded_resident"
+    _assert_kernel(_compile_text(fn, args), family)
+
+
 @pytest.mark.parametrize("mat", sorted(SHAPES))
 def test_gather_fill_compiles_for_v5e(one_chip, mat):
     from repro.kernels.gather_fill import make_gather_fill
+    from repro.kernels.gust_spmv import _resident_x_rows
 
     w, seg, c_pad, _, _ = SHAPES[mat]
     rows = w * c_pad
     fn = make_gather_fill(rows, L, seg, B, c_blk=C_BLK, interpret=False)
     args = (jax.ShapeDtypeStruct((rows, L), jnp.int32, sharding=one_chip),
-            jax.ShapeDtypeStruct((seg, 8, L), jnp.float32, sharding=one_chip))
+            jax.ShapeDtypeStruct((B, _resident_x_rows(seg), L), jnp.float32,
+                                 sharding=one_chip))
     _assert_kernel(_compile_text(fn, args), "gust_gather_fill")
 
 

@@ -296,6 +296,18 @@ def test_plan_cost_gather_fields():
     assert c.to_dict()["s_blk"] == a.s_blk
 
 
+@pytest.mark.parametrize("n", [8, 56, 64, 72, 184])
+def test_plan_cost_gather_walk_steps(n):
+    """The resident walk takes one step per group of eight column
+    segments: 1, 7, 8, 9 and 23 segments walk in 1, 1, 1, 2 and 3."""
+    rng = np.random.default_rng(n)
+    p = plan(random_dense(rng, 32, n, 0.2), PlanConfig(l=8), cache=None)
+    seg_count = p.artifact.seg_count
+    assert seg_count == n // 8
+    assert p.cost().gather_walk_steps == -(-seg_count // 8)
+    assert p.cost().to_dict()["gather_walk_steps"] == -(-seg_count // 8)
+
+
 def test_stack_equalizes_seg_tables_and_flags():
     """Layers with different S_blk / identity_perm must stack: tables are
     widened to the max and the shared static flags are conservative."""

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 from repro.core.formats import coo_from_dense
 from repro.core.scheduler import schedule
 from repro.kernels.gather_fill import make_gather_fill
+from repro.kernels.gust_spmv import _resident_x_rows
 from repro.kernels.ops import gust_spmm, pack_schedule
 from repro.kernels.ref import gather_fill_ref, gust_spmv_ref
 
@@ -94,8 +95,9 @@ def test_kernel_vs_ref_on_packed_blocks():
     )
     from repro.kernels.gust_spmv import make_gust_spmv
 
-    # kernel layout: (segment, batch padded to 8 sublanes, lane)
-    xs = jnp.pad(xp, ((0, 0), (0, 5))).reshape(seg, 8, 8).transpose(0, 2, 1)
+    # resident layout: (batch, segment padded to groups of 8, lane)
+    rows = _resident_x_rows(seg)
+    xs = jnp.pad(xp, ((0, (rows - seg) * 8), (0, 0))).T.reshape(3, rows, 8)
     fn = make_gust_spmv(packed.num_windows, packed.c_pad, 8, seg, 3)
     y_k = np.asarray(fn(packed.m_blk, packed.col_blk, packed.row_blk, xs))
     y_k = y_k[:, :3, :].transpose(0, 2, 1)
@@ -116,9 +118,104 @@ def test_gather_fill_kernel(l, seg, b):
     offs = np.where(flip, l - 1 - lanes, lanes)
     cols = (segs * l + offs).astype(np.int32)
     fn = make_gather_fill(total, l, seg, b)
-    # kernel layout: (segment, batch padded to 8 sublanes, lane)
-    xs = jnp.pad(jnp.asarray(x), ((0, 0), (0, 8 - b)))
-    xs = xs.reshape(seg, l, 8).transpose(0, 2, 1)
+    # resident layout: (batch, segment padded to groups of 8, lane)
+    rows = _resident_x_rows(seg)
+    xs = jnp.pad(jnp.asarray(x), ((0, (rows - seg) * l), (0, 0)))
+    xs = xs.T.reshape(b, rows, l)
     out = np.asarray(fn(jnp.asarray(cols), xs))[:, :b, :].transpose(0, 2, 1)
     ref = np.asarray(gather_fill_ref(jnp.asarray(cols), jnp.asarray(x)))
     np.testing.assert_allclose(out, ref, rtol=1e-6, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# Resident walk: eight segments a step, bitwise against the oracle.
+# ---------------------------------------------------------------------------
+
+WALK_L = 8
+RESIDENT_KERNELS = ["padded-single", "padded-double", "ragged-single",
+                    "ragged-double", "gather_fill"]
+
+
+def _walk_case(seg_count, b, quantized):
+    """A load-balanced schedule whose columns span ``seg_count`` segments
+    (the last one partial), and an x, on which every order of summation
+    gives the same f32 result, so kernel and oracle agree bitwise: small
+    integer values and distinct integer x, or, for the int8 stream (whose
+    scales are not integers), one nonzero per row."""
+    l = WALK_L
+    n = seg_count * l - (2 if seg_count > 1 else 0)
+    m = 24
+    rng = np.random.default_rng(100 * seg_count + b)
+    if quantized:
+        dense = np.zeros((m, n), np.float32)
+        dense[np.arange(m), rng.integers(0, n, m)] = rng.standard_normal(m)
+        x = rng.standard_normal((n, b)).astype(np.float32)
+    else:
+        vals = rng.integers(-4, 5, (m, n)) * (rng.random((m, n)) < 0.4)
+        dense = vals.astype(np.float32)
+        x = (rng.permutation(n * b) - n * b // 2).reshape(n, b)
+        x = x.astype(np.float32)
+    sched = schedule(coo_from_dense(dense), l, load_balance=True)
+    return dense, sched, x
+
+
+@pytest.mark.parametrize("kernel", RESIDENT_KERNELS)
+@pytest.mark.parametrize("b", [1, 3, 8])
+@pytest.mark.parametrize("seg_count", [1, 7, 8, 9, 23])
+def test_resident_walk_bitwise_vs_ref(seg_count, b, kernel):
+    """Every caller of the resident walk equals ``kernels/ref.py`` bit for
+    bit: segment counts that fill whole groups of eight, leave a partial
+    last group or a single segment, at batches below, at and off a
+    sublane group, with flipped lanes and padding slots in the stream."""
+    from repro.core.packing import pack_ragged
+    from repro.kernels.ops import execute_spmm
+
+    dense, sched, x = _walk_case(seg_count, b, quantized=False)
+    packed = pack_schedule(sched)
+    assert packed.seg_count == seg_count
+    lane = np.arange(WALK_L)[None, :]
+    col, m = np.asarray(packed.col_blk), np.asarray(packed.m_blk)
+    # the scheduler flips every other segment by rank, so a single
+    # segment is never flipped; gather_fill flips its own below
+    flipped = (col % WALK_L != lane) & (m != 0)
+    assert flipped.any() or seg_count == 1, "no flipped lane"
+    assert (m == 0).any(), "no padding slot"
+    xj = jnp.asarray(x)
+    if kernel == "gather_fill":
+        total = col.shape[0]
+        flip = np.random.default_rng(seg_count).random(col.shape) < 0.5
+        col = col // WALK_L * WALK_L + np.where(flip, WALK_L - 1 - lane, lane)
+        rows = _resident_x_rows(seg_count)
+        xs = jnp.pad(xj, ((0, rows * WALK_L - x.shape[0]), (0, 0)))
+        xs = xs.T.reshape(b, rows, WALK_L)
+        fn = make_gather_fill(total, WALK_L, seg_count, b)
+        out = np.asarray(fn(jnp.asarray(col), xs))[:, :b, :]
+        xp = jnp.pad(xj, ((0, seg_count * WALK_L - x.shape[0]), (0, 0)))
+        ref = np.asarray(gather_fill_ref(jnp.asarray(col), xp))
+        assert np.array_equal(out.transpose(0, 2, 1), ref)
+        return
+    layout, pipeline = kernel.split("-")
+    art = packed if layout == "padded" else pack_ragged(sched)
+    y = execute_spmm(art, xj, use_kernel=True, gather="resident",
+                     pipeline=pipeline)
+    y_ref = execute_spmm(art, xj, use_kernel=False, gather="resident")
+    assert np.array_equal(np.asarray(y), np.asarray(y_ref))
+    assert np.array_equal(np.asarray(y_ref), dense @ x)
+
+
+@pytest.mark.parametrize("kernel", RESIDENT_KERNELS[:4])
+def test_resident_walk_bitwise_vs_ref_int8(kernel):
+    """The int8 stream (per-block scales) goes through the same walk."""
+    from repro.core.packing import pack_ragged
+    from repro.kernels.ops import execute_spmm
+
+    _, sched, x = _walk_case(9, 3, quantized=True)
+    layout, pipeline = kernel.split("-")
+    pack = pack_schedule if layout == "padded" else pack_ragged
+    art = pack(sched, value_dtype=jnp.int8)
+    assert art.scale_blk is not None
+    xj = jnp.asarray(x)
+    y = execute_spmm(art, xj, use_kernel=True, gather="resident",
+                     pipeline=pipeline)
+    y_ref = execute_spmm(art, xj, use_kernel=False, gather="resident")
+    assert np.array_equal(np.asarray(y), np.asarray(y_ref))
