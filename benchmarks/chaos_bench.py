@@ -277,8 +277,8 @@ def store_leg(lm, params, args):
         assert chaos["stats"]["fallbacks"]["stored_to_fresh"] == reps, (
             "every failed store read must be a counted stored->fresh fallback"
         )
-        cold_leaves = cold["mats"]["w_gate"]["leaves"]
-        chaos_leaves = chaos["mats"]["w_gate"]["leaves"]
+        cold_leaves = cold["mats"]["0.w_gate"]["leaves"]
+        chaos_leaves = chaos["mats"]["0.w_gate"]["leaves"]
         for k in cold_leaves:
             assert np.array_equal(
                 np.asarray(cold_leaves[k]), np.asarray(chaos_leaves[k])

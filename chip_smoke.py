@@ -176,7 +176,7 @@ def phase_serve(cfg, gcfgs, *, seed: int) -> None:
         emit("gustify", variant=name, seconds=time.perf_counter() - t0,
              interpret=_interpret(g) if g.use_kernel else None,
              stream_utilization={k: tree["stats"][k]["stream_utilization"]
-                                 for k in g.mats},
+                                 for k in tree["mats"]},
              build_s=tree["stats"]["build_s"],
              sched_counters=dict(sched_counters), **memory())
         if g.use_kernel:
@@ -190,6 +190,7 @@ def phase_serve(cfg, gcfgs, *, seed: int) -> None:
     for name, loop in loops.items():
         t0 = time.perf_counter()
         text = loop._decode.lower(loop.params, loop.caches, toks, pos,
+                                  loop._counts, None,
                                   *loop._decode_extra).compile().as_text()
         compile_s[name] = time.perf_counter() - t0
         has_kernel = "tpu_custom_call" in text

@@ -52,7 +52,7 @@ def main():
         if gust is not None and loop.gust_tree is not None:
             util = {k: f"{v['stream_utilization']:.2%}"
                     for k, v in loop.gust_tree["stats"].items()
-                    if k in gust.mats}
+                    if k in loop.gust_tree["mats"]}
             print(f"  scheduled-stream utilization per matrix: {util}")
         print(f"  first completion: {list(outs.values())[0]}")
 

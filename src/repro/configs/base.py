@@ -14,10 +14,14 @@ from __future__ import annotations
 
 import dataclasses
 import importlib
+import math
 from typing import Dict, Optional, Tuple
+
+import numpy as np
 
 __all__ = [
     "ArchConfig",
+    "Yarn",
     "ShapeConfig",
     "SHAPES",
     "register",
@@ -25,6 +29,49 @@ __all__ = [
     "list_archs",
     "ARCH_IDS",
 ]
+
+
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN rotary scaling (arXiv:2309.00071), as a HF ``rope_parameters``
+    entry of ``rope_type: yarn`` states it; ``models.layers.rope`` rotates
+    by :meth:`inv_freq` and scales cos and sin by ``attention_factor``."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+    def inv_freq(self, d: int, theta: float) -> np.ndarray:
+        """(d/2,) float32 inverse frequencies for head size ``d``, as HF
+        ``rope_type: yarn`` computes them.  With factor s and original
+        positions L:
+
+            pos_freqs(i) = theta ** (2i / d),  i < d/2
+            extrap = 1 / pos_freqs,  interp = 1 / (s * pos_freqs)
+            dim(b) = d * ln(L / (2 pi b)) / (2 ln theta)
+            low = max(floor(dim(beta_fast)), 0)
+            high = min(ceil(dim(beta_slow)), d - 1)
+            ramp(i) = clip((i - low) / (high - low), 0, 1)
+            inv_freq = interp * ramp + extrap * (1 - ramp)
+
+        At d 128, theta 5e5, L 8192 and betas 32 / 1: low 18, high 35."""
+        half = d // 2
+        pos_freqs = theta ** (np.arange(half, dtype=np.float64) * 2.0 / d)
+
+        def dim(beta):
+            return d * math.log(self.original_max_position
+                                / (2 * math.pi * beta)) / (2 * math.log(theta))
+
+        low = max(math.floor(dim(self.beta_fast)), 0)
+        high = min(math.ceil(dim(self.beta_slow)), d - 1)
+        if high == low:
+            high += 0.001
+        ramp = np.clip((np.arange(half) - low) / (high - low), 0.0, 1.0)
+        inv = (ramp / (self.factor * pos_freqs)
+               + (1.0 - ramp) / pos_freqs)
+        return inv.astype(np.float32)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -45,8 +92,12 @@ class ArchConfig:
     chunk_size: int = 0  # chunk size for 'chunked' blocks
     global_cache_cap: int = 0  # decode-cache cap for global layers (long ctx)
     rope_theta: float = 10_000.0
-    n_experts: int = 0
+    global_yarn: Optional[Yarn] = None  # YaRN on 'global'/'moe_global'
+    # blocks; the other blocks keep the default rope at rope_theta
+    n_experts: int = 0  # the router's width: experts of the whole layer
     top_k: int = 0
+    experts_held: int = 0  # experts 0..experts_held-1 held here (one
+    # share of an expert-parallel layer; 0 = all n_experts)
     capacity_factor: float = 1.25
     mlp_kind: str = "swiglu"
     norm_kind: str = "rms"
@@ -107,6 +158,7 @@ class ArchConfig:
             else 0,
             n_experts=min(self.n_experts, 4) if self.n_experts else 0,
             top_k=min(self.top_k, 2) if self.top_k else 0,
+            experts_held=min(self.experts_held, 2) if self.experts_held else 0,
             n_enc_layers=min(self.n_enc_layers, 2) if self.n_enc_layers else 0,
             enc_seq=min(self.enc_seq, 16) if self.enc_seq else 0,
             attn_block_size=64,
@@ -144,6 +196,7 @@ ARCH_IDS = (
     "yi_6b",
     "llava_next_mistral_7b",
     "seamless_m4t_medium",
+    "mellum2_12b_a2_5b",
 )
 
 _REGISTRY: Dict[str, ArchConfig] = {}

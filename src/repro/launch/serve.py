@@ -8,12 +8,15 @@ solo run, so batching is purely a throughput knob (reported as
 ``tok_per_s`` / ``slot_occupancy``; ``--serial`` forces the old
 one-request-at-a-time pattern for comparison).
 
-The GUST path plans every MLP matrix once at engine build
-(``serving.gust_serve.gustify`` -> ``repro.plan``) and executes each
-decode step through the stacked :class:`~repro.core.plan.GustPlan`
+The GUST path plans every MLP and held-expert matrix once at engine
+build (``serving.gust_serve.gustify`` -> ``repro.plan``) and executes
+each decode step through the stacked :class:`~repro.core.plan.GustPlan`
 leaves; ``--ragged``/``--compact``/``--use-kernel`` map onto the plan's
 layout/dtype/backend knobs.  GUST decode shares the continuous-batching
-machinery with the dense path.
+machinery with the dense path.  A model with expert layers (e.g.
+``--arch mellum2_12b_a2_5b``) also reports its routing counters under
+``moe``: routed (token, held expert) pairs, expert columns computed, and
+tokens and busy decode steps per expert layer and held expert.
 
 ``--no-reduced`` serves the published widths; ``--layers N`` cuts depth
 to the first N layers (the only cut at full width).  Without an
@@ -168,8 +171,16 @@ def run_serving(
         # and the process-wide fallback counters
         "resilience": loop.resilience_stats(),
     }
+    # the expert layers' routing counters (ServeLoop.read_counters):
+    # routed (token, held expert) pairs against expert columns computed,
+    # and tokens and busy steps per expert layer and held expert
+    moe = {k[len("moe."):]: v for k, v in loop.read_counters().items()
+           if k.startswith("moe.")}
+    if moe:
+        stats["moe"] = moe
     if gust and loop.gust_tree is not None:
-        mat_stats = {k: loop.gust_tree["stats"][k] for k in gcfg.mats}
+        mat_stats = {k: loop.gust_tree["stats"][k]
+                     for k in loop.gust_tree["mats"]}
         stats["gust_stream_utilization"] = {
             k: round(v["stream_utilization"], 4) for k, v in mat_stats.items()
         }

@@ -27,6 +27,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.configs.base import Yarn
 from repro.distributed.sharding import constrain_attn, constrain_kv_cache
 
 from .layers import _he, rope
@@ -53,6 +54,7 @@ class AttnSpec:
     mode: str = "global"  # global | local | chunked
     window: int = 0  # window size (local) or chunk size (chunked)
     rope_theta: float = 10_000.0
+    yarn: Optional[Yarn] = None  # YaRN-scaled rope (global layers)
     use_rope: bool = True
     causal: bool = True  # False for encoder self-attention
     block_size: int = 1024  # KV block for the online-softmax path
@@ -87,8 +89,8 @@ def _qkv(p, x, spec: AttnSpec, positions):
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"], preferred_element_type=jnp.float32)
     q, k, v = q.astype(x.dtype), k.astype(x.dtype), v.astype(x.dtype)
     if spec.use_rope:
-        q = rope(q, positions, theta=spec.rope_theta)
-        k = rope(k, positions, theta=spec.rope_theta)
+        q = rope(q, positions, theta=spec.rope_theta, yarn=spec.yarn)
+        k = rope(k, positions, theta=spec.rope_theta, yarn=spec.yarn)
     return q, k, v
 
 

@@ -104,13 +104,18 @@ def unembed(p, x: jnp.ndarray) -> jnp.ndarray:
 
 
 def rope(
-    x: jnp.ndarray, positions: jnp.ndarray, *, theta: float = 10_000.0
+    x: jnp.ndarray, positions: jnp.ndarray, *, theta: float = 10_000.0,
+    yarn=None,
 ) -> jnp.ndarray:
     """Rotary embedding.  x: (..., S, H, D), positions: broadcastable (S,)
-    or (B, S)."""
+    or (B, S).  With ``yarn`` (a ``configs.base.Yarn``) the frequencies
+    are its ``inv_freq`` and cos/sin carry its attention factor."""
     d = x.shape[-1]
     half = d // 2
-    freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if yarn is None:
+        freq, scale = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half), 1.0
+    else:
+        freq, scale = jnp.asarray(yarn.inv_freq(d, theta)), yarn.attention_factor
     if positions.ndim == 1:
         ang = positions.astype(jnp.float32)[:, None] * freq[None, :]  # (S, half)
         ang = ang[None, :, None, :]  # (1, S, 1, half)
@@ -118,6 +123,8 @@ def rope(
         ang = positions.astype(jnp.float32)[..., None] * freq  # (B, S, half)
         ang = ang[:, :, None, :]
     sin, cos = jnp.sin(ang), jnp.cos(ang)
+    if yarn is not None:
+        sin, cos = sin * scale, cos * scale
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

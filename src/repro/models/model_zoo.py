@@ -167,15 +167,29 @@ class LM:
         logits = self._logits(params, x[:, -1:])
         return logits, caches
 
-    def decode_step(self, params, caches, tokens, pos, *, dtype=jnp.bfloat16):
+    def decode(self, params, caches, tokens, pos, *, dtype=jnp.bfloat16,
+               ffn=None, ffn_xs=None):
         """One token for every sequence.  tokens: (B, 1) int32; ``pos`` is
         a scalar or a (B,) int32 vector of per-sequence positions (mixed
-        prompt lengths decode each row at its own position)."""
+        prompt lengths decode each row at its own position).  ``ffn`` and
+        ``ffn_xs`` plug an operator into every block's second residual
+        branch (``transformer.stack_decode``; GUST serving plugs its
+        plans in here).  Returns (logits, caches, routes): ``routes``
+        (L, B, held), the held experts each row was routed to in each
+        expert layer, or None."""
         cfg = self.cfg
         x = self._embed_tokens(params, tokens, dtype)
         stack_params = params["decoder"] if cfg.is_encdec else params["stack"]
-        x, caches = T.stack_decode(stack_params, x, self._serve_stack(), caches, pos)
+        x, caches, routes = T.stack_decode(
+            stack_params, x, self._serve_stack(), caches, pos, ffn=ffn,
+            ffn_xs=ffn_xs)
         logits = self._logits(params, x)
+        return logits, caches, routes
+
+    def decode_step(self, params, caches, tokens, pos, *, dtype=jnp.bfloat16):
+        """:meth:`decode` with the blocks' own operators: (logits, caches)."""
+        logits, caches, _ = self.decode(params, caches, tokens, pos,
+                                        dtype=dtype)
         return logits, caches
 
     # -- input specs (ShapeDtypeStructs for the dry-run) ---------------------

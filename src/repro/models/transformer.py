@@ -15,11 +15,15 @@ sharding rules treat as a pure stacking dim.
 
 Three regimes per block/stack, mirroring attention.py / recurrent.py:
 ``*_train`` (full sequence), ``*_prefill`` (full sequence + cache out),
-``*_decode`` (one token + cache in/out).
+``*_decode`` (one token + cache in/out).  ``stack_decode`` takes an
+operator for every block's second residual branch (GUST serving plugs
+its plans in there) and returns which held experts each row was routed
+to in every expert layer.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Optional, Tuple
@@ -77,7 +81,8 @@ def make_block_cfg(cfg: ArchConfig, block_type: str) -> BlockCfg:
     common = dict(d_model=d, norm_kind=cfg.norm_kind, mlp_kind=cfg.mlp_kind, d_ff=cfg.d_ff)
 
     if block_type in ("global", "moe_global"):
-        attn = A.AttnSpec(mode="global", max_cache=cfg.global_cache_cap, **base_attn)
+        attn = A.AttnSpec(mode="global", max_cache=cfg.global_cache_cap,
+                          yarn=cfg.global_yarn, **base_attn)
     elif block_type in ("local", "moe_local"):
         attn = A.AttnSpec(mode="local", window=cfg.local_window, **base_attn)
     elif block_type in ("chunked", "moe_chunked"):
@@ -96,6 +101,7 @@ def make_block_cfg(cfg: ArchConfig, block_type: str) -> BlockCfg:
             n_experts=cfg.n_experts,
             top_k=cfg.top_k,
             capacity_factor=cfg.capacity_factor,
+            n_held=cfg.experts_held,
         )
         return BlockCfg(kind="attn_moe", attn=attn, moe=moe, **common)
     if block_type in ("global", "local", "chunked"):
@@ -156,12 +162,30 @@ def init_block(key, bc: BlockCfg):
     return p
 
 
-def _ffn(p, x, bc: BlockCfg):
-    """Second residual branch: MLP or MoE.  Returns (delta, aux)."""
+def _ffn(p, x, bc: BlockCfg, serving: bool = False):
+    """Second residual branch: MLP or MoE.  Returns (delta, aux, routed):
+    ``routed`` (B, S, held) marks the held experts each token was routed
+    to (None for an MLP).  ``serving`` routes without a capacity."""
     h = apply_norm(p["ln_mlp"], x, kind=bc.norm_kind)
     if bc.kind == "attn_moe":
-        return moe_ffn(p["moe"], h, bc.moe)
-    return mlp(p["mlp"], h, kind=bc.mlp_kind), 0.0
+        with jax.named_scope("moe"):  # routing, experts and combine
+            return moe_ffn(p["moe"], h, bc.moe, serving=serving)
+    return mlp(p["mlp"], h, kind=bc.mlp_kind), 0.0, None
+
+
+def _decode_ffn(p, x, bc: BlockCfg, ffn, where, g):
+    """The decode's second residual branch: ``ffn(where, bc, p, h, g)`` on
+    the normed hidden state when an operator is plugged in (``where``:
+    the block's id, ``"i"`` for pattern position i or ``"tailj"``; ``g``:
+    its slice of the operands), else the block's own MLP or MoE.
+    Returns (delta, routed (B, held) or None)."""
+    if ffn is None:
+        delta, _, routed = _ffn(p, x, bc, serving=True)
+        return delta, None if routed is None else routed[:, 0]
+    h = apply_norm(p["ln_mlp"], x, kind=bc.norm_kind)
+    with (jax.named_scope("moe") if bc.kind == "attn_moe"
+          else contextlib.nullcontext()):
+        return ffn(where, bc, p, h, g)
 
 
 def block_train(p, x, bc: BlockCfg, memory=None):
@@ -173,12 +197,12 @@ def block_train(p, x, bc: BlockCfg, memory=None):
             h = apply_norm(p["ln_cross"], x, kind=bc.norm_kind)
             k, v = A.cross_kv(p["cross"], memory, bc.cross)
             x = x + A.attend_cross(p["cross"], h, k, v, bc.cross)
-        delta, aux = _ffn(p, x, bc)
+        delta, aux, _ = _ffn(p, x, bc)
         x = x + delta
     elif bc.kind == "rec":
         h = apply_norm(p["ln_rec"], x, kind=bc.norm_kind)
         x = x + R.rglru_train(p["rec"], h, bc.rglru)
-        delta, aux = _ffn(p, x, bc)
+        delta, aux, _ = _ffn(p, x, bc)
         x = x + delta
     elif bc.kind == "mlstm":
         h = apply_norm(p["ln"], x, kind=bc.norm_kind)
@@ -226,13 +250,13 @@ def block_prefill(p, x, bc: BlockCfg, cache, memory=None, start: int = 0):
         else:
             y, cache = A.prefill_into_cache(p["attn"], h, bc.attn, cache, start)
             x = x + y
-        delta, _ = _ffn(p, x, bc)
+        delta, _, _ = _ffn(p, x, bc, serving=True)
         x = x + delta
     elif bc.kind == "rec":
         h = apply_norm(p["ln_rec"], x, kind=bc.norm_kind)
         y, cache = R.rglru_train(p["rec"], h, bc.rglru, cache, return_state=True)
         x = x + y
-        delta, _ = _ffn(p, x, bc)
+        delta, _, _ = _ffn(p, x, bc, serving=True)
         x = x + delta
     elif bc.kind == "mlstm":
         h = apply_norm(p["ln"], x, kind=bc.norm_kind)
@@ -245,27 +269,35 @@ def block_prefill(p, x, bc: BlockCfg, cache, memory=None, start: int = 0):
     return x, cache
 
 
-def block_decode(p, x, bc: BlockCfg, cache, pos, memory=None):
+def block_decode(p, x, bc: BlockCfg, cache, pos, memory=None, ffn=None,
+                 where="0", g=None):
+    """One token through one block.  ``ffn``/``where``/``g``: the operator
+    plugged into the second residual branch, the block's id and its
+    operands (:func:`_decode_ffn`).  Returns (x, cache, routed or None)."""
+    routed = None
     if bc.kind in ("attn_mlp", "attn_moe", "enc", "xattn"):
         h = apply_norm(p["ln_attn"], x, kind=bc.norm_kind)
-        if bc.kind == "xattn":
-            y, self_cache = A.decode_step(p["attn"], h, bc.attn, cache["self"], pos)
-            x = x + y
-            hc = apply_norm(p["ln_cross"], x, kind=bc.norm_kind)
-            x = x + A.attend_cross(
-                p["cross"], hc, cache["ck"], cache["cv"], bc.cross
-            )
-            cache = {"self": self_cache, "ck": cache["ck"], "cv": cache["cv"]}
-        else:
-            y, cache = A.decode_step(p["attn"], h, bc.attn, cache, pos)
-            x = x + y
-        delta, _ = _ffn(p, x, bc)
+        with jax.named_scope("attn"):  # attention and its KV update
+            if bc.kind == "xattn":
+                y, self_cache = A.decode_step(p["attn"], h, bc.attn,
+                                              cache["self"], pos)
+                x = x + y
+                hc = apply_norm(p["ln_cross"], x, kind=bc.norm_kind)
+                x = x + A.attend_cross(
+                    p["cross"], hc, cache["ck"], cache["cv"], bc.cross
+                )
+                cache = {"self": self_cache, "ck": cache["ck"],
+                         "cv": cache["cv"]}
+            else:
+                y, cache = A.decode_step(p["attn"], h, bc.attn, cache, pos)
+                x = x + y
+        delta, routed = _decode_ffn(p, x, bc, ffn, where, g)
         x = x + delta
     elif bc.kind == "rec":
         h = apply_norm(p["ln_rec"], x, kind=bc.norm_kind)
         y, cache = R.rglru_decode(p["rec"], h, bc.rglru, cache)
         x = x + y
-        delta, _ = _ffn(p, x, bc)
+        delta, routed = _decode_ffn(p, x, bc, ffn, where, g)
         x = x + delta
     elif bc.kind == "mlstm":
         h = apply_norm(p["ln"], x, kind=bc.norm_kind)
@@ -275,7 +307,7 @@ def block_decode(p, x, bc: BlockCfg, cache, pos, memory=None):
         h = apply_norm(p["ln"], x, kind=bc.norm_kind)
         y, cache = R.slstm_decode(p["core"], h, bc.slstm, cache)
         x = x + y
-    return x, cache
+    return x, cache, routed
 
 
 # ---------------------------------------------------------------------------
@@ -394,20 +426,43 @@ def stack_prefill(params, x, sc: StackCfg, caches, memory=None, start: int = 0):
     return x, {"reps": rep_caches, "tail": tail_caches}
 
 
-def stack_decode(params, x, sc: StackCfg, caches, pos, memory=None):
-    def body(x, xs):
-        p_sl, c_sl = xs
-        new_c = []
-        for i, bc in enumerate(sc.pattern):
-            x, c = block_decode(p_sl[i], x, bc, c_sl[i], pos, memory)
-            new_c.append(c)
-        return x, tuple(new_c)
+def stack_decode(params, x, sc: StackCfg, caches, pos, memory=None, ffn=None,
+                 ffn_xs=None):
+    """One token through the stack.  ``ffn(where, bc, p, h, g)`` replaces
+    each block's second residual branch (``where`` the block's id: ``"i"``
+    for pattern position i, ``"tailj"`` for tail block j; ``h`` the normed
+    hidden state; ``g`` the block's slice of ``ffn_xs``, laid out as ``params``:
+    ``{"reps": per-position rep-stacked pytrees, "tail": per-block}``;
+    None where a block has none).  Returns (x, caches, routes): routes
+    (L, B, held) marks, for each expert layer in depth order, the held
+    experts each row was routed to; None without expert layers."""
+    if ffn_xs is None:
+        ffn_xs = {"reps": (None,) * len(sc.pattern), "tail": [None] * sc.n_tail}
 
-    x, rep_caches = jax.lax.scan(body, x, (params["reps"], caches["reps"]))
+    def body(x, xs):
+        p_sl, c_sl, g_sl = xs
+        new_c, routes = [], []
+        for i, bc in enumerate(sc.pattern):
+            x, c, r = block_decode(p_sl[i], x, bc, c_sl[i], pos, memory, ffn,
+                                   str(i), g_sl[i])
+            new_c.append(c)
+            if r is not None:
+                routes.append(r)
+        return x, (tuple(new_c), tuple(routes))
+
+    x, (rep_caches, rep_routes) = jax.lax.scan(
+        body, x, (params["reps"], caches["reps"], tuple(ffn_xs["reps"])))
+    # depth order: rep r's expert layers, then rep r + 1's, then the tail
+    routes = ([jnp.stack(rep_routes, axis=1).reshape(
+        (-1,) + rep_routes[0].shape[1:])] if rep_routes else [])
     tail_caches = []
     for i in range(sc.n_tail):
-        x, c = block_decode(
-            params["tail"][i], x, sc.pattern[i], caches["tail"][i], pos, memory
+        x, c, r = block_decode(
+            params["tail"][i], x, sc.pattern[i], caches["tail"][i], pos,
+            memory, ffn, f"tail{i}", ffn_xs["tail"][i],
         )
         tail_caches.append(c)
-    return x, {"reps": rep_caches, "tail": tail_caches}
+        if r is not None:
+            routes.append(r[None])
+    routes = jnp.concatenate(routes) if routes else None
+    return x, {"reps": rep_caches, "tail": tail_caches}, routes

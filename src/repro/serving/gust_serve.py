@@ -1,27 +1,36 @@
 """GUST-sparse serving: the paper's technique as a first-class feature.
 
 Decode-time LM inference is matvec-dominated.  ``gustify`` converts a
-trained model's MLP weights into GUST plans (magnitude pruning ->
-``repro.plan`` -> packed blocks), **once**, at weight-load time — the
-paper's §3.3/§5.3 amortization ("the scheduling for each matrix only
-needs to be computed once ... even if the vector changes").
-``decode_step_gust`` then mirrors the model's decode step but routes each
-layer's MLP matvecs through :meth:`GustPlan.spmm`.
+trained model's MLP and expert weights into GUST plans (magnitude
+pruning -> ``repro.plan`` -> packed blocks), **once**, at weight-load
+time — the paper's §3.3/§5.3 amortization ("the scheduling for each
+matrix only needs to be computed once ... even if the vector changes").
+``gust_ffn`` plugs the plans into the model's own decode
+(``LM.decode``'s operator hook), so every block kind with an MLP or an
+expert layer, at any pattern position, decodes its matvecs through
+:meth:`GustPlan.spmm` with the model's attention and caches.
 
-Layer stacking is :meth:`GustPlan.stack`: per-layer packed artifacts are
-equalized to a uniform stream length (padded layout: uniform C_pad via
-``repad_to``; ragged layout: uniform block count via ``repad_to_blocks``)
-so the leaves stack along the reps axis and the layer scan stays a single
-compact HLO — the GUST plan is literally part of the serving checkpoint.
-With ``GustServeConfig.ragged`` the stack holds ragged color-block
-streams, so skewed pruned matrices stop streaming dead padding cycles
-through every decode step.  The wire format is the plan's
+Plans are grouped per block of the stack: pattern position ``i`` (its
+layers stacked along the reps axis) is ``"i"``, tail block ``j`` is
+``"tailj"``; the tree's ``mats`` key is ``"<block>.<matrix>"``.  An
+expert layer holds one plan per held expert and matrix, stacked along
+(rep, expert).  Stacking is :meth:`GustPlan.stack`: the packed artifacts
+are equalized to a uniform stream length (padded layout: uniform C_pad
+via ``repad_to``; ragged layout: uniform block count via
+``repad_to_blocks``) so the leaves stack and the layer scan stays a
+single compact HLO — the GUST plan is literally part of the serving
+checkpoint.  With ``GustServeConfig.ragged`` the stack holds ragged
+color-block streams, so skewed pruned matrices stop streaming dead
+padding cycles through every decode step.  The wire format is the plan's
 ``to_spec``/``from_spec`` leaves/meta codec, shared with ``dryrun_specs``.
 
-Applies to pattern-length-1 dense archs (phi3/yi/mistral-large/llava/
-gemma3 would need per-position stacks — gemma3 and the MoE archs run the
-per-expert variant documented in DESIGN.md §5).  ``dryrun_specs`` sizes
-the schedule stream from the paper's Eq. 9 bound
+The expert FFN runs every held expert's three plans on all B columns of
+the batch, each token's hidden vector scaled by its combine weight (0
+where the token was not routed to that expert) before ``w_down``: exact,
+dropless and static in shape, at held·B computed columns against the
+routed (token, held expert) pairs (``ServeLoop`` counts both).
+
+``dryrun_specs`` sizes the schedule stream from the paper's Eq. 9 bound
 (:meth:`GustPlan.spec_for`) so the 512-chip dry-run lowers the GUST
 decode path without running the scheduler.
 """
@@ -45,12 +54,11 @@ from repro.core.plan import GustPlan, PlanConfig, plan
 from repro.core.plan_store import PlanStore
 from repro.core.scheduler import sched_counters
 from repro.core.spans import Span
-from repro.models import transformer as T
-from repro.models.layers import apply_norm
 from repro.models.model_zoo import LM
 from repro.resilience.fallback import fallback_counters
 
-__all__ = ["GustServeConfig", "gustify", "decode_step_gust", "dryrun_specs"]
+__all__ = ["GustServeConfig", "gustify", "gust_ffn", "decode_step_gust",
+           "dryrun_specs"]
 
 _MLP_MATS = ("w_gate", "w_up", "w_down")
 
@@ -125,59 +133,83 @@ def _plan_cycles(p: GustPlan) -> int:
     return -1  # loaded artifact predates summary sidecars
 
 
+def _blocks(lm: LM):
+    """The blocks GUST serves, as (block id, group, index, BlockCfg):
+    every pattern position (its layers rep-stacked) and every tail block.
+    Raises for a block kind whose FFN GUST cannot serve yet."""
+    sc = lm.stack
+    kinds = {bc.kind for bc in sc.pattern}
+    if lm.cfg.is_encdec or not kinds <= {"attn_mlp", "attn_moe"}:
+        raise ValueError(
+            "gustify serves attention blocks with an MLP or an expert layer "
+            f"(got pattern {[b.kind for b in sc.pattern]})"
+        )
+    return ([(str(i), "reps", i, bc) for i, bc in enumerate(sc.pattern)]
+            + [(f"tail{j}", "tail", j, sc.pattern[j])
+               for j in range(sc.n_tail)])
+
+
 def gustify(lm: LM, params, cfg: GustServeConfig, *,
             store: Optional[PlanStore] = None) -> Dict:
-    """Build stacked GUST plans for every rep-layer MLP matrix.
+    """Build stacked GUST plans for every MLP and held-expert matrix.
 
-    Returns ``{"mats": {name: {"leaves": {...(R, ...)}, "meta": static
-    layout tuple}}, "stats": {...}}`` — per matrix, the
-    :meth:`GustPlan.stack` of one plan per layer.  ``stats`` holds one
-    entry per matrix, ``gustify_s`` (this call's host seconds) and
-    ``build_s``: the seconds of its phases, each a span
-    (:class:`~repro.core.spans.Span`) — ``prune`` (weights to the host,
-    magnitude pruning), ``colour`` and ``pack`` (``repro.plan``'s spans,
-    read off ``sched_counters``), ``stack`` (equalize and stack the
-    layers) and ``upload`` (wait until the stacked leaves are on the
-    device).
+    Returns ``{"mats": {"<block>.<matrix>": {"leaves": {...}, "meta":
+    static layout tuple}}, "stats": {...}}`` — per block and matrix the
+    :meth:`GustPlan.stack` of one plan per layer (leaves ``(R, ...)``) or,
+    in an expert layer, per layer and held expert (``(R, E, ...)``); a
+    tail block's leaves lead with 1.  ``stats`` holds one entry per
+    key (each plan's cycles and nonzeros, nested as the leaves lead),
+    ``gustify_s`` (this call's host seconds) and ``build_s``: the seconds
+    of its phases, each a span (:class:`~repro.core.spans.Span`) —
+    ``prune`` (weights to the host, magnitude pruning), ``colour`` and
+    ``pack`` (``repro.plan``'s spans, read off ``sched_counters``),
+    ``stack`` (equalize and stack the layers) and ``upload`` (wait until
+    the stacked leaves are on the device).
 
     With ``cfg.plan_store`` (or an explicit ``store``), plans read
     through the persistent :class:`PlanStore`: a warm start rebuilds
     every stacked artifact from disk with zero coloring work.
     """
-    if len(lm.stack.pattern) != 1 or lm.stack.pattern[0].kind != "attn_mlp":
-        raise ValueError(
-            "gustify currently targets homogeneous dense stacks "
-            f"(got pattern {[b.kind for b in lm.stack.pattern]})"
-        )
+    blocks = _blocks(lm)
     if store is None and cfg.plan_store is not None:
         store = PlanStore(cfg.plan_store, verify=cfg.store_verify)
     t0 = time.perf_counter()
-    mlp_params = params["stack"]["reps"][0]["mlp"]
-    reps = lm.stack.reps
     pc = cfg.plan_config
     phases = dict.fromkeys(("prune", "colour", "pack", "stack", "upload"), 0.0)
     out: Dict = {"mats": {}, "stats": {}}
     fb0 = dict(fallback_counters)  # attribute downgrades to this build
     sc0 = {k: sched_counters[k] for k in ("colour_s", "pack_s")}
     plans_of = {}
-    for name in cfg.mats:
-        with Span("build.prune", phases, "prune"):
-            w_stack = np.asarray(mlp_params[name])  # (R, d_in, d_out)
-        # one plan per layer, through the content-keyed cache: re-gustifying
-        # the same weights (e.g. a compact re-export) reuses the schedule
-        plans = []
-        for r in range(reps):
+    for where, group, i, bc in blocks:
+        weights = params["stack"][group][i]
+        weights = weights["moe" if bc.kind == "attn_moe" else "mlp"]
+        for name in cfg.mats:
+            key = f"{where}.{name}"
             with Span("build.prune", phases, "prune"):
-                coo = _prune_to_coo(w_stack[r], cfg)
-            plans.append(plan(coo, pc, cache=default_cache, store=store))
-        arts = [p.artifact for p in plans]  # packs each layer (build.pack)
-        with Span("build.stack", phases, "stack"):
-            out["mats"][name] = GustPlan.stack(arts)
-        plans_of[name] = plans
+                w = np.asarray(weights[name])  # ([R,] [E,] d_in, d_out)
+            if group == "tail":
+                w = w[None]
+            lead = w.shape[:-2]  # (R,) or (R, E)
+            # one plan per layer (and held expert), through the
+            # content-keyed cache: re-gustifying the same weights (e.g. a
+            # compact re-export) reuses the schedule
+            plans, nnz = [], []
+            for m in w.reshape((-1,) + w.shape[-2:]):
+                with Span("build.prune", phases, "prune"):
+                    coo = _prune_to_coo(m, cfg)
+                plans.append(plan(coo, pc, cache=default_cache, store=store))
+                nnz.append(coo.nnz)
+            arts = [p.artifact for p in plans]  # packs each (build.pack)
+            with Span("build.stack", phases, "stack"):
+                st = GustPlan.stack(arts)
+                st["leaves"] = jax.tree.map(
+                    lambda a: a.reshape(lead + a.shape[1:]), st["leaves"])
+            out["mats"][key] = st
+            plans_of[key] = (plans, nnz, lead)
     with Span("build.upload", phases, "upload"):
         jax.block_until_ready([m["leaves"] for m in out["mats"].values()])
-    for name, plans in plans_of.items():
-        # uniform stream size after stacking = max over layers (stack()
+    for key, (plans, plan_nnz, lead) in plans_of.items():
+        # uniform stream size after stacking = max over plans (stack()
         # equalizes to it); read off the artifacts, not meta positions
         if cfg.ragged:
             size_stat = {
@@ -185,11 +217,16 @@ def gustify(lm: LM, params, cfg: GustServeConfig, *,
             }
         else:
             size_stat = {"c_pad": max(p.artifact.c_pad for p in plans)}
-        leaves = out["mats"][name]["leaves"]
+        leaves = out["mats"][key]["leaves"]
         nnz = int(np.count_nonzero(np.asarray(leaves["m_blk"])))
         slots = leaves["m_blk"].size
-        out["stats"][name] = {
-            "cycles_per_layer": [_plan_cycles(p) for p in plans],
+
+        def nested(vals):
+            return np.asarray(vals).reshape(lead).tolist()
+
+        out["stats"][key] = {
+            "cycles_per_layer": nested([_plan_cycles(p) for p in plans]),
+            "nnz": nested(plan_nnz),
             "stream_utilization": nnz / max(slots, 1),
             "streamed_slots": int(slots),
             **size_stat,
@@ -208,62 +245,91 @@ def gustify(lm: LM, params, cfg: GustServeConfig, *,
     return out
 
 
-def _gust_mlp(gust_slice, metas, x, mlp_kind: str, cfg: GustServeConfig):
-    """x: (B, 1, d).  SwiGLU/GeGLU with every matvec through GUST."""
-    b = x.shape[0]
-    xt = x[:, 0].T.astype(jnp.float32)  # (d, B)
-    act = jax.nn.silu if mlp_kind == "swiglu" else jax.nn.gelu
+def _act(kind: str):
+    return jax.nn.silu if kind == "swiglu" else jax.nn.gelu
+
+
+def _matvec(leaves, meta, pc: PlanConfig, v):
+    """One plan's slice of a stack, rebuilt through the leaves/meta codec
+    (the same GustPlan route every entry point takes), times v (n, B)."""
+    p = GustPlan.from_spec({"leaves": leaves, "meta": meta}, config=pc)
+    return p.spmm(v).astype(jnp.float32)
+
+
+def _gust_mlp(g, metas, xt, act, pc: PlanConfig):
+    """xt: (d, B).  SwiGLU/GeGLU with every matvec through GUST; (d, B)."""
+    h = act(_matvec(g["w_gate"], metas["w_gate"], pc, xt)) * _matvec(
+        g["w_up"], metas["w_up"], pc, xt)  # (f, B)
+    return _matvec(g["w_down"], metas["w_down"], pc, h)
+
+
+def _gust_experts(g, metas, xt, w, pc: PlanConfig):
+    """xt: (d, B); w: (held, B) combine weights.  Every held expert's
+    SwiGLU on all B columns, its hidden vectors scaled by the weights
+    before ``w_down``, summed over the experts (a scan over them); (d, B)."""
+
+    def one(y, xs):
+        leaves, we = xs
+        h = jax.nn.silu(_matvec(leaves["w_gate"], metas["w_gate"], pc, xt))
+        h = h * _matvec(leaves["w_up"], metas["w_up"], pc, xt) * we[None, :]
+        return y + _matvec(leaves["w_down"], metas["w_down"], pc, h), None
+
+    y, _ = jax.lax.scan(one, jnp.zeros(xt.shape, jnp.float32), (g, w))
+    return y
+
+
+def gust_ffn(lm: LM, gust, cfg: GustServeConfig):
+    """``(ffn, ffn_xs)`` for :meth:`LM.decode`: the plans of ``gust`` (a
+    :func:`gustify` or :func:`dryrun_specs` tree) as every served block's
+    second residual branch.  An MLP block runs its three plans; an expert
+    layer routes on the device (``MoESpec.route``, float32 at
+    ``HIGHEST``) and runs :func:`_gust_experts`, returning which held
+    experts each row was routed to."""
     pc = cfg.plan_config
+    names = {}
+    for key in gust["mats"]:
+        where, name = key.split(".", 1)
+        names.setdefault(where, []).append(name)
 
-    def mv(name, v):
-        # one layer's slice of the stacked plan, rebuilt through the
-        # leaves/meta codec — the same GustPlan route every entry point takes
-        p = GustPlan.from_spec(
-            {"leaves": gust_slice[name], "meta": metas[name]}, config=pc
-        )
-        return p.spmm(v)
+    def group(where, tail=False):
+        if where not in names:
+            return None
+        g = {n: gust["mats"][f"{where}.{n}"]["leaves"] for n in names[where]}
+        return jax.tree.map(lambda a: a[0], g) if tail else g
 
-    g = act(mv("w_gate", xt).astype(jnp.float32))
-    u = mv("w_up", xt).astype(jnp.float32)
-    h = (g * u)  # (f, B)
-    y = mv("w_down", h)  # (d, B)
-    return y.T[:, None, :].astype(x.dtype)  # (B, 1, d)
+    sc = lm.stack
+    ffn_xs = {"reps": tuple(group(str(i)) for i in range(len(sc.pattern))),
+              "tail": [group(f"tail{j}", tail=True)
+                       for j in range(sc.n_tail)]}
+
+    def ffn(where, bc, p, h, g):
+        metas = {n: gust["mats"][f"{where}.{n}"]["meta"] for n in names[where]}
+        xt = h[:, 0].T.astype(jnp.float32)  # (d, B)
+        if bc.kind == "attn_moe":
+            _, _, gate, idx = bc.moe.route(p["moe"]["router"], h[:, 0])
+            w, routed = bc.moe.held_weights(gate, idx)  # (B, held)
+            y = _gust_experts(g, metas, xt, w.T, pc)
+        else:
+            y, routed = _gust_mlp(g, metas, xt, _act(bc.mlp_kind), pc), None
+        return y.T[:, None, :].astype(h.dtype), routed  # (B, 1, d)
+
+    return ffn, ffn_xs
 
 
 def decode_step_gust(lm: LM, params, gust, caches, tokens, pos, *,
-                     cfg: GustServeConfig, dtype=jnp.bfloat16):
-    """Mirror of LM.decode_step with the per-layer MLP routed through GUST.
-
-    ``gust`` is the pytree produced by :func:`gustify` (or dryrun_specs).
-    ``pos`` is a scalar or (B,) vector of per-slot positions — the GUST
-    path shares the continuous-batching machinery (slot-local caches,
-    per-row attention masks) with the dense decode, so mixed-length
-    request batches serve correctly through ``ServeLoop`` here too.
-    """
-    sc = lm.stack
-    bc = sc.pattern[0]
-    x = lm._embed_tokens(params, tokens, dtype)
-    metas = {k: v["meta"] for k, v in gust["mats"].items()}
-    gust_leaves = {k: v["leaves"] for k, v in gust["mats"].items()}
-
-    def body(x, xs):
-        p_sl, c_sl, g_sl = xs
-        h = apply_norm(p_sl["ln_attn"], x, kind=bc.norm_kind)
-        from repro.models import attention as A
-
-        with jax.named_scope("attn"):  # attention and its KV update
-            y, cache = A.decode_step(p_sl["attn"], h, bc.attn, c_sl, pos)
-        x = x + y
-        h = apply_norm(p_sl["ln_mlp"], x, kind=bc.norm_kind)
-        x = x + _gust_mlp(g_sl, metas, h, bc.mlp_kind, cfg)
-        return x, cache
-
-    x, rep_caches = jax.lax.scan(
-        body, x, (params["stack"]["reps"][0], caches["reps"][0], gust_leaves)
-    )
-    new_caches = {"reps": (rep_caches,), "tail": caches["tail"]}
-    logits = lm._logits(params, x)
-    return logits, new_caches
+                     cfg: GustServeConfig, dtype=jnp.bfloat16,
+                     routes: bool = False):
+    """``LM.decode`` with every served block's MLP or expert layer
+    through the GUST plans of ``gust`` (:func:`gust_ffn`); returns
+    (logits, caches), with ``routes`` also ``LM.decode``'s routes (which
+    held experts each row was routed to).  ``pos`` is a scalar or (B,)
+    vector of per-slot positions — the GUST path is the model's own
+    decode, so mixed-length request batches serve through ``ServeLoop``
+    as the dense path does."""
+    ffn, ffn_xs = gust_ffn(lm, gust, cfg)
+    logits, caches, r = lm.decode(params, caches, tokens, pos, dtype=dtype,
+                                  ffn=ffn, ffn_xs=ffn_xs)
+    return (logits, caches, r) if routes else (logits, caches)
 
 
 def dryrun_specs(lm: LM, cfg: GustServeConfig) -> Dict:
@@ -272,20 +338,24 @@ def dryrun_specs(lm: LM, cfg: GustServeConfig) -> Dict:
     the dry-run proof that the GUST decode path lowers and fits.  Each
     matrix is a :meth:`GustPlan.spec_for` plan (honoring ``cfg.ragged``:
     a ragged config dry-runs the ragged program, the bound sizing every
-    window's block count), stacked across reps by the shared codec."""
-    reps = lm.stack.reps
-    d = lm.cfg.d_model
-    f = lm.cfg.d_ff
+    window's block count), stacked as :func:`gustify` stacks them by the
+    shared codec."""
+    d, f = lm.cfg.d_model, lm.cfg.d_ff
     pc = cfg.plan_config
     out: Dict = {"mats": {}, "stats": {}}
-    for name in cfg.mats:
-        m, n = (d, f) if name == "w_down" else (f, d)
-        proto = GustPlan.spec_for(
-            m, n, pc, colors=expected_colors_bound(n, cfg.density, pc.l)
-        )
-        spec = proto.to_spec()
-        out["mats"][name] = {
-            "leaves": stacked_leaf_specs(proto.artifact, reps),
-            "meta": spec["meta"],
-        }
+    for where, group, _, bc in _blocks(lm):
+        lead = (lm.stack.reps if group == "reps" else 1,)
+        if bc.kind == "attn_moe":
+            lead += (bc.moe.held,)
+        for name in cfg.mats:
+            m, n = (d, f) if name == "w_down" else (f, d)
+            proto = GustPlan.spec_for(
+                m, n, pc, colors=expected_colors_bound(n, cfg.density, pc.l)
+            )
+            one = stacked_leaf_specs(proto.artifact, 1)
+            out["mats"][f"{where}.{name}"] = {
+                "leaves": {k: jax.ShapeDtypeStruct(lead + v.shape[1:], v.dtype)
+                           for k, v in one.items()},
+                "meta": proto.to_spec()["meta"],
+            }
     return out

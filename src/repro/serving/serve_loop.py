@@ -108,15 +108,18 @@ def make_serve_fns(lm: LM, cfg: ServeConfig, gust_tree=None):
     """Returns (prefill_fn, decode_fn, init_caches_fn), all pure.
 
     ``init_caches_fn`` takes an optional batch override (the serve loop
-    prefills new requests at batch=1); ``decode_fn`` takes ``pos`` as a
-    (B,) int32 vector of per-slot positions (a scalar still works for
-    homogeneous callers such as the dry-run).
+    prefills new requests at batch=1).  ``decode_fn(params, caches,
+    tokens, pos, counts, active[, leaves]) -> (logits, caches, counts)``
+    takes ``pos`` as a (B,) int32 vector of per-slot positions.  For a
+    model with expert layers ``counts`` are the routing counters
+    (:func:`_moe_counters`), advanced on the device by this step's routes
+    over the rows ``active`` (B,) marks; otherwise both are None.
 
-    With GUST, ``decode_fn(params, caches, tokens, pos, leaves)`` takes
-    the stacked plan leaves (``{name: tree["mats"][name]["leaves"]}``) as
-    an argument: closed over, they would be embedded in the compiled
-    program as constants — hundreds of MB at published widths — and every
-    compile would copy them.  ``gust_tree`` supplies only the plan meta.
+    With GUST, ``decode_fn`` takes the stacked plan leaves
+    (``{key: tree["mats"][key]["leaves"]}``) as its last argument: closed
+    over, they would be embedded in the compiled program as constants —
+    hundreds of MB at published widths — and every compile would copy
+    them.  ``gust_tree`` supplies only the plan meta.
     """
     dtype = cfg.jnp_dtype
 
@@ -127,25 +130,58 @@ def make_serve_fns(lm: LM, cfg: ServeConfig, gust_tree=None):
         with jax.named_scope("prefill"):
             return lm.prefill(params, batch, caches, dtype=dtype)
 
+    def count(counts, active, routes=None):
+        if counts is None:
+            return None
+        r = routes & active[None, :, None]  # (L, B, held)
+        return {
+            "routed_pairs": counts["routed_pairs"] + r.sum(dtype=jnp.int32),
+            "computed_cols": counts["computed_cols"] + routes.size,
+            "expert_tokens": counts["expert_tokens"] + r.sum(1, dtype=jnp.int32),
+            "expert_steps": counts["expert_steps"] + r.any(1).astype(jnp.int32),
+        }
+
     if cfg.gust is not None and cfg.gust.enable:
         if gust_tree is None:
             raise ValueError("gust serving requires a gustify()/dryrun tree")
 
         metas = {k: v["meta"] for k, v in gust_tree["mats"].items()}
 
-        def decode_fn(params, caches, tokens, pos, leaves):
+        def decode_fn(params, caches, tokens, pos, counts, active, leaves):
             tree = {"mats": {k: {"leaves": leaves[k], "meta": metas[k]}
                              for k in metas}}
-            return decode_step_gust(
-                lm, params, tree, caches, tokens, pos,
-                cfg=cfg.gust, dtype=dtype,
-            )
+            logits, caches, *routes = decode_step_gust(
+                lm, params, tree, caches, tokens, pos, cfg=cfg.gust,
+                dtype=dtype, routes=counts is not None)
+            return logits, caches, count(counts, active, *routes)
     else:
 
-        def decode_fn(params, caches, tokens, pos):
-            return lm.decode_step(params, caches, tokens, pos, dtype=dtype)
+        def decode_fn(params, caches, tokens, pos, counts, active):
+            logits, caches, routes = lm.decode(params, caches, tokens, pos,
+                                               dtype=dtype)
+            return logits, caches, count(counts, active, routes)
 
     return prefill_fn, decode_fn, init_caches
+
+
+def _moe_counters(lm: LM):
+    """Zeroed device-side routing counters of ``lm``'s expert layers, or
+    None without them: ``routed_pairs`` ((token, held expert) pairs
+    routed, over active rows), ``computed_cols`` (expert columns the
+    decode computes: held experts x batch, every expert layer),
+    ``expert_tokens`` and ``expert_steps`` ((L, held): tokens routed to
+    each held expert of each expert layer, and the decode steps in which
+    it got at least one)."""
+    sc = lm.stack
+    moe = [bc for bc in sc.pattern if bc.kind == "attn_moe"]
+    if not moe:
+        return None
+    layers = sc.reps * len(moe) + sum(
+        sc.pattern[j].kind == "attn_moe" for j in range(sc.n_tail))
+    per_expert = jnp.zeros((layers, moe[0].moe.held), jnp.int32)
+    zero = jnp.zeros((), jnp.int32)
+    return {"routed_pairs": zero, "computed_cols": zero,
+            "expert_tokens": per_expert, "expert_steps": per_expert}
 
 
 def make_sampler(temperature: float) -> Callable:
@@ -225,6 +261,9 @@ class ServeLoop:
         # (prefill is pure, so the template is never mutated)
         self._cache_template_b1 = init(1)
         self.slots = [_Slot() for _ in range(cfg.batch)]
+        # routing counters of the expert layers, advanced on the device
+        # by every decode step; read_counters() brings them to the host
+        self._counts = _moe_counters(lm)
         self._base_key = jax.random.PRNGKey(seed)
         self._next_id = 0
         self.pending: Deque[Tuple] = collections.deque()
@@ -480,10 +519,16 @@ class ServeLoop:
                 for i in active:
                     toks[i, 0] = self.slots[i].generated[-1]
                     pos[i] = self.slots[i].pos
+                active_rows = None
+                if self._counts is not None:
+                    active_rows = np.zeros((self.cfg.batch,), bool)
+                    active_rows[active] = True
+                    active_rows = jnp.asarray(active_rows)
                 faults.trip("serve.decode")
-                logits, new_caches = self._decode(
+                logits, new_caches, new_counts = self._decode(
                     self.params, self.caches, jnp.asarray(toks),
-                    jnp.asarray(pos), *self._decode_extra,
+                    jnp.asarray(pos), self._counts, active_rows,
+                    *self._decode_extra,
                 )
                 sampled = self._sample_rows(
                     logits[:, 0],
@@ -516,6 +561,7 @@ class ServeLoop:
             return len([s for s in self.slots if s.active])
         self._decode_failures = 0
         self.caches = new_caches
+        self._counts = new_counts
         st["decode_steps"] += 1
         st["active_slot_steps"] += len(active)
         with Span("serve.retire", st):
@@ -549,6 +595,18 @@ class ServeLoop:
             return 0.0
         return self.stats["active_slot_steps"] / (steps * self.cfg.batch)
 
+    def read_counters(self) -> Dict:
+        """Bring the device-side routing counters into ``stats`` (one
+        transfer; the decode steps never wait for them): ``moe.routed_pairs``,
+        ``moe.computed_cols``, and per expert layer and held expert
+        ``moe.expert_tokens`` and ``moe.expert_steps`` (lists of lists).
+        Returns ``stats``; a model without expert layers adds nothing."""
+        if self._counts is not None:
+            host = jax.device_get(self._counts)
+            for k, v in host.items():
+                self.stats["moe." + k] = v.tolist()
+        return self.stats
+
     def resilience_stats(self) -> Dict[str, int]:
         """Lifecycle + degradation counters in one snapshot: terminal
         statuses, contained decode retries, and the process-wide
@@ -571,4 +629,5 @@ class ServeLoop:
         many steps even under persistent faults."""
         for _ in range(max_steps):
             if self.step() == 0 and not self.pending:
-                return
+                break
+        self.read_counters()

@@ -12,7 +12,7 @@ planned with l=256 (w_up/w_gate: 11008 x 4096, w_down: 4096 x 11008),
 decoded at batch 4; stream lengths are the planner's at that density.
 The padded resident kernels also compile at the Table-3 ``mouse_gene``
 plan (45,101 square, l=256) at batch 1, the widest resident walk the
-benchmark runs.
+benchmark runs, and at Mellum2's expert matrices at batch 64.
 """
 
 import os
@@ -42,6 +42,8 @@ KERNELS = [
 L, B, C_BLK = 256, 4, 8
 #: mouse_gene at l=256: (num_windows, seg_count, c_pad), batch 1.
 MOUSEGENE = (177, 177, 888)
+#: Mellum2's expert matrices at l=256: (num_windows, seg_count, c_pad).
+MELLUM_EXPERTS = {"w_up": (4, 9, 296), "w_down": (9, 4, 152)}
 
 
 @pytest.fixture(scope="module")
@@ -151,6 +153,28 @@ def test_resident_spmv_compiles_at_mousegene(one_chip, pipeline):
         fn, family = K.make_gust_spmv(w, c_pad, L, seg, 1, **kw), \
             "gust_spmv_padded_resident"
     _assert_kernel(_compile_text(fn, args), family)
+
+
+@pytest.mark.parametrize("mat", sorted(MELLUM_EXPERTS))
+def test_resident_spmv_compiles_at_mellum_experts(one_chip, mat):
+    """Mellum2's expert matrices (896 x 2304 and 2304 x 896 at density
+    0.1, l=256, c_pad as the planner packs them), decoded at batch 64: the
+    double-buffered resident kernel the served expert layers run."""
+    from repro.kernels import gust_spmv as K
+
+    w, seg, c_pad = MELLUM_EXPERTS[mat]
+    rows, b = w * c_pad, 64
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    args = (spec((rows, L), "float32"), spec((rows, L), "int32"),
+            spec((rows, L), "int32"),
+            spec((b, K._resident_x_rows(seg), L), "float32"))
+    fn = K.make_gust_spmv_db(w, c_pad, L, seg, b, c_blk=C_BLK,
+                             interpret=False)
+    _assert_kernel(_compile_text(fn, args), "gust_spmv_padded_resident_db")
 
 
 @pytest.mark.parametrize("mat", sorted(SHAPES))

@@ -51,8 +51,8 @@ def test_compact_gust_decode_close_to_full():
                                  cfg=gcfg, dtype=jnp.float32)
         outs[compact] = np.asarray(lg)
         # stream bytes: compact must be exactly half (12 -> 6 B/slot)
-        m_blk = gust["mats"]["w_down"]["leaves"]["m_blk"]
-        col = gust["mats"]["w_down"]["leaves"]["col_blk"]
+        m_blk = gust["mats"]["0.w_down"]["leaves"]["m_blk"]
+        col = gust["mats"]["0.w_down"]["leaves"]["col_blk"]
         per_slot = m_blk.dtype.itemsize + 2 * col.dtype.itemsize
         assert per_slot == (6 if compact else 12)
     err = np.abs(outs[True] - outs[False]).max() / np.abs(outs[False]).max()

@@ -173,3 +173,28 @@ def test_serve_exits_nonzero_on_failed_request(monkeypatch, tmp_path, capsys):
     monkeypatch.setattr(ServeLoop, "_admit", broken_admit)
     assert serve.main(argv) == 1
     assert "FAILED" in capsys.readouterr().err
+
+
+def test_serve_driver_moe_gust(tmp_path):
+    """The CLI serves the reduced Mellum2 (expert layers, sliding and
+    full attention) through GUST and prints the routing counters."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "jax_cache")
+    p = subprocess.run(
+        [sys.executable, "-m", "repro.launch.serve", "--arch",
+         "mellum2_12b_a2_5b", "--requests", "3", "--max-new", "20",
+         "--prompt-len", "8", "--gust", "--density", "0.5",
+         "--gust-length", "16"],
+        env=env, capture_output=True, text=True, timeout=600,
+    )
+    assert p.returncode == 0, p.stderr
+    stats = json.loads(p.stdout.strip().splitlines()[-1])
+    assert stats["requests"] == 3 and stats["gust"]
+    moe = stats["moe"]
+    # 5 expert layers of the reduced stack (a period and a tail), 2 held
+    assert len(moe["expert_tokens"]) == 5
+    assert all(len(row) == 2 for row in moe["expert_tokens"])
+    assert 0 < moe["routed_pairs"] <= moe["computed_cols"]
+    assert moe["routed_pairs"] == sum(map(sum, moe["expert_tokens"]))
+

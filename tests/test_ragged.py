@@ -380,7 +380,7 @@ def test_serving_ragged_stack_matches_padded():
     gr = GustServeConfig(density=0.3, gust_length=16, ragged=True)
     gust_p = gustify(lm, params, gp)
     gust_r = gustify(lm, params, gr)
-    for name in gp.mats:
+    for name in gust_p["mats"]:
         st_p, st_r = gust_p["stats"][name], gust_r["stats"][name]
         # ragged stacks never stream more slots, and utilization only rises
         assert st_r["streamed_slots"] <= st_p["streamed_slots"]

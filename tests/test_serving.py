@@ -44,6 +44,15 @@ def dense_lm():
     return lm, lm.init(KEY)
 
 
+@pytest.fixture(scope="module")
+def moe_lm():
+    """Expert layers (2 of 4 experts held, top 2) under sliding-window
+    and full YaRN attention: the reduced Mellum2."""
+    cfg = get_arch("mellum2_12b_a2_5b").reduced()
+    lm = build_model(cfg)
+    return lm, lm.init(KEY)
+
+
 def test_serve_loop_generates(dense_lm):
     lm, params = dense_lm
     loop = ServeLoop(lm, params, ServeConfig(batch=4, seq_len=64, dtype="float32"))
@@ -79,8 +88,8 @@ def test_gust_decode_identity_at_full_density(dense_lm):
     err = np.abs(np.asarray(ld) - np.asarray(lg)).max() / np.abs(np.asarray(ld)).max()
     assert err < 1e-4, err
     # full density -> every scheduled slot is a real nonzero along rows
-    for name in gcfg.mats:
-        assert gust["stats"][name]["stream_utilization"] > 0.5
+    for key in gust["mats"]:
+        assert gust["stats"][key]["stream_utilization"] > 0.5
 
 
 def test_gust_decode_pallas_xla_parity(dense_lm):
@@ -176,34 +185,39 @@ def test_second_admission_mid_decode_is_isolated(dense_lm):
     assert loop.completed[rb] == solo_b
 
 
-def test_mixed_length_concurrent_matches_solo(dense_lm):
+def test_mixed_length_concurrent_matches_solo(dense_lm, moe_lm):
     """Regression (ISSUE 4 bug 2): slots with different prompt lengths
     decode at their OWN positions.  The old step() decoded everyone at
-    max(slot.pos), corrupting every shorter request."""
-    lm, params = dense_lm
+    max(slot.pos), corrupting every shorter request.  Expert layers route
+    without a capacity on the serving path, so their rows stay
+    independent too."""
     prompts = [np.arange(5, dtype=np.int32),
                np.arange(11, dtype=np.int32),
                np.arange(2, 9, dtype=np.int32)]
-    solos = [_solo(lm, params, p, max_new=6) for p in prompts]
-    loop = ServeLoop(lm, params, ServeConfig(batch=4, seq_len=64, dtype="float32"))
-    rids = [loop.submit(p, max_new=6) for p in prompts]
-    loop.run_to_completion()
-    for rid, solo in zip(rids, solos):
-        assert loop.completed[rid] == solo
+    for lm, params in (dense_lm, moe_lm):
+        solos = [_solo(lm, params, p, max_new=6) for p in prompts]
+        loop = ServeLoop(lm, params,
+                         ServeConfig(batch=4, seq_len=64, dtype="float32"))
+        rids = [loop.submit(p, max_new=6) for p in prompts]
+        loop.run_to_completion()
+        for rid, solo in zip(rids, solos):
+            assert loop.completed[rid] == solo
 
 
-def test_gust_mixed_length_concurrent_matches_solo(dense_lm):
-    """The GUST decode path runs through the same per-slot machinery."""
-    lm, params = dense_lm
+def test_gust_mixed_length_concurrent_matches_solo(dense_lm, moe_lm):
+    """The GUST decode path runs through the same per-slot machinery, for
+    MLP blocks and for held experts under the router alike."""
     gcfg = GustServeConfig(density=0.5, gust_length=16)
     prompts = [np.arange(4, dtype=np.int32), np.arange(9, dtype=np.int32)]
-    solos = [_solo(lm, params, p, max_new=4, batch=2, gust=gcfg) for p in prompts]
-    sc = ServeConfig(batch=2, seq_len=64, dtype="float32", gust=gcfg)
-    loop = ServeLoop(lm, params, sc)
-    rids = [loop.submit(p, max_new=4) for p in prompts]
-    loop.run_to_completion()
-    for rid, solo in zip(rids, solos):
-        assert loop.completed[rid] == solo
+    for lm, params in (dense_lm, moe_lm):
+        solos = [_solo(lm, params, p, max_new=4, batch=2, gust=gcfg)
+                 for p in prompts]
+        sc = ServeConfig(batch=2, seq_len=64, dtype="float32", gust=gcfg)
+        loop = ServeLoop(lm, params, sc)
+        rids = [loop.submit(p, max_new=4) for p in prompts]
+        loop.run_to_completion()
+        for rid, solo in zip(rids, solos):
+            assert loop.completed[rid] == solo
 
 
 def test_queue_admission_drains_stream(dense_lm):

@@ -139,7 +139,7 @@ def test_served_programs_carry_named_scopes(gust_loop):
     b = loop.cfg.batch
     dec = op_names(loop._decode, loop.params, loop.caches,
                    jnp.zeros((b, 1), jnp.int32), jnp.zeros((b,), jnp.int32),
-                   *loop._decode_extra)
+                   loop._counts, None, *loop._decode_extra)
     pre = op_names(loop._prefill, loop.params,
                    {"tokens": jnp.zeros((1, 5), jnp.int32)},
                    loop._cache_template_b1)
