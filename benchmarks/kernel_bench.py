@@ -39,9 +39,22 @@ host where Pallas runs in interpret mode, so interpret-mode wall clock is
    headroom for timer noise): ``resolve_tuning`` falls back to the
    baseline unless a candidate measures faster, so tuning can only help.
 
+4. **Cost per color block on the chip** (``--block-cost``, run on a
+   TPU, alone): the compiled double-buffered resident kernel every
+   benchmark cell runs, at the cells' geometries, on random
+   lane-structured collision-free streams (timing does not depend on
+   the values); median of ``--iters`` samples of 20 calls in a row
+   after a warm-up, reported as microseconds per color block.  The stream's routing
+   operand is whatever the installed kernel takes (the adder index or
+   the source-lane table, read off ``route_rows``'s signature), so the
+   same script measures two checkouts of the program side by side.
+
 Usage:
     PYTHONPATH=src python benchmarks/kernel_bench.py
         [--widths 16384 65536] [--iters 5] [--tiny] [--out BENCH_kernel.json]
+    PYTHONPATH=src python benchmarks/kernel_bench.py --block-cost \
+        [--geometries mousegene yi_w_up yi_w_down mellum_w_up mellum_w_down]
+        [--iters 7] [--out block_cost.json]
 
 ``--tiny`` (CI smoke): small widths, every wall-clock gate report-only,
 separate output file — never clobbers the committed full-run record.
@@ -292,6 +305,81 @@ def _tune_section(n: int, batch: int, iters: int, rng) -> dict:
     }
 
 
+# ---------------------------------------------------------------------------
+# 4. cost per color block of the compiled kernel (TPU)
+# ---------------------------------------------------------------------------
+
+#: (num_windows, seg_count, c_pad, batch) of the benchmark cells' GUST
+#: matrices at l = 256: mouse_gene at B=1, yi_6b's MLP at the chat
+#: cell's batch 8, Mellum2's experts at batch 64; ``tiny`` for a CPU run.
+BLOCK_GEOMETRIES = {
+    "mousegene": (177, 177, 888, 1),
+    "yi_w_up": (43, 16, 552, 8),
+    "yi_w_down": (16, 43, 1408, 8),
+    "mellum_w_up": (4, 9, 296, 64),
+    "mellum_w_down": (9, 4, 152, 64),
+    "tiny": (2, 3, 16, 8),
+}
+
+
+def _random_stream(rows: int, l: int, seg_count: int, rng):
+    """A collision-free lane-structured stream: every slot real, each
+    cycle row's adders a permutation of the lanes (drawn from a pool),
+    columns in a random segment at the slot's lane or its reverse.
+    Returns ``(m, col, row, src)``."""
+    pool = np.argsort(rng.random((256, l)), axis=1).astype(np.int32)
+    row = pool[rng.integers(0, pool.shape[0], rows)]
+    src = np.argsort(row, axis=1).astype(np.int32)
+    lane = np.arange(l, dtype=np.int32)
+    flip = rng.random((rows, l)) < 0.5
+    col = (rng.integers(0, seg_count, (rows, l), dtype=np.int32) * l
+           + np.where(flip, l - 1 - lane, lane))
+    m = rng.standard_normal((rows, l)).astype(np.float32)
+    return m, col.astype(np.int32), row, src
+
+
+def _block_cost(name: str, iters: int, rng, l: int = 256, c_blk: int = 8,
+                reps: int = 20):
+    """Microseconds per color block of ``gust_spmv_padded_resident_db``
+    at one geometry: each of ``iters`` samples times ``reps`` calls in a
+    row and waits once, so the host's dispatch is not counted."""
+    import inspect
+
+    import jax
+
+    from repro.kernels import gust_spmv as K
+
+    w, seg, c_pad, b = BLOCK_GEOMETRIES[name]
+    rows = w * c_pad
+    m, col, row, src = _random_stream(rows, l, seg, rng)
+    routing = "src" if "src_blk" in inspect.signature(
+        K.route_rows).parameters else "row"
+    x = rng.standard_normal((b, K._resident_x_rows(seg), l)).astype(
+        np.float32)
+    args = [jnp.asarray(a) for a in
+            (m, col, src if routing == "src" else row, x)]
+    fn = jax.jit(K.make_gust_spmv_db(w, c_pad, l, seg, b, c_blk=c_blk))
+    out = fn(*args)
+    out.block_until_ready()
+    times = []
+    for _ in range(iters):  # back-to-back calls: dispatch hides behind
+        t0 = time.perf_counter()  # the device, which then never idles
+        for _ in range(reps):
+            y = fn(*args)
+        y.block_until_ready()
+        times.append((time.perf_counter() - t0) / reps)
+    t = float(np.median(times))
+    blocks = rows // c_blk
+    return {
+        "geometry": name, "num_windows": w, "seg_count": seg,
+        "c_pad": c_pad, "b": b, "blocks": blocks, "routing": routing,
+        "call_ms": t * 1e3, "us_per_block": t / blocks * 1e6,
+        "times_ms": [x * 1e3 for x in times],
+        "out_sha1": __import__("hashlib").sha1(
+            np.asarray(out).tobytes()).hexdigest(),
+    }
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--widths", type=int, nargs="+", default=[16384, 65536])
@@ -314,7 +402,22 @@ def main(argv=None):
                     help="CI smoke: small widths, wall-clock gates "
                     "report-only, separate output file")
     ap.add_argument("--out", default=None)
+    ap.add_argument("--block-cost", action="store_true",
+                    help="only time the compiled kernel per color block "
+                    "(section 4; on a TPU)")
+    ap.add_argument("--geometries", nargs="+",
+                    default=[g for g in BLOCK_GEOMETRIES if g != "tiny"],
+                    choices=sorted(BLOCK_GEOMETRIES))
     args = ap.parse_args(argv)
+    if args.block_cost:
+        rows = []
+        for g in args.geometries:
+            rows.append(_block_cost(g, args.iters, np.random.default_rng(0)))
+            print(json.dumps(rows[-1]), flush=True)
+        if args.out:
+            with open(args.out, "w") as f:
+                json.dump(rows, f, indent=1)
+        return
     if args.tiny:
         args.widths = [16384]
         args.batch = min(args.batch, 4)

@@ -94,6 +94,9 @@ RULES: Dict[str, Tuple[str, str, str]] = {
     "GUST-P17": ("error", "Gather-locality invariants",
                  "every col_blk is in [0, seg_count*l): the padded-x gather "
                  "stays in-bounds"),
+    "GUST-P18": ("error", "Packed-format invariants",
+                 "src_blk (when present) is the crossbar inverse: the lane "
+                 "of the nonzero slot routed to each adder, else -1"),
 }
 
 
@@ -162,6 +165,7 @@ def _normalize(plan_or_leaves, meta) -> Tuple[Dict[str, np.ndarray], Tuple]:
         leaves = {
             "m_blk": obj.m_blk, "col_blk": obj.col_blk,
             "row_blk": obj.row_blk, "row_perm": obj.row_perm,
+            "src_blk": obj.src_blk,
             "seg_blk": obj.seg_blk, "col_loc": obj.col_loc,
         }
         if getattr(obj, "scale_blk", None) is not None:
@@ -295,7 +299,8 @@ def _check_meta_shapes(leaves, g: _Geometry) -> List[Finding]:
 def _check_dtypes(leaves, g: _Geometry) -> List[Finding]:
     out: List[Finding] = []
     idx_dtypes = {leaves[k].dtype.name
-                  for k in ("col_blk", "row_blk", "col_loc") if k in leaves}
+                  for k in ("col_blk", "row_blk", "src_blk", "col_loc")
+                  if k in leaves}
     if not idx_dtypes <= {"int16", "int32"}:
         out.append(_finding(
             "GUST-P05", "col_blk",
@@ -591,6 +596,30 @@ def _check_collisions(leaves, g: _Geometry, zero) -> List[Finding]:
         "collision-free within a window)", bad)]
 
 
+def _check_sources(leaves, zero) -> List[Finding]:
+    """GUST-P18: the kernels route by ``src_blk``, so it must name, for
+    every adder of every stream row, the lane of the nonzero slot routed
+    there, and -1 where none is.  Artifacts written before the leaf
+    existed carry none (their loaders derive it): nothing to check."""
+    src = leaves.get("src_blk")
+    if src is None:
+        return []
+    row = np.asarray(leaves["row_blk"], np.int64)
+    if src.shape != row.shape:
+        return [_finding("GUST-P18", "src_blk",
+                         f"shape {src.shape} != row_blk {row.shape}")]
+    rows, l = row.shape
+    want = np.full(rows * l, -1, np.int64)
+    r, j = np.nonzero(~zero)
+    want[r * l + row[r, j]] = j
+    bad = np.asarray(src, np.int64) != want.reshape(rows, l)
+    if not bad.any():
+        return []
+    return [_finding(
+        "GUST-P18", "src_blk",
+        "not the inverse of row_blk over the nonzero slots", bad)]
+
+
 def _verify_coo(coo) -> List[Finding]:
     """GUST-P16: canonical sparse COO (the SpGEMM output contract)."""
     out: List[Finding] = []
@@ -661,6 +690,9 @@ def verify(plan_or_leaves, meta: Optional[Sequence] = None) -> List[Finding]:
     findings += _check_gather_tables(leaves, g, col_ok=not col_findings)
     findings += _check_scales(leaves, g, zero)
     findings += _check_collisions(leaves, g, zero)
+    if not any(f.rule in ("GUST-P01", "GUST-P03", "GUST-P14")
+               for f in findings):
+        findings += _check_sources(leaves, zero)  # needs sound rows/values
     return findings
 
 

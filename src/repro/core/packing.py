@@ -53,6 +53,17 @@ contract as a type.  The steps themselves:
        stays in-bounds and preserves the straight-lane structure the
        fused kernel's gather relies on (``col % l ∈ {lane, l-1-lane}``);
      * ``row_blk`` is ``0``     — safe because the value is 0.
+   Crossbar leaf.  Both layouts carry ``src_blk``, shaped and
+   typed like ``row_blk``: the inverse of each cycle row's routing,
+   ``src_blk[r, a]`` = the lane ``j`` with ``row_blk[r, j] == a`` and
+   ``m_blk[r, j] != 0``, else ``-1`` (:func:`_source_lanes`).  Padding
+   rows are all ``-1``; a lane whose stored value is 0 (a padding slot, a
+   zero value, an int8 value that quantized to 0) is never a source,
+   which is exact because it contributes 0.  Collision-freedom of the
+   edge coloring leaves at most one nonzero lane per adder and cycle, so
+   the kernels route each cycle with in-register lane gathers by this
+   table (``kernels.gust_spmv.route_rows``); ``row_blk`` stays for the
+   XLA backend, the sharded segment sum, the oracles and the verifier.
    Ragged-stream metadata contract: blocks of one window are contiguous
    (``block_window`` is sorted), window ``w`` owns stream blocks
    ``block_starts[w]:block_starts[w+1]``, every window owns at least one
@@ -174,6 +185,9 @@ class PackedSchedule:
       row_blk: (W * C_pad, l) int32 adder index; 0 in padding slots.
       row_perm:(W * l,) int32 — original row of each scheduled row position
                (identity-extended past m).
+      src_blk: (W * C_pad, l) index dtype — source lane of each adder per
+               cycle row (the inverse of ``row_blk`` over the nonzero
+               slots), -1 where no nonzero product reaches the adder.
       seg_blk: (T_blk, S_blk) int32 — per-(c_blk, l)-block distinct column
                segments (sorted; padded with segment 0).  T_blk =
                W * C_pad / c_blk.
@@ -198,6 +212,7 @@ class PackedSchedule:
     col_blk: jnp.ndarray
     row_blk: jnp.ndarray
     row_perm: jnp.ndarray
+    src_blk: jnp.ndarray
     seg_blk: jnp.ndarray
     col_loc: jnp.ndarray
     l: int
@@ -212,7 +227,7 @@ class PackedSchedule:
 
     def tree_flatten(self):
         leaves = (self.m_blk, self.col_blk, self.row_blk, self.row_perm,
-                  self.seg_blk, self.col_loc, self.scale_blk)
+                  self.src_blk, self.seg_blk, self.col_loc, self.scale_blk)
         aux = (self.l, self.num_windows, self.c_pad, self.shape, self.fusable,
                self.c_blk, self.s_blk, self.identity_perm)
         return leaves, aux
@@ -246,7 +261,8 @@ class PackedSchedule:
 
         Preserves every leaf dtype (a compact int16 stream stays int16)
         and the packed-format invariants: new value slots are 0, new
-        column slots gather the slot's own lane, new row slots are 0.
+        column slots gather the slot's own lane, new row slots are 0 and
+        new source lanes -1.
         Used to equalize C_pad across stacked layers in serving.
         """
         if c_pad == self.c_pad:
@@ -291,6 +307,7 @@ class PackedSchedule:
             col_blk=col_grown,
             row_blk=grow(self.row_blk, np.zeros(l, np.int32)),
             row_perm=self.row_perm,
+            src_blk=grow(self.src_blk, np.full(l, -1, np.int32)),
             seg_blk=jnp.asarray(seg_blk),
             col_loc=jnp.asarray(col_loc, self.col_loc.dtype),
             l=l,
@@ -330,6 +347,9 @@ class RaggedSchedule:
       row_blk:      (T_blk * c_blk, l) int adder index; 0 in padding slots.
       row_perm:     (W * l,) int32 — original row of each scheduled row
                     position (identity-extended past m).
+      src_blk:      (T_blk * c_blk, l) index dtype — source lane of each
+                    adder per cycle row, -1 where none (as
+                    :class:`PackedSchedule`).
       seg_blk:      (T_blk, S_blk) int32 — per-block distinct column
                     segments (sorted; padded with segment 0).
       col_loc:      (T_blk * c_blk, l) col_blk remapped to block-local
@@ -352,6 +372,7 @@ class RaggedSchedule:
     col_blk: jnp.ndarray
     row_blk: jnp.ndarray
     row_perm: jnp.ndarray
+    src_blk: jnp.ndarray
     seg_blk: jnp.ndarray
     col_loc: jnp.ndarray
     block_window: jnp.ndarray
@@ -368,7 +389,7 @@ class RaggedSchedule:
 
     def tree_flatten(self):
         leaves = (self.m_blk, self.col_blk, self.row_blk, self.row_perm,
-                  self.seg_blk, self.col_loc,
+                  self.src_blk, self.seg_blk, self.col_loc,
                   self.block_window, self.block_starts, self.scale_blk)
         aux = (self.l, self.num_windows, self.c_blk, self.num_blocks,
                self.shape, self.fusable, self.s_blk, self.identity_perm)
@@ -452,6 +473,7 @@ class RaggedSchedule:
             col_blk=col_grown,
             row_blk=grow(self.row_blk, np.zeros(l, np.int32)),
             row_perm=self.row_perm,
+            src_blk=grow(self.src_blk, np.full(l, -1, np.int32)),
             seg_blk=jnp.asarray(seg_blk),
             col_loc=jnp.asarray(col_loc, self.col_loc.dtype),
             block_window=bw,
@@ -584,6 +606,17 @@ def _is_int8(value_dtype) -> bool:
     return jnp.dtype(value_dtype) == jnp.dtype(jnp.int8)
 
 
+def _stored_values(m_b: np.ndarray, c_blk: int, value_dtype):
+    """The value plane as the artifact stores it (host numpy, so the
+    source lanes are read off the stored values): int8 codes plus their
+    per-block scales on a quantized stream, else ``m_b`` in
+    ``value_dtype``.  Returns ``(values, scale or None, value_dtype)``."""
+    if _is_int8(value_dtype):
+        q, scale = _quantize_stream(m_b, c_blk)
+        return q, jnp.asarray(scale), jnp.int8
+    return m_b.astype(jnp.dtype(value_dtype), copy=False), None, value_dtype
+
+
 def _local_gather_tables(
     col: np.ndarray, l: int, c_blk: int, s_min: int = 1
 ) -> Tuple[np.ndarray, np.ndarray, int]:
@@ -631,6 +664,24 @@ def _local_gather_tables(
     return seg_blk, col_loc, s_blk
 
 
+def _source_lanes(m_blk, row_blk) -> np.ndarray:
+    """The crossbar's inverse of a packed stream (host numpy):
+    ``src[r, a]`` is the lane ``j`` with ``row_blk[r, j] == a`` and
+    ``m_blk[r, j] != 0``, else ``-1``.  ``m_blk`` is the stored value
+    leaf (int8 codes on a quantized stream), so a lane whose product is 0
+    by its value is never a source.  Edge coloring leaves at most one
+    nonzero lane per adder and cycle row, so the scatter below writes
+    each entry at most once.  Leading axes (stacked layers) are kept;
+    returns int32 of ``row_blk``'s shape."""
+    row = np.asarray(row_blk)
+    l = row.shape[-1]
+    src = np.full(row.size, -1, np.int32)
+    nz = np.flatnonzero(np.asarray(m_blk).reshape(-1) != 0)
+    lane = (nz % l).astype(np.int32)
+    src[nz - lane + row.reshape(-1)[nz]] = lane
+    return src.reshape(row.shape)
+
+
 def _extended_row_perm(sched: GustSchedule) -> np.ndarray:
     """row_perm identity-extended to the full W*l scheduled row positions
     (shared by both fixed-shape layouts)."""
@@ -654,17 +705,14 @@ def pack_schedule(
     m_b, c_b, r_b, c_pad, fusable = pack_blocks(sched, c_blk)
     row_perm = _extended_row_perm(sched)
     seg_blk, col_loc, s_blk = _local_gather_tables(c_b, l, c_blk)
-    scale = None
-    if _is_int8(value_dtype):
-        m_b, scale = _quantize_stream(m_b, c_blk)
-        scale = jnp.asarray(scale)
-        value_dtype = jnp.int8
+    m_b, scale, value_dtype = _stored_values(m_b, c_blk, value_dtype)
 
     return PackedSchedule(
         m_blk=jnp.asarray(m_b, value_dtype),
         col_blk=jnp.asarray(c_b, index_dtype),
         row_blk=jnp.asarray(r_b, index_dtype),
         row_perm=jnp.asarray(row_perm),
+        src_blk=jnp.asarray(_source_lanes(m_b, r_b), index_dtype),
         seg_blk=jnp.asarray(seg_blk),
         col_loc=jnp.asarray(col_loc, index_dtype),
         l=l,
@@ -748,17 +796,14 @@ def pack_ragged(
     block_window = np.repeat(np.arange(W, dtype=np.int32), bpw)
     row_perm = _extended_row_perm(sched)
     seg_blk, col_loc, s_blk = _local_gather_tables(c_b, l, c_blk)
-    scale = None
-    if _is_int8(value_dtype):
-        m_b, scale = _quantize_stream(m_b, c_blk)
-        scale = jnp.asarray(scale)
-        value_dtype = jnp.int8
+    m_b, scale, value_dtype = _stored_values(m_b, c_blk, value_dtype)
 
     return RaggedSchedule(
         m_blk=jnp.asarray(m_b, value_dtype),
         col_blk=jnp.asarray(c_b, index_dtype),
         row_blk=jnp.asarray(r_b, index_dtype),
         row_perm=jnp.asarray(row_perm),
+        src_blk=jnp.asarray(_source_lanes(m_b, r_b), index_dtype),
         seg_blk=jnp.asarray(seg_blk),
         col_loc=jnp.asarray(col_loc, index_dtype),
         block_window=jnp.asarray(block_window),
@@ -825,10 +870,12 @@ def splice_ragged_blocks(
     m_old = np.asarray(old.m_blk)
     c_old = np.asarray(old.col_blk)
     r_old = np.asarray(old.row_blk)
+    s_old = np.asarray(old.src_blk)
     m_new = np.zeros((t_new * cb, l), dtype=m_old.dtype)
     c_new = np.empty((t_new * cb, l), dtype=c_old.dtype)
     c_new[:] = np.arange(l, dtype=c_old.dtype)  # padding invariant: col==lane
     r_new = np.zeros((t_new * cb, l), dtype=r_old.dtype)
+    s_new = np.full((t_new * cb, l), -1, dtype=s_old.dtype)
     scale_new = np.ones((t_new,), np.float32) if quant else None
 
     if clean.size:
@@ -837,6 +884,7 @@ def splice_ragged_blocks(
         m_new[dst] = m_old[src]
         c_new[dst] = c_old[src]
         r_new[dst] = r_old[src]
+        s_new[dst] = s_old[src]
         if quant:
             sb = _ranges(bs_old[clean], bpw_old[clean])
             db = _ranges(bs_new[clean], bpw_new[clean])
@@ -884,6 +932,7 @@ def splice_ragged_blocks(
         m_new[dst] = np.asarray(sub.m_blk)
         c_new[dst] = np.asarray(sub.col_blk)
         r_new[dst] = np.asarray(sub.row_blk)
+        s_new[dst] = np.asarray(sub.src_blk)
         if quant:
             db = _ranges(bs_new[dirty], bpw_new[dirty])
             scale_new[db] = np.asarray(sub.scale_blk, np.float32)
@@ -895,6 +944,7 @@ def splice_ragged_blocks(
         col_blk=jnp.asarray(c_new, index_dtype),
         row_blk=jnp.asarray(r_new, index_dtype),
         row_perm=jnp.asarray(row_perm),
+        src_blk=jnp.asarray(s_new, index_dtype),
         seg_blk=jnp.asarray(seg_blk),
         col_loc=jnp.asarray(col_loc, index_dtype),
         block_window=jnp.asarray(np.repeat(np.arange(W, dtype=np.int32), bpw_new)),
@@ -1056,6 +1106,7 @@ def packed_spec(
         col_blk=sds((W * c_pad, l), index_dtype),
         row_blk=sds((W * c_pad, l), index_dtype),
         row_perm=sds((W * l,), jnp.int32),
+        src_blk=sds((W * c_pad, l), index_dtype),
         seg_blk=sds((t_blk, s_blk), jnp.int32),
         col_loc=sds((W * c_pad, l), index_dtype),
         l=l,
@@ -1092,6 +1143,7 @@ def ragged_spec(
         col_blk=sds((num_blocks * c_blk, l), index_dtype),
         row_blk=sds((num_blocks * c_blk, l), index_dtype),
         row_perm=sds((W * l,), jnp.int32),
+        src_blk=sds((num_blocks * c_blk, l), index_dtype),
         seg_blk=sds((num_blocks, s_blk), jnp.int32),
         col_loc=sds((num_blocks * c_blk, l), index_dtype),
         block_window=sds((num_blocks,), jnp.int32),
@@ -1126,6 +1178,7 @@ def packed_leaves(p: PackedSchedule) -> Dict:
         "col_blk": p.col_blk,
         "row_blk": p.row_blk,
         "row_perm": p.row_perm,
+        "src_blk": p.src_blk,
         "seg_blk": p.seg_blk,
         "col_loc": p.col_loc,
     }
@@ -1141,14 +1194,29 @@ def packed_meta(p: PackedSchedule) -> Tuple:
             p.s_blk, p.identity_perm)
 
 
+def _leaf_sources(leaves: Dict):
+    """The ``src_blk`` leaf, derived from ``m_blk``/``row_blk`` by
+    :func:`_source_lanes` when the leaves were written without it (a
+    store entry or serving stack from before the leaf existed); a shape
+    spec derives a spec of ``row_blk``'s shape and dtype."""
+    if "src_blk" in leaves:
+        return leaves["src_blk"]
+    row = leaves["row_blk"]
+    if isinstance(row, jax.ShapeDtypeStruct):
+        return jax.ShapeDtypeStruct(row.shape, row.dtype)
+    return jnp.asarray(_source_lanes(leaves["m_blk"], row), row.dtype)
+
+
 def packed_from_leaves(leaves: Dict, meta: Tuple) -> PackedSchedule:
-    """Inverse of the codec: rebuild a PackedSchedule from leaves + meta."""
+    """Inverse of the codec: rebuild a PackedSchedule from leaves + meta
+    (leaves written without ``src_blk`` derive it, :func:`_leaf_sources`)."""
     l, w, c_pad, shape, fusable, c_blk, s_blk, identity_perm = meta
     return PackedSchedule(
         m_blk=leaves["m_blk"],
         col_blk=leaves["col_blk"],
         row_blk=leaves["row_blk"],
         row_perm=leaves["row_perm"],
+        src_blk=_leaf_sources(leaves),
         seg_blk=leaves["seg_blk"],
         col_loc=leaves["col_loc"],
         l=l, num_windows=w, c_pad=c_pad, shape=shape, fusable=fusable,
@@ -1166,6 +1234,7 @@ def ragged_leaves(r: RaggedSchedule) -> Dict:
         "col_blk": r.col_blk,
         "row_blk": r.row_blk,
         "row_perm": r.row_perm,
+        "src_blk": r.src_blk,
         "seg_blk": r.seg_blk,
         "col_loc": r.col_loc,
         "block_window": r.block_window,
@@ -1185,7 +1254,8 @@ def ragged_meta(r: RaggedSchedule) -> Tuple:
 
 
 def ragged_from_leaves(leaves: Dict, meta: Tuple) -> RaggedSchedule:
-    """Inverse of the ragged codec."""
+    """Inverse of the ragged codec (``src_blk`` derived when missing, as
+    :func:`packed_from_leaves`)."""
     tag, l, w, c_blk, t_blk, shape, fusable, s_blk, identity_perm = meta
     if tag != "ragged":
         raise ValueError(f"not a ragged meta tuple: {meta!r}")
@@ -1194,6 +1264,7 @@ def ragged_from_leaves(leaves: Dict, meta: Tuple) -> RaggedSchedule:
         col_blk=leaves["col_blk"],
         row_blk=leaves["row_blk"],
         row_perm=leaves["row_perm"],
+        src_blk=_leaf_sources(leaves),
         seg_blk=leaves["seg_blk"],
         col_loc=leaves["col_loc"],
         block_window=leaves["block_window"],

@@ -217,6 +217,11 @@ class PlanCost:
     * ``gather_walk_steps`` — steps of the resident walk per color block,
       ``ceil(seg_count / 8)``: each step gathers one group of eight
       column segments with sublane gathers;
+    * ``route_lane_gathers`` — lane gathers of the crossbar per color
+      block and 8 batch rows, ``c_blk · (l / 128)²`` (``c_blk`` at
+      l <= 128): each cycle row builds every 128-lane output chunk from
+      one gather per source chunk (32 at l = 256), on every block of
+      every call (plus ``(l / 128)²`` a block for the values);
     * ``x_vmem_bytes_resident`` / ``x_vmem_bytes_local`` — f32 x-tile
       VMEM residency per vector column: the whole padded vector
       (``seg_count · l · 4``) vs one block's tile working set
@@ -267,6 +272,7 @@ class PlanCost:
     gather_flops_resident: int
     gather_flops_local: int
     gather_walk_steps: int
+    route_lane_gathers: int
     x_vmem_bytes_resident: int
     x_vmem_bytes_local: int
     backend: str = "jnp"
@@ -1118,7 +1124,10 @@ class GustPlan:
             expected_execution_cycles,
             expected_utilization,
         )
-        from repro.kernels.gust_spmv import _resident_walk_steps
+        from repro.kernels.gust_spmv import (
+            _resident_walk_steps,
+            _route_lane_gathers,
+        )
 
         if self.sched is None:
             raise ValueError(
@@ -1150,6 +1159,8 @@ class GustPlan:
             gather_flops_resident=4 * streamed * a.seg_count,
             gather_flops_local=4 * streamed * a.s_blk,
             gather_walk_steps=_resident_walk_steps(a.seg_count),
+            route_lane_gathers=_route_lane_gathers(self.config.c_blk,
+                                                   self.l),
             x_vmem_bytes_resident=a.seg_count * self.l * 4,
             x_vmem_bytes_local=a.s_blk * self.l * 4,
             backend="pallas" if self._use_kernel() else "jnp",

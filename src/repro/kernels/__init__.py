@@ -2,7 +2,7 @@
 interpreted elsewhere; ``tests/test_chip_compile.py`` compiles them for a
 described v5e).
 
-  gust_spmv.py        -- flagship: fused gather + one-hot MXU routing SpMV
+  gust_spmv.py        -- flagship: fused gather + lane-gather routing SpMV
                          over the padded (W, C_pad/c_blk) grid
   gust_spmv_ragged.py -- ragged color-block streaming variant: 1-D
                          scalar-prefetch grid over real blocks only

@@ -30,12 +30,22 @@ TPU adaptation of the paper's three hardware levels (DESIGN.md §2):
                     the vector entries a window needs) and removes the
                     VMEM-residency cap on matrix *width*.
 
-  crossbar +   -> a one-hot routing matmul on the MXU, one per cycle row
-  adders          of the block: ``y_win += P_c @ OneHot(Row_sch[c])``.
+  crossbar +   -> in-register lane gathers, one per cycle row of the
+  adders          block: ``y_win[:, a] += M_c[Src_sch[c, a]] *
+                  G_c[:, Src_sch[c, a]]``, where the pack-time table
+                  ``src_blk`` (the inverse of ``row_blk`` over the nonzero
+                  slots, -1 for an adder no product reaches, whose value
+                  then reads 0) names the lane each adder takes.
                   Collision-freedom of the edge coloring is what makes this
                   exact — within a cycle each adder (output row) receives at
-                  most one partial product, so the one-hot rows never
-                  overlap within a cycle and the matmul loses nothing.
+                  most one nonzero partial product, so the route is a
+                  partial permutation and inverting it drops only zeros.
+                  Mosaic gathers lanes within one (8, 128) tile, so each
+                  8-row batch tile takes one gather per pair of 128-lane
+                  output and source chunks and a select by source chunk:
+                  ``c_blk * (l/128)**2`` gathers per block and tile
+                  (``PlanCost.route_lane_gathers``), plus ``(l/128)**2``
+                  per block that put the values in adder order; no MXU.
 
 Layout.  The resident kernels take x as ``(B, S8, l)`` f32 — batch row,
 column segment padded with zero rows to ``S8``, a multiple of 8, lane —
@@ -63,7 +73,7 @@ async copies (:func:`pltpu.make_async_copy`) into a two-slot ping/pong
 VMEM scratch — the classic latency-hiding pipeline:
 
   * :func:`make_gust_spmv_db` streams the **schedule block triple**
-    (m/col/row) from ANY-space memory, two ``(c_blk, l)`` tiles in
+    (m/col/src) from ANY-space memory, two ``(c_blk, l)`` tiles in
     flight, x VMEM-resident;
   * :func:`make_gust_spmv_local_db` keeps the schedule blocks
     pipeline-managed and ping/pongs the **x tiles** the block references
@@ -84,7 +94,7 @@ tables: the _dequant ``float32(q) * scale`` is fused into the accumulate
 ``interpret=None`` on every builder means "interpret only off TPU"
 (``_resolve_interpret``), the same rule as ``PlanConfig.interpret``.
 
-All arithmetic accumulates in f32 regardless of input dtype (MXU-native).
+All arithmetic accumulates in f32 regardless of input dtype.
 """
 
 from __future__ import annotations
@@ -133,7 +143,7 @@ def _lane_reverse(x):
     (the TPU's in-register lane gather), then the chunks in reverse
     order.  Pure data movement, so the result is bit-exact."""
     rows, l = x.shape
-    w = _VREG_LANES if l > _VREG_LANES and l % _VREG_LANES == 0 else l
+    w = _lane_chunk(l)
     rev = (w - 1) - jax.lax.broadcasted_iota(jnp.int32, (rows, w), 1)
     chunks = [
         jnp.take_along_axis(x[:, k * w:(k + 1) * w], rev, axis=1)
@@ -237,25 +247,71 @@ def _gather_resident(col_blk, xs_ref, xr_ref, *, l, seg_count):
     return tuple(per_cycle[c] for c in range(c_blk))
 
 
-def route_rows(m_blk, gs, row_blk, *, l):
-    """Multipliers + crossbar + adders for one block: per cycle row ``c``
-    the VPU multiply ``m[c] * g[c]`` and a one-hot routing matmul on the
-    MXU sending lane ``j`` to adder ``row[c, j]``.  Returns the block's
-    (B_pad, l) f32 contribution to its window accumulator.  Padding
-    slots carry m == 0 and row == 0, contributing exactly zero.  The
-    matmul runs at full f32 precision: each output sums at most one
-    nonzero product per cycle (collision-freedom), so it is exact."""
-    row = row_blk.astype(jnp.int32)
-    adder = jax.lax.broadcasted_iota(jnp.int32, (l, l), 0)
+def _lane_chunk(l: int) -> int:
+    """Width of one in-register lane gather: a vreg's 128 lanes when
+    ``l`` is a multiple of them, else all of ``l`` in one piece."""
+    return _VREG_LANES if l > _VREG_LANES and l % _VREG_LANES == 0 else l
+
+
+def _route_lane_gathers(c_blk: int, l: int) -> int:
+    """Lane gathers of :func:`route_rows` per color block and 8-row batch
+    tile: one per cycle row, output chunk and source chunk."""
+    return c_blk * (l // _lane_chunk(l)) ** 2
+
+
+def _gather_lanes(v, chunk, lane, *, w):
+    """``y[:, a] = v[:, chunk[a] * w + lane[a]]`` for a (R, l) ``v`` and
+    the decoded source table, one row per row of ``v`` or one (1, l) row
+    for all of them; lanes whose ``chunk`` is -1 (no source) get some
+    lane of ``v``.  Mosaic gathers lanes one (8, w) tile at a time with
+    an index of the operand's own shape, so each 8-row tile builds every
+    output chunk from one gather per source chunk and a select of the
+    lanes whose source lies in it."""
+    rows, l = v.shape
+    step = _SUBLANES if rows % _SUBLANES == 0 else rows
+    n = l // w
+    tiles = []
+    for t in range(0, rows, step):
+        at = slice(0, 1) if lane.shape[0] == 1 else slice(t, t + step)
+        outs = []
+        for k in range(n):
+            ks = slice(k * w, (k + 1) * w)
+            idx = jnp.broadcast_to(lane[at, ks], (step, w))
+            y = None
+            for s in range(n):
+                got = jnp.take_along_axis(v[t:t + step, s * w:(s + 1) * w],
+                                          idx, axis=1,
+                                          mode="promise_in_bounds")
+                y = got if y is None else jnp.where(chunk[at, ks] == s, got, y)
+            outs.append(y)
+        tiles.append(outs[0] if n == 1 else jnp.concatenate(outs, axis=1))
+    return tiles[0] if len(tiles) == 1 else jnp.concatenate(tiles)
+
+
+def route_rows(m_blk, gs, src_blk, *, l):
+    """Multipliers + crossbar + adders for one block, the crossbar as
+    in-register lane gathers by the pack-time source-lane table ``src``
+    (``-1``: no product reaches the adder): the block's values are put in
+    adder order once, ``m_src[c, a] = m[c, src[c, a]]`` (0 where no
+    source), then per cycle row ``c`` adder ``a`` takes ``m_src[c, a] *
+    g[c][:, src[c, a]]``.  Returns the block's (B_pad, l) f32
+    contribution to its window accumulator, summed over the cycle rows
+    in order.  Collision-freedom gives each adder at most one nonzero
+    product per cycle, and every lane the table leaves out carries
+    m == 0, so each adder gets exactly the product the one-hot route
+    sends it: exact up to the sign of a zero.  Routing the operands, not
+    the products, keeps the multiply next to the add it feeds, so the
+    interpreter's compiler fuses (or not) the two alike in every
+    kernel."""
+    w = _lane_chunk(l)
+    src = src_blk.astype(jnp.int32)
+    chunk = src // w  # floor: a missing source (-1) is chunk -1, lane w-1
+    lane = src - chunk * w
+    m_src = jnp.where(src >= 0, _gather_lanes(m_blk, chunk, lane, w=w), 0.0)
     acc = None
     for c, g in enumerate(gs):
-        p = m_blk[c:c + 1] * g  # (B_pad, l)
-        onehot = (adder == row[c:c + 1]).astype(jnp.float32)  # (l_out, l)
-        y = jax.lax.dot_general(
-            p, onehot, (((1,), (1,)), ((), ())),
-            precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32,
-        )
+        y = m_src[c:c + 1] * _gather_lanes(g, chunk[c:c + 1],
+                                           lane[c:c + 1], w=w)
         acc = y if acc is None else acc + y
     return acc
 
@@ -280,7 +336,7 @@ def _accumulate_out(y_ref, acc, first):
 
 def _kernel(*refs, l, seg_count, num_cb, quantized):
     scale_ref = refs[0] if quantized else None
-    m_ref, col_ref, row_ref, xs_ref, y_ref, xr_scr = refs[quantized:]
+    m_ref, col_ref, src_ref, xs_ref, y_ref, xr_scr = refs[quantized:]
     w, cb = pl.program_id(0), pl.program_id(1)
 
     @pl.when(cb == 0)
@@ -290,7 +346,7 @@ def _kernel(*refs, l, seg_count, num_cb, quantized):
     scale = None if scale_ref is None else scale_ref[w * num_cb + cb]
     gs = _gather_resident(col_ref[...], xs_ref, xr_scr, l=l,
                           seg_count=seg_count)
-    acc = route_rows(_dequant(m_ref[...], scale), gs, row_ref[...], l=l)
+    acc = route_rows(_dequant(m_ref[...], scale), gs, src_ref[...], l=l)
     _accumulate_out(y_ref, acc, cb == 0)
 
 
@@ -315,7 +371,7 @@ def make_gust_spmv(
     on every invocation.
 
     BlockSpecs:
-      * schedule stream (m/col/row): HBM -> VMEM tiles of (c_blk, l), one
+      * schedule stream (m/col/src): HBM -> VMEM tiles of (c_blk, l), one
         per grid step — the Buffer Filler pipeline;
       * x (b, S8, l), straight only: full-array VMEM residency; its
         lane-reversed twin is derived into VMEM scratch at each window's
@@ -323,9 +379,9 @@ def make_gust_spmv(
       * y: one (1, B_pad, l) accumulator tile per window, revisited
         across the color-block (reduction) grid dimension.
 
-    Call signature: ``fn(m_blk, col_blk, row_blk, xs)``; with
+    Call signature: ``fn(m_blk, col_blk, src_blk, xs)``; with
     ``quantized=True`` the per-block scales lead as the scalar-prefetch
-    operand: ``fn(scale_blk, m_blk, col_blk, row_blk, xs)``.
+    operand: ``fn(scale_blk, m_blk, col_blk, src_blk, xs)``.
     """
     if c_pad % c_blk:
         raise ValueError("c_pad must be a multiple of c_blk")
@@ -370,18 +426,18 @@ def gather_local_step(col_ref, xt_ref, s, g_scr, *, l):
         g_scr[c] = g
 
 
-def _local_flush(m_ref, row_ref, gs, y_ref, first, *, l, scale):
-    """Shared flush of the local kernels: _dequant (when quantized) + VPU
-    multiply of the gathered block + routing matmul, then
+def _local_flush(m_ref, src_ref, gs, y_ref, first, *, l, scale):
+    """Shared flush of the local kernels: _dequant (when quantized), the
+    route and multiply of the gathered block (:func:`route_rows`), then
     init-or-accumulate the window tile."""
-    acc = route_rows(_dequant(m_ref[...], scale), gs, row_ref[...], l=l)
+    acc = route_rows(_dequant(m_ref[...], scale), gs, src_ref[...], l=l)
     _accumulate_out(y_ref, acc, first)
 
 
 def _local_kernel(*refs, l, s_blk, num_cb, quantized):
     seg_ref = refs[0]
     scale_ref = refs[1] if quantized else None
-    m_ref, col_ref, row_ref, xt_ref, y_ref, g_scr = refs[1 + quantized:]
+    m_ref, col_ref, src_ref, xt_ref, y_ref, g_scr = refs[1 + quantized:]
     w, cb, s = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(s == 0)
@@ -394,7 +450,7 @@ def _local_kernel(*refs, l, s_blk, num_cb, quantized):
     def _flush():
         scale = None if scale_ref is None else scale_ref[w * num_cb + cb]
         gs = tuple(g_scr[c] for c in range(g_scr.shape[0]))
-        _local_flush(m_ref, row_ref, gs, y_ref, cb == 0, l=l, scale=scale)
+        _local_flush(m_ref, src_ref, gs, y_ref, cb == 0, l=l, scale=scale)
 
 
 @functools.lru_cache(maxsize=256)
@@ -412,7 +468,7 @@ def make_gust_spmv_local(
     """Build the segment-local pallas_call for a padded-schedule geometry.
 
     Call signature of the returned function:
-    ``fn(seg_flat, [scale_blk,] m_blk, col_loc, row_blk, xs)`` where
+    ``fn(seg_flat, [scale_blk,] m_blk, col_loc, src_blk, xs)`` where
     ``seg_flat`` is the pack-time segment table flattened to
     ``(T_blk * S_blk,)`` int32 (scalar-prefetched: it steers the x-tile
     pipeline before each body runs), ``col_loc`` holds the block-local
@@ -423,7 +479,7 @@ def make_gust_spmv_local(
     Grid ``(num_windows, c_pad/c_blk, S_blk)``: the inner dimension walks
     the ``S_blk`` x tiles the block references (``seg_flat[t*S_blk+s]``),
     accumulating the gathered block in VMEM scratch; the multiply +
-    routing matmul fire on the last tile.  Gather work per block is
+    route fire on the last tile.  Gather work per block is
     O(S_blk · C_blk · l) instead of the resident walk's
     ``ceil(seg_count / 8)`` steps of every batch row, and x VMEM
     residency is one tile instead of the whole vector — the wide-matrix
@@ -489,8 +545,8 @@ def _db_kernel(*refs, l, seg_count, c_blk, num_cb, quantized):
     in the same order as the single-buffered kernel's revisited output
     tile, so the result is bitwise identical."""
     scale_ref = refs[0] if quantized else None
-    (m_ref, col_ref, row_ref, xs_ref, y_ref,
-     m_scr, col_scr, row_scr, sems, xr_scr) = refs[quantized:]
+    (m_ref, col_ref, src_ref, xs_ref, y_ref,
+     m_scr, col_scr, src_scr, sems, xr_scr) = refs[quantized:]
     w = pl.program_id(0)
 
     def copies(slot, blk):
@@ -499,7 +555,7 @@ def _db_kernel(*refs, l, seg_count, c_blk, num_cb, quantized):
             stream_copy(m_ref, m_scr, sems.at[slot, 0], slot, start, c_blk),
             stream_copy(col_ref, col_scr, sems.at[slot, 1], slot, start,
                         c_blk),
-            stream_copy(row_ref, row_scr, sems.at[slot, 2], slot, start,
+            stream_copy(src_ref, src_scr, sems.at[slot, 2], slot, start,
                         c_blk),
         )
 
@@ -521,7 +577,7 @@ def _db_kernel(*refs, l, seg_count, c_blk, num_cb, quantized):
         gs = _gather_resident(col_scr[slot], xs_ref, xr_scr, l=l,
                               seg_count=seg_count)
         return acc + route_rows(
-            _dequant(m_scr[slot], scale), gs, row_scr[slot], l=l
+            _dequant(m_scr[slot], scale), gs, src_scr[slot], l=l
         )
 
     y_ref[0] = jax.lax.fori_loop(
@@ -545,7 +601,7 @@ def make_gust_spmv_db(
 ):
     """Double-buffered twin of :func:`make_gust_spmv`: same call
     signature and bitwise-identical output, but the schedule stream
-    (m/col/row) is fetched by manual async copies into a two-slot
+    (m/col/src) is fetched by manual async copies into a two-slot
     ping/pong scratch so the DMA of color block ``i+1`` overlaps the
     math of block ``i``, and the whole per-window reduction runs in one
     grid step (grid ``(W,)`` instead of ``(W, num_cb)``).
@@ -624,13 +680,13 @@ def _local_db_block(seg_ref, col_ref, xs_ref, xt_scr, sems, t, *, l, s_blk):
 def _local_db_kernel(*refs, l, s_blk, num_cb, quantized):
     seg_ref = refs[0]
     scale_ref = refs[1] if quantized else None
-    m_ref, col_ref, row_ref, xs_ref, y_ref, xt_scr, sems = refs[1 + quantized:]
+    m_ref, col_ref, src_ref, xs_ref, y_ref, xt_scr, sems = refs[1 + quantized:]
     w, cb = pl.program_id(0), pl.program_id(1)
     t = w * num_cb + cb
     gs = _local_db_block(seg_ref, col_ref, xs_ref, xt_scr, sems, t,
                         l=l, s_blk=s_blk)
     scale = None if scale_ref is None else scale_ref[t]
-    _local_flush(m_ref, row_ref, gs, y_ref, cb == 0, l=l, scale=scale)
+    _local_flush(m_ref, src_ref, gs, y_ref, cb == 0, l=l, scale=scale)
 
 
 @functools.lru_cache(maxsize=256)

@@ -94,7 +94,7 @@ __all__ = [
 def _kernel(*refs, l, seg_count, quantized):
     bw_ref, bs_ref = refs[:2]
     scale_ref = refs[2] if quantized else None
-    m_ref, col_ref, row_ref, xs_ref, y_ref, xr_scr = refs[2 + quantized:]
+    m_ref, col_ref, src_ref, xs_ref, y_ref, xr_scr = refs[2 + quantized:]
     t = pl.program_id(0)
     w = bw_ref[t]
     first = t == bs_ref[w]
@@ -106,7 +106,7 @@ def _kernel(*refs, l, seg_count, quantized):
     scale = None if scale_ref is None else scale_ref[t]
     gs = _gather_resident(col_ref[...], xs_ref, xr_scr, l=l,
                           seg_count=seg_count)
-    acc = route_rows(_dequant(m_ref[...], scale), gs, row_ref[...], l=l)
+    acc = route_rows(_dequant(m_ref[...], scale), gs, src_ref[...], l=l)
     _accumulate_out(y_ref, acc, first)
 
 
@@ -127,13 +127,13 @@ def make_gust_spmv_ragged(
 
     Call signature of the returned function:
     ``fn(block_window, block_starts, [scale_blk,] m_blk, col_blk,
-    row_blk, xs)`` with the stream blocks ``(num_blocks * c_blk, l)`` and
+    src_blk, xs)`` with the stream blocks ``(num_blocks * c_blk, l)`` and
     the straight resident x layout ``(b, S8, l)`` (the lane-reversed twin
     is derived in-kernel, once per window); returns ``(num_windows,
     B_pad, l)`` f32 per-window accumulators.
 
     BlockSpecs:
-      * schedule stream (m/col/row): HBM -> VMEM tiles of (c_blk, l), one
+      * schedule stream (m/col/src): HBM -> VMEM tiles of (c_blk, l), one
         real block per grid step — no padding blocks are ever streamed;
       * x (straight): full-array VMEM residency;
       * y: the (1, B_pad, l) accumulator tile of ``block_window[t]``,
@@ -169,7 +169,7 @@ def make_gust_spmv_ragged(
 def _local_kernel(*refs, l, s_blk, quantized):
     bw_ref, bs_ref = refs[:2]
     scale_ref = refs[3] if quantized else None
-    m_ref, col_ref, row_ref, xt_ref, y_ref, g_scr = refs[3 + quantized:]
+    m_ref, col_ref, src_ref, xt_ref, y_ref, g_scr = refs[3 + quantized:]
     t, s = pl.program_id(0), pl.program_id(1)
     w = bw_ref[t]
 
@@ -183,7 +183,7 @@ def _local_kernel(*refs, l, s_blk, quantized):
     def _flush():
         scale = None if scale_ref is None else scale_ref[t]
         gs = tuple(g_scr[c] for c in range(g_scr.shape[0]))
-        _local_flush(m_ref, row_ref, gs, y_ref, t == bs_ref[w],
+        _local_flush(m_ref, src_ref, gs, y_ref, t == bs_ref[w],
                     l=l, scale=scale)
 
 
@@ -204,12 +204,12 @@ def make_gust_spmv_ragged_local(
 
     Call signature of the returned function:
     ``fn(block_window, block_starts, seg_flat, [scale_blk,] m_blk,
-    col_loc, row_blk, xs)`` — ``seg_flat`` is the pack-time segment table
+    col_loc, src_blk, xs)`` — ``seg_flat`` is the pack-time segment table
     flattened to ``(T_blk * S_blk,)`` int32 and ``col_loc`` the
     block-local columns.  Grid ``(num_blocks, S_blk)``: the inner
     dimension streams the x tile of segment ``seg_flat[t*S_blk + s]``
     (one (1, B_pad, l) tile in VMEM per step), the gathered block
-    accumulates in VMEM scratch, and the multiply + routing matmul fire
+    accumulates in VMEM scratch, and the multiply + route fire
     on the last tile.  Combines the ragged stream's "no dead padding
     cycles" with the segment-local gather's O(S_blk) per-block cost — the
     full GUST utilization story.
@@ -257,8 +257,8 @@ def _db_kernel(*refs, l, seg_count, c_blk, quantized):
     bitwise identical."""
     bs_ref = refs[0]
     scale_ref = refs[1] if quantized else None
-    (m_ref, col_ref, row_ref, xs_ref, y_ref,
-     m_scr, col_scr, row_scr, sems, xr_scr) = refs[1 + quantized:]
+    (m_ref, col_ref, src_ref, xs_ref, y_ref,
+     m_scr, col_scr, src_scr, sems, xr_scr) = refs[1 + quantized:]
     w = pl.program_id(0)
     t0 = bs_ref[w]
     count = bs_ref[w + 1] - t0
@@ -269,7 +269,7 @@ def _db_kernel(*refs, l, seg_count, c_blk, quantized):
             stream_copy(m_ref, m_scr, sems.at[slot, 0], slot, start, c_blk),
             stream_copy(col_ref, col_scr, sems.at[slot, 1], slot, start,
                         c_blk),
-            stream_copy(row_ref, row_scr, sems.at[slot, 2], slot, start,
+            stream_copy(src_ref, src_scr, sems.at[slot, 2], slot, start,
                         c_blk),
         )
 
@@ -291,7 +291,7 @@ def _db_kernel(*refs, l, seg_count, c_blk, quantized):
         gs = _gather_resident(col_scr[slot], xs_ref, xr_scr, l=l,
                               seg_count=seg_count)
         return acc + route_rows(
-            _dequant(m_scr[slot], scale), gs, row_scr[slot], l=l
+            _dequant(m_scr[slot], scale), gs, src_scr[slot], l=l
         )
 
     y_ref[0] = jax.lax.fori_loop(
@@ -315,7 +315,7 @@ def make_gust_spmv_ragged_db(
 ):
     """Double-buffered twin of :func:`make_gust_spmv_ragged`, grid
     ``(W,)``.  Call signature:
-    ``fn(block_starts, [scale_blk,] m_blk, col_blk, row_blk, xs)`` —
+    ``fn(block_starts, [scale_blk,] m_blk, col_blk, src_blk, xs)`` —
     ``block_window`` is not needed (the window is the grid step; its
     block range comes from ``block_starts`` alone).  The schedule stream
     lives in ANY-space memory and ping/pongs through VMEM scratch sized
@@ -360,13 +360,13 @@ def _local_db_kernel(*refs, l, s_blk, quantized):
     padded ``_local_db_kernel``)."""
     bw_ref, bs_ref, seg_ref = refs[:3]
     scale_ref = refs[3] if quantized else None
-    m_ref, col_ref, row_ref, xs_ref, y_ref, xt_scr, sems = refs[3 + quantized:]
+    m_ref, col_ref, src_ref, xs_ref, y_ref, xt_scr, sems = refs[3 + quantized:]
     t = pl.program_id(0)
     w = bw_ref[t]
     gs = _local_db_block(seg_ref, col_ref, xs_ref, xt_scr, sems, t,
                         l=l, s_blk=s_blk)
     scale = None if scale_ref is None else scale_ref[t]
-    _local_flush(m_ref, row_ref, gs, y_ref, t == bs_ref[w], l=l, scale=scale)
+    _local_flush(m_ref, src_ref, gs, y_ref, t == bs_ref[w], l=l, scale=scale)
 
 
 @functools.lru_cache(maxsize=256)

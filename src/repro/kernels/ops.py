@@ -274,8 +274,9 @@ def _execute_spmm_impl(
         # the per-block scales ride as the last scalar-prefetch operand
         scale = (jnp.asarray(packed.scale_blk, jnp.float32),) if quant else ()
         kw = dict(c_blk=packed.c_blk, interpret=interpret, quantized=quant)
-        stream = (packed.m_blk, packed.col_blk, packed.row_blk, x2d)
-        stream_loc = (packed.m_blk, packed.col_loc, packed.row_blk, x2d)
+        # the kernels route by the source-lane table, not the adder index
+        stream = (packed.m_blk, packed.col_blk, packed.src_blk, x2d)
+        stream_loc = (packed.m_blk, packed.col_loc, packed.src_blk, x2d)
         if ragged:
             steer = (packed.block_window, packed.block_starts)
             if gather == "local":

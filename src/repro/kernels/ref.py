@@ -120,6 +120,28 @@ def gust_spgemm_ref(
     return y.reshape(num_windows, l, n_out)
 
 
+def _route_rows_onehot(m_blk, gs, row_blk, *, l):
+    """The crossbar as a one-hot matmul, the oracle of the kernels'
+    lane-gather ``route_rows``: per cycle row ``c`` of a (C_blk, l) block,
+    ``m[c] * g[c]`` times ``OneHot(row[c])`` at ``HIGHEST`` precision,
+    sending lane ``j`` to adder ``row[c, j]``, summed over the cycle rows
+    in order.  ``gs`` is the per-cycle tuple of gathered (B_pad, l)
+    arrays; returns the block's (B_pad, l) f32 contribution."""
+    row = row_blk.astype(jnp.int32)
+    adder = jax.lax.broadcasted_iota(jnp.int32, (l, l), 0)
+    acc = None
+    for c, g in enumerate(gs):
+        p = m_blk[c:c + 1] * g  # (B_pad, l)
+        onehot = (adder == row[c:c + 1]).astype(jnp.float32)  # (l_out, l)
+        y = jax.lax.dot_general(
+            p, onehot, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
+        )
+        acc = y if acc is None else acc + y
+    return acc
+
+
 def _window_accumulate(
     m_blocks: jnp.ndarray,  # (T, l) values (0 in padding)
     v_sch: jnp.ndarray,  # (T, l, B) gathered vector stream

@@ -295,6 +295,25 @@ def test_p17_col_out_of_bounds(padded_f32):
     assert _fired(leaves, meta) == ["GUST-P17"]
 
 
+@pytest.mark.parametrize("mutation", ["moved", "dropped", "shape"])
+def test_p18_source_lanes_not_the_route_inverse(padded_f32, mutation):
+    """The table the kernels route by: a source moved to another lane, a
+    source dropped, or a table of the wrong shape fires GUST-P18 alone;
+    leaves written without the table verify clean."""
+    leaves, meta = _leaves_meta(padded_f32)
+    src = leaves["src_blk"]
+    r, a = next(zip(*np.nonzero(src >= 0)))
+    if mutation == "moved":
+        src[r, a] = (src[r, a] + 1) % L
+    elif mutation == "dropped":
+        src[r, a] = -1
+    else:
+        leaves["src_blk"] = src[:-1]
+    assert _fired(leaves, meta) == ["GUST-P18"]
+    del leaves["src_blk"]
+    assert _fired(leaves, meta) == []
+
+
 def test_mutations_on_ragged_layout(ragged_f32):
     """The element rules run identically on the ragged stream (which has
     no all-padding blocks — only padding slots inside real blocks)."""

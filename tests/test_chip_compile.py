@@ -12,7 +12,8 @@ planned with l=256 (w_up/w_gate: 11008 x 4096, w_down: 4096 x 11008),
 decoded at batch 4; stream lengths are the planner's at that density.
 The padded resident kernels also compile at the Table-3 ``mouse_gene``
 plan (45,101 square, l=256) at batch 1, the widest resident walk the
-benchmark runs, and at Mellum2's expert matrices at batch 64.
+benchmark runs, at yi_6b's w_down at batch 8, and at Mellum2's expert
+matrices at batch 64.
 """
 
 import os
@@ -164,6 +165,27 @@ def test_resident_spmv_compiles_at_mellum_experts(one_chip, mat):
 
     w, seg, c_pad = MELLUM_EXPERTS[mat]
     rows, b = w * c_pad, 64
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    args = (spec((rows, L), "float32"), spec((rows, L), "int32"),
+            spec((rows, L), "int32"),
+            spec((b, K._resident_x_rows(seg), L), "float32"))
+    fn = K.make_gust_spmv_db(w, c_pad, L, seg, b, c_blk=C_BLK,
+                             interpret=False)
+    _assert_kernel(_compile_text(fn, args), "gust_spmv_padded_resident_db")
+
+
+def test_resident_db_compiles_at_yi_decode_batch(one_chip):
+    """yi_6b's w_down (16 windows, 43 segments) at batch 8, the chat
+    cell's decode batch: one full sublane tile of batch through the
+    double-buffered resident kernel's lane-gather route."""
+    from repro.kernels import gust_spmv as K
+
+    w, seg, c_pad, _, _ = SHAPES["w_down"]
+    rows, b = w * c_pad, 8
 
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype),

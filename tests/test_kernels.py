@@ -99,7 +99,7 @@ def test_kernel_vs_ref_on_packed_blocks():
     rows = _resident_x_rows(seg)
     xs = jnp.pad(xp, ((0, (rows - seg) * 8), (0, 0))).T.reshape(3, rows, 8)
     fn = make_gust_spmv(packed.num_windows, packed.c_pad, 8, seg, 3)
-    y_k = np.asarray(fn(packed.m_blk, packed.col_blk, packed.row_blk, xs))
+    y_k = np.asarray(fn(packed.m_blk, packed.col_blk, packed.src_blk, xs))
     y_k = y_k[:, :3, :].transpose(0, 2, 1)
     np.testing.assert_allclose(y_k, y_ref, rtol=1e-5, atol=1e-5)
 
@@ -219,3 +219,75 @@ def test_resident_walk_bitwise_vs_ref_int8(kernel):
                      pipeline=pipeline)
     y_ref = execute_spmm(art, xj, use_kernel=False, gather="resident")
     assert np.array_equal(np.asarray(y), np.asarray(y_ref))
+
+
+# ---------------------------------------------------------------------------
+# Crossbar: the lane-gather route against the one-hot route it replaced.
+# ---------------------------------------------------------------------------
+
+ROUTE_CASES = ["mixed", "empty_cycles", "full_cycles", "all_padding",
+               "zero_value", "int8"]
+
+
+def _route_block(l, case, rng, c_blk=8):
+    """One (c_blk, l) block of a collision-free stream: per cycle row a
+    random partial permutation of lanes onto adders; padding lanes hold
+    value 0 and adder 0 as packing writes them.  Values span 20 decades
+    either side of 1 (the gathered x 15, so no product leaves the normal
+    f32 range).  Returns ``(stored values, dequantized f32 values,
+    row)``."""
+    m = np.zeros((c_blk, l), np.float32)
+    row = np.zeros((c_blk, l), np.int32)
+    for c in range(c_blk):
+        if case == "all_padding" or (case == "empty_cycles" and c % 2):
+            continue
+        perm = rng.permutation(l)
+        real = (np.ones(l, bool) if case == "full_cycles" or c == 0
+                else rng.random(l) < 0.6)
+        row[c, real] = perm[real]
+        mag = 10.0 ** rng.uniform(-20, 20, l)
+        m[c, real] = (np.sign(rng.standard_normal(l)) * mag)[real]
+    if case == "zero_value":
+        # a real slot whose value is 0, on a real adder (not adder 0)
+        j = int(np.flatnonzero(row[0] != 0)[0])
+        m[0, j] = 0.0
+    if case == "int8":
+        q = np.clip(np.rint(rng.standard_normal((c_blk, l)) * 60), -127, 127)
+        q = np.where(m != 0, q, 0).astype(np.int8)
+        q[0, 0] = 0 if row[0, 0] else q[0, 0]  # a value quantized to 0
+        return q, q.astype(np.float32) * np.float32(0.0173), row
+    return m, m, row
+
+
+@pytest.mark.parametrize("case", ROUTE_CASES)
+@pytest.mark.parametrize("b_pad", [8, 64])
+@pytest.mark.parametrize("l", [64, 128, 256, 512])
+def test_route_rows_equals_onehot_route(l, b_pad, case):
+    """``route_rows`` (a lane gather by the pack-time source-lane table)
+    equals the one-hot HIGHEST-precision matmul route bit for bit, up to
+    the sign of zero, at every lane width the gather splits differently
+    (one piece below 128 lanes, 1, 2 and 4 vreg chunks) and at one and
+    eight sublane tiles of batch."""
+    from repro.core.packing import _source_lanes
+    from repro.kernels.gust_spmv import route_rows
+    from repro.kernels.ref import _route_rows_onehot
+
+    rng = np.random.default_rng(l * 100 + b_pad + ROUTE_CASES.index(case))
+    stored, m, row = _route_block(l, case, rng)
+    src = _source_lanes(stored, row)
+    real = stored != 0
+    if case == "all_padding":
+        assert (src == -1).all()
+    else:
+        assert (src >= 0).any() and (src == -1).any() or case == "full_cycles"
+    gs = tuple(
+        jnp.asarray(rng.standard_normal((b_pad, l)).astype(np.float32)
+                    * 10.0 ** rng.uniform(-15, 15, (b_pad, l)))
+        for _ in range(m.shape[0])
+    )
+    y = np.asarray(route_rows(jnp.asarray(m), gs, jnp.asarray(src), l=l))
+    y_ref = np.asarray(_route_rows_onehot(jnp.asarray(m), gs,
+                                          jnp.asarray(row), l=l))
+    assert y.shape == (b_pad, l)
+    assert np.array_equal(y, y_ref)
+    assert real.any() == bool(np.any(y != 0))

@@ -253,3 +253,134 @@ def test_schedule_cache_eviction_and_bypass():
     sb, pb = schedule_packed(coo_from_dense(d), 8, cache=None)
     assert sa is not sb
     assert np.array_equal(np.asarray(pa.m_blk), np.asarray(pb.m_blk))
+
+
+# ---------------------------------------------------------------------------
+# src_blk: the crossbar's source-lane table
+# ---------------------------------------------------------------------------
+
+
+def assert_source_lanes(art):
+    """``src_blk`` inverts the routing over the nonzero slots: where it
+    names a lane, that lane holds a nonzero value routed to that adder,
+    and every nonzero lane is named by its adder (so no two nonzero lanes
+    of a cycle share an adder); rows with no nonzero value are all -1."""
+    m = np.asarray(art.m_blk).astype(np.float32)
+    row = np.asarray(art.row_blk, np.int64)
+    src = np.asarray(art.src_blk, np.int64)
+    assert src.shape == row.shape and art.src_blk.dtype == art.row_blk.dtype
+    r, a = np.nonzero(src >= 0)
+    assert np.array_equal(row[r, src[r, a]], a)
+    assert np.all(m[r, src[r, a]] != 0)
+    rn, jn = np.nonzero(m != 0)
+    assert np.array_equal(src[rn, row[rn, jn]], jn)
+    assert int((src >= 0).sum()) == rn.size
+    empty = ~(m != 0).any(axis=1)
+    assert np.all(src[empty] == -1)
+    return empty
+
+
+def _src_case_artifacts(layout, transform):
+    """(artifact, mask of the rows the transform grew, or None) for one
+    packing path."""
+    from repro.core.packing import (
+        pack_ragged,
+        ragged_from_leaves,
+        ragged_leaves,
+        ragged_meta,
+        splice_ragged_blocks,
+    )
+    from repro.core.plan import GustPlan
+
+    rng = np.random.default_rng(21)
+    dense = random_dense(rng, 40, 56, 0.25)
+    dense[3, 5] = 0.0
+    sched = schedule(coo_from_dense(dense), 8, load_balance=False)
+    pack = pack_ragged if layout == "ragged" else pack_schedule
+    kw = {"int8": dict(value_dtype=jnp.int8, index_dtype=jnp.int16),
+          "compact": dict(value_dtype=jnp.bfloat16, index_dtype=jnp.int16),
+          }.get(transform, {})
+    p = pack(sched, **kw)
+    if transform == "repad":
+        if layout == "ragged":
+            p = p.repad_to_blocks(p.num_blocks + 3)
+            grown = np.arange(p.num_blocks * 8) >= (p.num_blocks - 3) * 8
+        else:
+            old = p.c_pad
+            p = p.repad_to(old + 16)
+            grown = np.arange(p.num_windows * p.c_pad) % p.c_pad >= old
+        return p, grown
+    if transform == "stack":
+        other = pack(schedule(coo_from_dense(random_dense(rng, 40, 56, 0.6)),
+                              8, load_balance=False))
+        st = GustPlan.stack([p, other])
+        p = GustPlan.from_spec({
+            "leaves": {k: v[0] for k, v in st["leaves"].items()},
+            "meta": st["meta"]}).artifact
+    elif transform in ("codec", "spec"):
+        spec = GustPlan.from_artifact(p).to_spec()
+        if transform == "spec":
+            p = GustPlan.from_spec(spec).artifact
+        elif layout == "ragged":
+            p = ragged_from_leaves(ragged_leaves(p), ragged_meta(p))
+        else:
+            p = packed_from_leaves(packed_leaves(p), packed_meta(p))
+    elif transform == "splice":
+        dense2 = dense.copy()
+        dense2[8:16][dense2[8:16] != 0] *= 2.0
+        dense2[9, 1] = 1.5
+        sched2 = schedule(coo_from_dense(dense2), 8, load_balance=False)
+        p = splice_ragged_blocks(p, sched2, [1])
+        fresh = pack_ragged(sched2)
+        assert np.array_equal(np.asarray(p.src_blk),
+                              np.asarray(fresh.src_blk))
+    return p, None
+
+
+SRC_CASES = [
+    (layout, transform)
+    for layout in ("padded", "ragged")
+    for transform in ("pack", "int8", "compact", "repad", "stack", "codec",
+                      "spec")
+] + [("ragged", "splice")]
+
+
+@pytest.mark.parametrize("layout,transform", SRC_CASES)
+def test_source_lane_table_invariants(layout, transform):
+    """``src_blk`` holds its invariants as packed, quantized, compact,
+    grown by ``repad_to``/``repad_to_blocks`` (the new rows all -1),
+    stacked with a heavier layer, through the leaves/meta codec and
+    ``to_spec``/``from_spec``, and spliced (equal to a fresh pack)."""
+    art, grown = _src_case_artifacts(layout, transform)
+    empty = assert_source_lanes(art)
+    if grown is not None:
+        assert grown.any() and np.all(empty[grown])
+        assert np.all(np.asarray(art.src_blk)[grown] == -1)
+
+
+@pytest.mark.parametrize("layout", ["padded", "ragged"])
+def test_artifact_without_source_lanes_derives_them(tmp_path, layout):
+    """Leaves written before the ``src_blk`` leaf existed (here, a plan
+    store entry stripped of it) load, derive the table, and run the
+    kernel bit-identically to a fresh plan."""
+    from repro.core.plan import PlanConfig, plan
+    from repro.core.plan_store import PlanStore
+
+    rng = np.random.default_rng(23)
+    dense = random_dense(rng, 40, 56, 0.25)
+    cfg = PlanConfig(l=8, layout=layout, backend="pallas")
+    fresh = plan(dense, cfg, cache=None)
+    store = PlanStore(str(tmp_path))
+    spec = fresh.to_spec()
+    spec["leaves"] = {k: v for k, v in spec["leaves"].items()
+                      if k != "src_blk"}
+    store.put(store.key(ScheduleCache.matrix_key(coo_from_dense(dense)), cfg),
+              spec)
+    loaded = plan(dense, cfg, cache=None, store=store)
+    assert store.hits == 1 and loaded.sched is None
+    assert_source_lanes(loaded.artifact)
+    assert np.array_equal(np.asarray(loaded.artifact.src_blk),
+                          np.asarray(fresh.artifact.src_blk))
+    x = jnp.asarray(rng.standard_normal((56, 2)).astype(np.float32))
+    assert np.array_equal(np.asarray(loaded.spmm(x)),
+                          np.asarray(fresh.spmm(x)))

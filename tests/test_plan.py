@@ -404,6 +404,19 @@ def test_plan_cost_fields():
     )
 
 
+@pytest.mark.parametrize("l,gathers", [(8, 8), (128, 8), (256, 32),
+                                       (512, 128)])
+def test_plan_cost_route_lane_gathers(l, gathers):
+    """The crossbar takes one lane gather per cycle row, 128-lane output
+    chunk and source chunk: c_blk below 128 lanes, c_blk * (l/128)**2
+    from there (32 at the benchmark's l = 256)."""
+    rng = np.random.default_rng(l)
+    p = plan(random_dense(rng, l, 16, 0.05), PlanConfig(l=l), cache=None)
+    assert p.config.c_blk == 8
+    assert p.cost().route_lane_gathers == gathers
+    assert p.cost().to_dict()["route_lane_gathers"] == gathers
+
+
 # ---------------------------------------------------------------------------
 # deprecated spellings warn with the new one
 # ---------------------------------------------------------------------------
